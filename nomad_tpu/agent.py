@@ -500,8 +500,8 @@ class Agent:
         """Runtime introspection payload for /v1/agent/debug (the
         pprof-analog; reference command/agent/http.go:115-119). Sections:
         thread stacks, gc stats, tracemalloc top allocations (only when
-        tracing was started), device probe state, pallas kernel state,
-        coalescer and mirror-cache stats."""
+        tracing was started), the device this process holds, coalescer
+        (dispatches by solve path) and mirror-cache stats."""
         import gc
 
         query = query or {}
@@ -533,30 +533,17 @@ class Agent:
         else:
             out["tracemalloc_top"] = None  # start tracing to populate
 
-        try:
-            from nomad_tpu.scheduler import device_probe_status
+        from nomad_tpu.scheduler import device_status
 
-            out["device_probe"] = device_probe_status()
-        except Exception as e:
-            out["device_probe"] = {"error": str(e)}
-        try:
-            from nomad_tpu.ops.pallas_solve import _STATE, pallas_mode
-
-            # tuple() snapshots the set before iterating: scheduler
-            # threads mutate it via mark_proven with no lock.
-            out["pallas"] = {
-                "mode": pallas_mode(),
-                "failed": _STATE["failed"],
-                "proven_shapes": sorted(map(str, tuple(_STATE["proven"]))),
-            }
-        except Exception as e:
-            out["pallas"] = {"error": str(e)}
+        out["device"] = device_status()
         try:
             from nomad_tpu.ops.coalesce import GLOBAL_SOLVER
 
             out["coalescer"] = {
                 "dispatches": GLOBAL_SOLVER.dispatches,
                 "coalesced": GLOBAL_SOLVER.coalesced,
+                "paths": dict(GLOBAL_SOLVER.paths),
+                "batch_retries": GLOBAL_SOLVER.batch_retries,
             }
         except Exception as e:
             out["coalescer"] = {"error": str(e)}

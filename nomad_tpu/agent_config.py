@@ -614,7 +614,7 @@ def _from_mapping(data: dict) -> FileConfig:
                             "server.solver_mesh must be a mapping")
                     # Same posture: a typo'd mesh knob fails config load
                     # (SolverMeshConfig.parse), not leader-establish.
-                    from nomad_tpu.parallel.mesh import SolverMeshConfig
+                    from nomad_tpu.parallel.mesh_config import SolverMeshConfig
 
                     SolverMeshConfig.parse(dict(v))
                     cfg.server.solver_mesh = dict(v)

@@ -567,8 +567,8 @@ class AgentApi:
 
     def debug(self) -> Dict:
         """Runtime introspection (/v1/agent/debug; requires the agent to
-        run with enable_debug): thread stacks, gc stats, device probe /
-        pallas / coalescer / mirror state."""
+        run with enable_debug): thread stacks, gc stats, device /
+        coalescer / mirror state."""
         out, _ = self.client.query("/v1/agent/debug")
         return out
 

@@ -1594,7 +1594,7 @@ class HTTPServer:
         """Runtime introspection, gated by enable_debug — the pprof-analog
         surface (reference gates pprof handlers the same way,
         command/agent/http.go:115-119). Thread stacks, gc and allocation
-        stats, device probe/pallas/coalescer/mirror state: the first
+        stats, device/coalescer/mirror state: the first
         things needed when a bench or an agent wedges."""
         if not getattr(self.agent, "debug_enabled", lambda: False)():
             raise HTTPCodedError(404, "debug endpoints disabled "

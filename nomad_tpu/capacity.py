@@ -29,7 +29,7 @@ What it keeps, per poll generation:
   the mirror's base usage starts from;
 - per-lane usage: ``service`` / ``batch`` / ``system`` by job type, with
   express-flagged jobs split into their own ``express`` lane (the
-  admission front door's lane taxonomy, carried through to capacity);
+  admission front door's lane classification, carried through to capacity);
 - **fragmentation histograms**: per dimension, how many schedulable
   nodes sit in each free-fraction decile — the shape of the cell's
   leftover capacity;
@@ -63,7 +63,7 @@ import numpy as np
 from nomad_tpu import telemetry
 from nomad_tpu.structs import NODE_STATUS_READY, RESOURCE_DIMS
 
-# Lane taxonomy: the admission front door's batch/service distinction
+# Lane classification: the admission front door's batch/service distinction
 # plus the express lane (an express-flagged batch job rides its own
 # books there too) and system jobs.
 LANES = ("service", "batch", "system", "express")

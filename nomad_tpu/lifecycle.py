@@ -16,7 +16,7 @@ spans and events after the fact, so enabling it cannot perturb placement
 (the SIMLOAD event digest is the enforcement: r08 artifacts carry this
 section with digests identical to the pre-attribution r07 runs).
 
-Stage taxonomy (a PARTITION of submit→placed, so stage sums reconcile
+Stage classification (a PARTITION of submit→placed, so stage sums reconcile
 with measured end-to-end latency by construction — ``unattributed``
 holds the thread-handoff/dispatch gaps the spans don't cover):
 
@@ -61,7 +61,7 @@ STAGES = (
     "unattributed",
 )
 
-# Express-lane stages (server/express.py): a separate taxonomy — the
+# Express-lane stages (server/express.py): a separate classification — the
 # express path skips broker/worker/plan-queue entirely, so its timeline
 # is the in-line pick + lease (submit→placed) with the async raft commit
 # OUTSIDE submit→placed (it happens after the caller was answered).

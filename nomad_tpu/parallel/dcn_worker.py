@@ -23,16 +23,11 @@ def _main() -> None:
     local_devices = int(sys.argv[6]) if len(sys.argv) > 6 else 4
 
     os.environ["JAX_PLATFORMS"] = "cpu"
-    os.environ["NOMAD_TPU_PROBE_FORCE_CPU"] = "1"
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             f"{flags} --xla_force_host_platform_device_count={local_devices}"
         ).strip()
-
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
     from nomad_tpu.parallel import dcn
 

@@ -19,7 +19,7 @@ three ledgers:
   ``sys._current_frames()`` at a seeded-jittered cadence
   (``prng.stream(seed, "profile.sampler")`` — the schedule is a pure
   function of the seed, so two runs sample at identical offsets) and
-  aggregates collapsed stacks per THREAD ROLE (the taxonomy in
+  aggregates collapsed stacks per THREAD ROLE (the classification in
   :data:`ROLES`: worker / pipeline-committer / raft / heartbeat-wheel /
   express-committer / observer / http / main / other). Flamegraph-ready
   exports: ``collapsed()`` (Brendan Gregg folded-stack lines) and
@@ -70,10 +70,10 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from nomad_tpu import prng, telemetry
 
-# Thread-role taxonomy: every thread in the process maps to exactly one
+# Thread-role classification: every thread in the process maps to exactly one
 # role by FIRST-MATCH prefix rule (order matters: "raft-observatory"
 # must classify observer, not raft). Pinned by the golden-format tests —
-# extending the taxonomy is an artifact-schema change.
+# extending the classification is an artifact-schema change.
 ROLES = ("worker", "pipeline-committer", "raft", "heartbeat-wheel",
          "express-committer", "observer", "http", "main", "other")
 

@@ -162,10 +162,10 @@ class ClusterServer(Server):
     def start(self) -> None:
         if self._started:
             return
+        # Same ordering contract as Server.start: the device is claimed
+        # (or the start fails) before any worker exists.
+        self._acquire_device()
         self._started = True
-        # Same ordering contract as Server.start: the mesh must be
-        # configured before any worker builds a mirror.
-        self._apply_solver_mesh()
         self.rpc.start()
         joined = not self.cluster.start_join
         for addr in self.cluster.start_join:

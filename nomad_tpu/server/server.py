@@ -238,7 +238,7 @@ class ServerConfig:
         from nomad_tpu.profile_observe import ProfileObserveConfig
 
         self.profile_config = ProfileObserveConfig.parse(self.profile)
-        from nomad_tpu.parallel.mesh import SolverMeshConfig
+        from nomad_tpu.parallel.mesh_config import SolverMeshConfig
 
         self.solver_mesh_config = SolverMeshConfig.parse(self.solver_mesh)
 
@@ -451,8 +451,8 @@ class Server:
     def start(self) -> None:
         if self._started:
             return
+        self._acquire_device()
         self._started = True
-        self._apply_solver_mesh()
         self.plan_queue.set_enabled(True)
         self.eval_broker.set_enabled(True)
         self.plan_applier.start()
@@ -490,15 +490,25 @@ class Server:
             )
             warmer.start()
 
-    def _apply_solver_mesh(self) -> None:
-        """Configure the process solve mesh from `server { solver_mesh }`
-        BEFORE any worker can build a mirror: node tensors are born with
-        the configured sharding (mirror.put_node_sharded), so ordering is
-        what keeps the warm path reshard-free. Transparent fallback on a
-        box that can't satisfy the extents. Shared by Server.start and
-        ClusterServer.start so the gating can never drift."""
-        if (self.config.solver_mesh_config.enabled
-                and self.config.scheduler_backend == "tpu"):
+    def _acquire_device(self) -> None:
+        """With scheduler_backend="tpu", claim the device in this process
+        BEFORE any worker starts: every worker sees the same device from
+        its first eval, and a server that cannot claim one raises here
+        and does not start. Then configure the process solve mesh from
+        `server { solver_mesh }`, also before any worker can build a
+        mirror: node tensors are born with the configured sharding
+        (mirror.put_node_sharded), so ordering is what keeps the warm
+        path reshard-free. Shared by Server.start and ClusterServer.start
+        so the gating can never drift."""
+        if self.config.scheduler_backend != "tpu":
+            return
+        from nomad_tpu.scheduler import acquire_device
+
+        device = acquire_device()
+        self.logger.info(
+            "device solver on %s (%s x%d)", device["platform"],
+            device["device_kind"], device["count"])
+        if self.config.solver_mesh_config.enabled:
             from nomad_tpu.parallel import mesh as mesh_lib
 
             mesh_lib.apply_solver_mesh(
@@ -507,17 +517,14 @@ class Server:
 
     def _prewarm_solver(self) -> None:
         """Background shape-bucket pre-compile (see ServerConfig
-        .prewarm_shapes). Waits for device acquisition, then re-warms
-        whenever the cluster's node-bucket signature changes — a fresh
-        cluster warms as soon as nodes register, and growth into a larger
-        padded bucket triggers a new compile before an eval needs it. A
-        host-only deployment simply never warms."""
+        .prewarm_shapes; started only with the device acquired).
+        Re-warms whenever the cluster's node-bucket signature changes — a
+        fresh cluster warms as soon as nodes register, and growth into a
+        larger padded bucket triggers a new compile before an eval needs
+        it."""
         from nomad_tpu.ops.binpack import bucket
-        from nomad_tpu.scheduler import wait_for_device
+        from nomad_tpu.tpu import solver
 
-        solver = wait_for_device(timeout=600.0, logger=self.logger)
-        if solver is None:
-            return
         warmed_sig = None
         while not self._periodic_stop.is_set():
             snap = self.state_store.snapshot()
@@ -619,18 +626,6 @@ class Server:
                     ("blocking", name, "watch_rejected"),
                     wstats["rejected"],
                 )
-            solver = self.solver_stats()
-            device = solver.get("device", {})
-            # probe state as a numeric gauge: 1 ready / 0 probing-unprobed /
-            # -1 down — alertable without string handling
-            state_num = {"ready": 1, "down": -1}.get(
-                str(device.get("status")), 0
-            )
-            telemetry.set_gauge(("scheduler", "device", "state"), state_num)
-            telemetry.set_gauge(
-                ("scheduler", "device", "fallbacks"),
-                float(device.get("fallbacks", 0)),
-            )
 
     def restore_eval_broker(self) -> None:
         """Re-enqueue non-terminal evals after (re)gaining leadership
@@ -1242,15 +1237,15 @@ class Server:
 
     @staticmethod
     def solver_stats() -> Dict:
-        """Device-solver health: probe state + host-fallback count, the
-        coalescer's dispatch/batch counters, and the mirror-cache hit rate.
-        Surfaced through Stats()/agent-info so a silently-degraded device
-        path (host fallback: same placements, order-of-magnitude latency
-        cliff) is operator-visible. Metrics posture mirrors the
+        """Device-solver health: the device this process holds, the
+        circuit breaker (an open breaker is the one way evals reach the
+        host oracle under scheduler_backend="tpu"), the coalescer's
+        dispatch/batch counters, and the mirror-cache hit rate. Surfaced
+        through Stats()/agent-info. Metrics posture mirrors the
         reference's broker stats (nomad/eval_broker.go:557-575)."""
-        from nomad_tpu.scheduler import DEVICE_BREAKER, device_probe_status
+        from nomad_tpu.scheduler import DEVICE_BREAKER, device_status
 
-        out: Dict = {"device": device_probe_status(),
+        out: Dict = {"device": device_status(),
                      "breaker": DEVICE_BREAKER.stats()}
         try:
             import sys

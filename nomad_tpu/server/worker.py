@@ -8,6 +8,7 @@ plan queue; a RefreshIndex response forces a state refresh before retry.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import threading
 import time
@@ -70,6 +71,15 @@ class Worker(threading.Thread):
             while self._paused and not self._stop.is_set():
                 self._pause_cond.wait()
 
+    def _coalesce(self):
+        """ops.coalesce when this server schedules on the device, else
+        None: a scheduler_backend="host" server never imports jax."""
+        if getattr(self.server.config, "scheduler_backend", "tpu") != "tpu":
+            return None
+        from nomad_tpu.ops import coalesce
+
+        return coalesce
+
     def run(self) -> None:
         batch_size = getattr(self.server.config, "eval_batch_size", 1)
         while not self._stop.is_set():
@@ -93,32 +103,32 @@ class Worker(threading.Thread):
                 # until all of these evals' solves have stacked (or a
                 # short window passes) instead of fragmenting on their
                 # staggered host prep.
-                from nomad_tpu.ops.coalesce import (
-                    MAX_BATCH_BUCKET, GLOBAL_SOLVER,
-                )
+                coalesce = self._coalesce()
+                member = self._process
+                if coalesce is not None:
+                    engine = coalesce.GLOBAL_SOLVER
+                    # Clamped at the dispatch chunk size: holding for more
+                    # arrivals than one chunk can carry buys no coalescing.
+                    burst_token = engine.hint_burst(
+                        min(len(batch), coalesce.MAX_BATCH_BUCKET)
+                    )
 
-                # Clamped at the dispatch chunk size: holding for more
-                # arrivals than one chunk can carry buys no coalescing.
-                burst_token = GLOBAL_SOLVER.hint_burst(
-                    min(len(batch), MAX_BATCH_BUCKET)
-                )
-
-                def process_burst_member(ev, token, wait_index):
-                    # Account this eval against ITS announced burst
-                    # exactly once: its first solve submit, or — for
-                    # evals that never reach the coalescer (exact-path
-                    # small counts, scale-downs, failed prep) — its
-                    # completion, so the hold never waits on a solve
-                    # that will never come.
-                    GLOBAL_SOLVER.burst_begin(burst_token)
-                    try:
-                        self._process(ev, token, wait_index)
-                    finally:
-                        GLOBAL_SOLVER.burst_done()
+                    def member(ev, token, wait_index):
+                        # Account this eval against ITS announced burst
+                        # exactly once: its first solve submit, or — for
+                        # evals that never reach the coalescer (exact-path
+                        # small counts, scale-downs, failed prep) — its
+                        # completion, so the hold never waits on a solve
+                        # that will never come.
+                        engine.burst_begin(burst_token)
+                        try:
+                            self._process(ev, token, wait_index)
+                        finally:
+                            engine.burst_done()
 
                 threads = [
                     threading.Thread(
-                        target=process_burst_member,
+                        target=member,
                         args=(ev, token, wait_index),
                         daemon=True, name=f"{self.name}-batch{i}",
                     )
@@ -195,7 +205,9 @@ class Worker(threading.Thread):
         # thread (mirror device_puts, exact-path solves, result fetches);
         # quiesce_all must be able to drain it before interpreter teardown
         # — a daemon worker of a shut-down server can still be mid-solve.
-        from nomad_tpu.ops.coalesce import device_activity
+        coalesce = self._coalesce()
+        device_activity = (coalesce.device_activity if coalesce is not None
+                           else contextlib.nullcontext)
 
         inv_span = tracer.start_span(
             ev.id, "worker.invoke_scheduler", parent=root_ctx,

@@ -8,7 +8,7 @@ be decomposed after the fact. This module adds the per-evaluation view:
 lightweight spans with parent links and key/value annotations, recorded
 into a bounded, lock-protected ring of traces keyed by evaluation id.
 
-Span taxonomy (producers in parentheses):
+Span classification (producers in parentheses):
 
 - ``eval``                      root; broker enqueue → ack/failed (eval_broker)
 - ``broker.wait``               ready-queue wait, enqueue/nack → dequeue (eval_broker)

@@ -8,7 +8,7 @@ Four layers:
   change logs must report byte-identical aggregates to a fresh one that
   full-rebuilt from the same state (the device mirror's fuzz posture).
 - **solver panel** units: padding economy, bucket occupancy, and the
-  compile-trigger taxonomy (precompile / bucket_crossing / first_roll).
+  compile-trigger classification (precompile / bucket_crossing / first_roll).
 - **PromText** units: the shared exposition line-builder's sanitation,
   TYPE-once, and conflict guards (the one-sanitizer satellite).
 - **live-agent e2e**: /v1/agent/capacity and /v1/agent/solver over HTTP
@@ -310,13 +310,6 @@ def test_promtext_conflicting_type_raises():
 @pytest.fixture(scope="module")
 def agent(tmp_path_factory):
     from nomad_tpu.agent import Agent, AgentConfig
-
-    from nomad_tpu.scheduler import wait_for_device
-
-    # The e2e assertions read the solver panel, which only records on
-    # the device path: block for the probe so the factory can't fall
-    # back to the host scheduler during its first-caller grace.
-    assert wait_for_device(timeout=180.0) is not None
 
     config = AgentConfig.dev()
     config.data_dir = str(tmp_path_factory.mktemp("capacity-agent"))

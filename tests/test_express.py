@@ -853,7 +853,7 @@ def test_agent_express_endpoint_and_metrics(tmp_path):
         bundle = client.agent().debug_bundle()
         assert bundle["express"]["placed"] == 1
         # The express eval's timeline resolves over HTTP with the
-        # express stage taxonomy (in-line pick/lease partition).
+        # express stage classification (in-line pick/lease partition).
         tl = client.evaluations().timeline(eval_id)
         assert tl["triggered_by"] == "express"
         assert tl["submit_to_placed_ms"] is not None

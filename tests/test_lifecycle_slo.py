@@ -46,7 +46,7 @@ def _full_span_set(t0):
 
 
 def test_stage_partition_reconciles_exactly():
-    """The stage taxonomy is a PARTITION of submit→placed: directly
+    """The stage classification is a PARTITION of submit→placed: directly
     mapped spans + derived (parent-minus-children) stages + the explicit
     unattributed gap sum to the measured end-to-end latency."""
     t0 = 1000.0

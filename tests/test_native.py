@@ -187,3 +187,51 @@ def test_bulk_verify_columnar_against_empty_node_table():
     result = evaluate_plan(state.snapshot(), plan)
     assert not result.alloc_batches        # nothing committable
     assert result.refresh_index > 0        # stale-data refresh forced
+
+
+# -- the loader builds from what git holds ----------------------------------
+
+
+@pytest.fixture
+def fresh_loader(monkeypatch):
+    """nomad_tpu.native as a new process sees it: nothing loaded yet."""
+    for name, value in (("_lib", None), ("_tried", False),
+                        ("_built", False), ("_error", None)):
+        monkeypatch.setattr(native, name, value)
+    return native
+
+
+def test_library_older_than_source_is_rebuilt(fresh_loader):
+    import os
+
+    if not native.available():
+        pytest.skip("no toolchain: the numpy verifier is all there is")
+    for name, value in (("_lib", None), ("_tried", False)):
+        setattr(fresh_loader, name, value)
+    src_mtime = os.path.getmtime(native._SRC)
+    os.utime(native._SO, (src_mtime - 10, src_mtime - 10))
+    assert native._stale()
+    assert native.status() == {
+        "verifier": "native", "built": True, "error": None}
+    assert not native._stale()
+
+
+def test_failed_make_is_logged_not_swallowed(fresh_loader, monkeypatch,
+                                             caplog):
+    import subprocess
+
+    def failing_make(cmd, **kw):
+        raise subprocess.CalledProcessError(
+            2, cmd, stderr="nomad_native.cpp:1: error: expected ';'")
+
+    monkeypatch.setattr(native, "_stale", lambda: True)
+    monkeypatch.setattr(native.subprocess, "run", failing_make)
+    with caplog.at_level("WARNING", logger="nomad_tpu.native"):
+        status = native.status()
+    assert status["verifier"] == "numpy" and not status["built"]
+    assert "expected ';'" in status["error"]
+    assert "numpy verifier runs instead" in caplog.text
+    # the numpy path still answers
+    fit, exhausted = native.fit_check(
+        np.array([[1, 1]], np.int32), np.array([[2, 0]], np.int32))
+    assert fit.tolist() == [False] and exhausted.tolist() == [1]

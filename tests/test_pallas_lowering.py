@@ -1,0 +1,49 @@
+"""Deviceless TPU lowering of the batched Pallas water-fill.
+
+``jax.export`` with ``platforms=["tpu"]`` runs the Pallas TPU lowering
+rules — BlockSpec checks, memory spaces, every primitive's Mosaic rule —
+without a device, so a kernel the installed JAX refuses is caught here on
+the CPU-pinned suite. Interpret mode skips all of that: it accepted the
+per-eval ``(1, ·)`` SMEM blocks that do not lower for B > 1. Whether the
+Mosaic COMPILER then accepts the module only a chip can say; chip_smoke.py
+compiles and compares the same shapes there.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from nomad_tpu.ops.coalesce import MAX_BATCH_BUCKET
+from nomad_tpu.ops.pallas_solve import solve_waterfill_pallas_batched
+
+NODE_BUCKET = 16384  # steady-10k / burst-100k: bucket(10_000)
+
+
+def _arg_shapes(b, n, d=4):
+    S = jax.ShapeDtypeStruct
+    i32, f32 = jnp.int32, jnp.float32
+    return (
+        S((b, n, d), i32), S((b, n, 2), f32), S((b, n, d), i32),
+        S((b, n), i32), S((b, n), i32), S((b, n), i32), S((b, n), i32),
+        S((b, n), jnp.bool_), S((b, d), i32), S((b,), i32), S((b,), i32),
+        S((b,), f32),
+    )
+
+
+@pytest.mark.parametrize("flags", [(False, False), (True, False),
+                                   (False, True)],
+                         ids=["plain", "job_distinct", "tg_distinct"])
+@pytest.mark.parametrize("width", [1, 2, 4, 8])
+def test_kernel_lowers_for_tpu_at_every_coalesced_width(width, flags):
+    assert width <= MAX_BATCH_BUCKET
+    jd, td = flags
+
+    def solve(*args):
+        return solve_waterfill_pallas_batched(*args, jd, td)
+
+    exported = jax.export.export(jax.jit(solve), platforms=["tpu"])(
+        *_arg_shapes(width, NODE_BUCKET))
+    assert "tpu_custom_call" in exported.mlir_module()
+    counts, remaining = exported.out_avals
+    assert counts.shape == (width, NODE_BUCKET)
+    assert remaining.shape == (width,)

@@ -3,7 +3,8 @@
 The kernel must be bit-identical to ops/binpack.solve_waterfill (which is
 itself differential-fuzzed against the host oracle), so the pallas path
 inherits the whole oracle-parity chain. Runs in interpret mode on the CPU
-backend; the compiled path is exercised on real TPU by bench.py."""
+backend; tests/test_pallas_lowering.py lowers it for TPU without a device
+and chip_smoke.py compiles and compares it on the chip."""
 
 import numpy as np
 import pytest
@@ -110,12 +111,25 @@ def test_batched_matches_vmapped():
     np.testing.assert_array_equal(np.asarray(r0), np.asarray(r1))
 
 
-def test_coalescer_uses_pallas_in_interpret_mode(monkeypatch):
+def _select_interpreted_kernel(monkeypatch):
+    """Make the coalescer's selection pick the kernel on this CPU-pinned
+    suite, run by the interpreter: the selection itself reads only the
+    backend and the node bucket (pallas_solve.selected)."""
+    from functools import partial
+
+    monkeypatch.setattr(pallas_solve, "selected", lambda n: True)
+    monkeypatch.setattr(
+        pallas_solve, "solve_waterfill_pallas",
+        partial(solve_waterfill_pallas, interpret=True))
+    monkeypatch.setattr(
+        pallas_solve, "solve_waterfill_pallas_batched",
+        partial(solve_waterfill_pallas_batched, interpret=True))
+
+
+def test_coalescer_dispatches_selected_kernel(monkeypatch):
     from nomad_tpu.ops.coalesce import CoalescingSolver
 
-    monkeypatch.setenv("NOMAD_TPU_PALLAS", "interpret")
-    pallas_solve.reset_pallas_failed()
-    assert pallas_solve.pallas_mode() == "interpret"
+    _select_interpreted_kernel(monkeypatch)
     rng = np.random.default_rng(12)
     args = random_instance(rng, 64)
     solver = CoalescingSolver()
@@ -124,25 +138,71 @@ def test_coalescer_uses_pallas_in_interpret_mode(monkeypatch):
     c0, r0 = solve_waterfill(*args, False, False)
     np.testing.assert_array_equal(np.asarray(c0), counts)
     assert int(r0) == unplaced
-    # The pallas path must have actually run — a silent fallback to the
-    # jnp solver would produce identical results and mask a regression.
-    assert not pallas_solve._STATE["failed"]
-    assert len(pallas_solve._STATE["proven"]) >= 1
+    # The pallas path must have actually run — the jnp solver produces
+    # identical results, so only the path book tells them apart.
+    assert solver.paths == {"pallas": 1}
 
 
-def test_fallback_disables_pallas(monkeypatch):
-    monkeypatch.setenv("NOMAD_TPU_PALLAS", "interpret")
-    pallas_solve.reset_pallas_failed()
-    assert pallas_solve.pallas_mode() == "interpret"
-    pallas_solve.mark_pallas_failed()
-    assert pallas_solve.pallas_mode() == "off"
-    pallas_solve.reset_pallas_failed()
+def test_coalescer_stacks_selected_kernel(monkeypatch):
+    """Width > 1 rides the batched kernel (the dispatch the (1, .) SMEM
+    blocks could not lower for)."""
+    from nomad_tpu.ops.coalesce import _stack_and_solve
+
+    _select_interpreted_kernel(monkeypatch)
+    rng = np.random.default_rng(13)
+    rows = [random_instance(rng, 64) for _ in range(3)]
+    entries = [
+        (*r[:10], int(r[10]), float(r[11]), False, False) for r in rows
+    ]
+    counts, remaining, path = _stack_and_solve(entries, False, False)
+    assert path == "pallas"
+    assert counts.shape == (4, 64)  # padded to the width bucket
+    for i, r in enumerate(rows):
+        c0, r0 = solve_waterfill(*r, False, False)
+        np.testing.assert_array_equal(np.asarray(c0), np.asarray(counts[i]))
+        assert int(r0) == int(remaining[i])
 
 
-def test_mode_defaults_off_on_cpu(monkeypatch):
-    monkeypatch.delenv("NOMAD_TPU_PALLAS", raising=False)
-    pallas_solve.reset_pallas_failed()
-    assert pallas_solve.pallas_mode() == "off"  # tests pin the cpu backend
+def test_kernel_failure_propagates_and_flips_no_latch(monkeypatch):
+    """A failure of the selected kernel is an error the eval surfaces:
+    the fetch raises, nothing routes to the jnp water-fill, and the next
+    dispatch selects the kernel again."""
+    from nomad_tpu.ops import coalesce
+    from nomad_tpu.ops.coalesce import CoalescingSolver
+
+    _select_interpreted_kernel(monkeypatch)
+    calls = {"kernel": 0}
+
+    def boom(*a, **k):
+        calls["kernel"] += 1
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    def never(*a, **k):
+        raise AssertionError("a kernel failure selected the jnp path")
+
+    monkeypatch.setattr(pallas_solve, "solve_waterfill_pallas", boom)
+    monkeypatch.setattr(coalesce, "solve_waterfill", never)
+    rng = np.random.default_rng(14)
+    args = random_instance(rng, 64)
+    solver = CoalescingSolver()
+    for attempt in (1, 2):
+        fetch = solver.submit(*args[:10], int(args[10]), float(args[11]))
+        with pytest.raises(RuntimeError, match="coalesced solve failed") as ei:
+            fetch()
+        assert "Mosaic" in str(ei.value.__cause__)
+        # dispatch + its one-at-a-time retry, both through the kernel
+        assert calls["kernel"] == 2 * attempt
+    assert solver.batch_retries == 2
+
+
+def test_selection_reads_backend_and_bucket(monkeypatch):
+    # tests pin the cpu backend: never selected here, whatever the env.
+    monkeypatch.setenv("NOMAD_TPU_PALLAS", "1")
+    assert pallas_solve.selected(16384) is False
+    monkeypatch.setattr(pallas_solve.jax, "default_backend", lambda: "tpu")
+    assert pallas_solve.selected(16384) is True
+    assert pallas_solve.selected(pallas_solve.PALLAS_MAX_NODES) is True
+    assert pallas_solve.selected(2 * pallas_solve.PALLAS_MAX_NODES) is False
 
 
 # The fuzz corpus: the same randomized instances the waterfill/rounds/

@@ -1,5 +1,5 @@
 """Runtime self-observatory tests (nomad_tpu/profile_observe.py):
-config parse validation, the thread-role taxonomy (pinned), golden
+config parse validation, the thread-role classification (pinned), golden
 collapsed-stack and speedscope export formats, seeded-cadence
 determinism, the lock watchdog's contention timing + closure-based
 violation semantics, the byte-economy ledger (rings, mirror
@@ -99,10 +99,10 @@ def test_file_config_validates_profile_block(tmp_path):
     assert ac.lock_watchdog is True
 
 
-# -- thread-role taxonomy (pinned) -------------------------------------------
+# -- thread-role classification (pinned) -------------------------------------------
 
 
-def test_thread_role_taxonomy_pinned():
+def test_thread_role_classification_pinned():
     """The role vocabulary is an artifact-schema contract: collapsed
     exports, speedscope profile names, and the prom role label all ride
     it. Every mapping here is deliberate."""
@@ -519,7 +519,7 @@ def test_profile_endpoint_e2e(agent):
     prof = view["profiler"]
     assert prof["samples"] >= 5
     assert prof["schedule"]["seed"] == 42
-    # The agent's own subsystem threads classified into the taxonomy.
+    # The agent's own subsystem threads classified into the classification.
     assert set(prof["roles"]) <= set(ROLES)
     assert "main" in prof["roles"]
     shares = [r["wall_share"] for r in prof["roles"].values()]

@@ -313,14 +313,6 @@ def test_blocked_eval_wait_spans_all_finish():
 def agent(tmp_path_factory):
     from nomad_tpu.agent import Agent, AgentConfig
 
-    from nomad_tpu.scheduler import wait_for_device
-
-    # The device path must actually carry the solves (the acceptance
-    # criterion names the solver stage spans): block for the probe before
-    # any eval dispatches, or the factory would fall back to the host
-    # scheduler while the prewarm thread holds the first-caller grace.
-    assert wait_for_device(timeout=180.0) is not None
-
     config = AgentConfig.dev()
     config.data_dir = str(tmp_path_factory.mktemp("trace-agent"))
     config.http_port = 0

@@ -197,13 +197,11 @@ def test_batched_worker_processes_all_with_coalesced_dispatches():
     s.plan_queue.set_enabled(True)
     s.eval_broker.set_enabled(True)
     s.plan_applier.start()
-    # The assertion below counts coalescer dispatches, so the device
-    # solver must be READY before any eval processes — otherwise the
-    # factory legitimately falls back to the host scheduler (order-
-    # dependent flake when an earlier test started the ready race).
-    from nomad_tpu.scheduler import wait_for_device
+    # This test assembles the server by hand instead of Server.start(),
+    # so it claims the device the way start() would.
+    from nomad_tpu.scheduler import acquire_device
 
-    assert wait_for_device(timeout=120) is not None
+    assert acquire_device()["platform"] == "cpu"
     try:
         # count > exact threshold so the water-fill/coalescer path runs
         jobs, evals = _seed_n_jobs(s, 4, count=200)
