@@ -99,7 +99,8 @@ def _record_dispatch_width(width: int, wall_ms: float) -> None:
 
 
 class _Entry:
-    __slots__ = ("args", "event", "group", "index", "error", "kind", "k")
+    __slots__ = ("args", "event", "group", "index", "error", "kind", "k",
+                 "traced", "t_taken", "t_launched", "launch_cpu_s")
 
     def __init__(self, args, kind: str = "wf", k: int = 0):
         self.args = args
@@ -113,6 +114,17 @@ class _Entry:
         # same-k entries share a dispatch.
         self.kind = kind
         self.k = k
+        # Made on its rider's thread: does the rider carry a stage timer?
+        # Only then the dispatcher stamps it, on the span clock
+        # (trace.now): its batch taken off the pending list (the hold is
+        # over), and the jit call returned (stacking, launch and any
+        # compile done; the event is set next), with the dispatcher
+        # thread's CPU seconds from taken to launched. The group carries
+        # t_ready. taken <= launched <= ready.
+        self.traced = trace.active_stages() is not trace.NULL_STAGES
+        self.t_taken: Optional[float] = None
+        self.t_launched: Optional[float] = None
+        self.launch_cpu_s = 0.0
 
     def result(self) -> Tuple[np.ndarray, int]:
         """Block for the dispatch, then return (counts[N], n_unplaced) —
@@ -121,21 +133,42 @@ class _Entry:
         # The dispatcher-hold + device wall both land in the caller's
         # 'execute' stage cut (trace.stage no-ops when the calling thread
         # carries no stage timer).
-        with trace.stage("execute"):
+        st = trace.active_stages()
+        with st.stage("execute"):
+            # A stamp of its own, after the execute cut's: a cut that
+            # starts with the cut it is in cannot be told from it.
+            traced = st is not trace.NULL_STAGES
+            t0 = trace.now() if traced else 0.0
             self.event.wait()
+            if traced and self.t_launched is not None:
+                self._cut_wait(st, t0, trace.now())
         if self.group is None:
             raise RuntimeError("coalesced solve failed") from self.error
         return self.group.fetch(self.index)
+
+    def _cut_wait(self, st, t0: float, woke: float) -> None:
+        """Cut the rider's wait [t0, woke] at the dispatcher's stamps, as
+        far as they fall inside it (the rider's overlapped host work may
+        have outlasted the hold): hold + launch + wake are the wait."""
+        taken = min(max(self.t_taken, t0), woke)
+        launched = min(max(self.t_launched, taken), woke)
+        group = self.group
+        st.add("execute.hold", t0, taken)
+        st.add("execute.launch", taken, launched, kind=self.kind,
+               width=group.width if group is not None else 1,
+               path=group.path if group is not None else "",
+               cpu_ms=round(self.launch_cpu_s * 1000.0, 4))
+        st.add("execute.wake", launched, woke)
 
 
 class _Group:
     """One dispatched batch: device arrays + lazily-fetched host results."""
 
     __slots__ = ("counts_dev", "remaining_dev", "_fetch_lock", "_host",
-                 "width", "t0")
+                 "width", "t0", "path", "t_ready")
 
     def __init__(self, counts_dev, remaining_dev, width: int = 1,
-                 t0: Optional[float] = None):
+                 t0: Optional[float] = None, path: str = ""):
         self.counts_dev = counts_dev
         self.remaining_dev = remaining_dev
         self._fetch_lock = threading.Lock()
@@ -145,6 +178,10 @@ class _Group:
         # (width, wall) pair on the solver panel's batch-width axis.
         self.width = width
         self.t0 = t0
+        # The program family that carried it, and when the first
+        # fetcher's block_until_ready returned (trace.now).
+        self.path = path
+        self.t_ready: Optional[float] = None
 
     def _materialize(self) -> None:
         """First fetch blocks on the device and copies the whole batch
@@ -156,10 +193,12 @@ class _Group:
                 # uses the same names through the same StageTimer). An
                 # async device fault surfaces here and raises to the
                 # fetching eval.
-                with trace.stage("execute"):
+                with trace.stage("execute"), \
+                        trace.stage("execute.device_wait"):
                     jax.block_until_ready(
                         (self.counts_dev, self.remaining_dev)
                     )
+                self.t_ready = trace.now()
                 with trace.stage("readback"):
                     counts, remaining = jax.device_get(
                         (self.counts_dev, self.remaining_dev)
@@ -233,6 +272,9 @@ class CoalescingSolver:
         self.coalesced = 0
         self.paths: Dict[str, int] = {}
         self.batch_retries = 0
+        # (span clock, the dispatcher thread's CPU clock) when it took a
+        # batch with a traced rider in it; None for a batch with none.
+        self._taken: Optional[Tuple[float, float]] = None
 
     def hint_burst(self, n: int, window_s: float = BURST_WINDOW_S,
                    gap_s: float = BURST_GAP_S) -> int:
@@ -435,6 +477,9 @@ class CoalescingSolver:
         # every row reads the same mirror. Same-generation burst members
         # do; cross-generation stragglers dispatch separately.
         groups: Dict[Tuple, List[_Entry]] = {}
+        # Off the pending list: the hold is over.
+        self._taken = ((trace.now(), time.thread_time())
+                       if any(e.traced for e in batch) else None)
         for e in batch:
             total = e.args[0]
             key = (total.shape[0], e.kind, e.k, e.args[12], e.args[13],
@@ -468,12 +513,13 @@ class CoalescingSolver:
                             self._count_path(path)
                             cls = (_ExactGroup if e.kind == "exact"
                                    else _Group)
-                            e.group = cls(a_dev[None], b_dev[None])
+                            e.group = cls(a_dev[None], b_dev[None],
+                                          path=path)
                             e.index = 0
                         except Exception as exc:
                             e.error = exc
                         finally:
-                            e.event.set()
+                            self._launched([e])
 
     @staticmethod
     def _solve_one(e: _Entry):
@@ -518,6 +564,21 @@ class CoalescingSolver:
             "jnp",
         )
 
+    def _launched(self, entries: List[_Entry]) -> None:
+        """Wake one dispatch's riders; where one of the batch is traced,
+        first stamp them taken and launched (wall, and the dispatcher's
+        CPU since their batch was taken)."""
+        if self._taken is not None:
+            taken, cpu_taken = self._taken
+            cpu_s = time.thread_time() - cpu_taken
+            launched = trace.now()
+            for e in entries:
+                e.t_taken = taken
+                e.launch_cpu_s = cpu_s
+                e.t_launched = launched
+        for e in entries:
+            e.event.set()
+
     def _count_path(self, path: str) -> None:
         self.paths[path] = self.paths.get(path, 0) + 1
 
@@ -533,9 +594,10 @@ class CoalescingSolver:
             (a_dev, b_dev), path = self._solve_one(e)
             self._count_path(path)
             cls = _ExactGroup if e.kind == "exact" else _Group
-            e.group = cls(a_dev[None], b_dev[None], width=1, t0=t0)
+            e.group = cls(a_dev[None], b_dev[None], width=1, t0=t0,
+                          path=path)
             e.index = 0
-            e.event.set()
+            self._launched(entries)
             return
 
         self.coalesced += len(entries)
@@ -545,7 +607,7 @@ class CoalescingSolver:
             )
             self._count_path("exact")
             group: _Group = _ExactGroup(
-                idxs_dev, oks_dev, width=len(entries), t0=t0
+                idxs_dev, oks_dev, width=len(entries), t0=t0, path="exact"
             )
         else:
             counts_dev, remaining_dev, path = _stack_and_solve(
@@ -553,11 +615,11 @@ class CoalescingSolver:
             )
             self._count_path(path)
             group = _Group(counts_dev, remaining_dev,
-                           width=len(entries), t0=t0)
+                           width=len(entries), t0=t0, path=path)
         for i, e in enumerate(entries):
             e.group = group
             e.index = i
-            e.event.set()
+        self._launched(entries)
 
 
 def _stack_rows(rows, jd: bool, td: bool):

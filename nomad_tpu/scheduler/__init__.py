@@ -86,6 +86,7 @@ def new_scheduler(
 
 import os as _os
 import threading as _threading
+import time as _time
 
 from nomad_tpu.backoff import CircuitBreaker
 
@@ -140,38 +141,85 @@ def configure_compile_cache(platform: str) -> Optional[str]:
     return path
 
 
+# XLA's own events (jax.monitoring, jax 0.9): the first wraps
+# compile_or_get_cached, so it fires for a backend compile AND for a load
+# from the persistent cache; the second fires first, on the same thread,
+# only when the cache served the program.
+XLA_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+XLA_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_xla_tls = _threading.local()
+_xla_listening = False
+
+
+def _on_xla_duration(event: str, duration: float, **_kwargs) -> None:
+    """jax.monitoring duration listener: counts every program XLA compiles
+    or loads from the persistent cache, process-wide, into SOLVER_PANEL
+    (``xla_compiles`` / ``xla_cache_loads`` and their milliseconds), and
+    notes a compile on the span active on the compiling thread."""
+    from nomad_tpu import trace
+    from nomad_tpu.tpu.solver import SOLVER_PANEL
+
+    if event == XLA_CACHE_LOAD_EVENT:
+        _xla_tls.loaded_at = _time.perf_counter()
+        SOLVER_PANEL.record_xla(duration, loaded=True)
+    elif event == XLA_COMPILE_EVENT:
+        loaded_at = getattr(_xla_tls, "loaded_at", None)
+        _xla_tls.loaded_at = None
+        # The load's own enclosing event spans the load. A stamp from
+        # before it began is a load whose enclosing event never fired
+        # here, and this is a compile.
+        if (loaded_at is not None
+                and loaded_at >= _time.perf_counter() - duration - 0.001):
+            return
+        SOLVER_PANEL.record_xla(duration, loaded=False)
+        span = trace.current_span()
+        if span is not None:
+            span.annotate("compiled", True)
+
+
 def acquire_device() -> Dict[str, object]:
     """Claim the accelerator for THIS process and return what JAX reports
-    (platform, device_kind, count) plus where its compile cache lives.
+    (platform, device_kind, count), where its compile cache lives, and how
+    long reaching the device took (``acquire_s``: importing jax, backend
+    initialization, the first ``jax.devices()``).
     The first call initializes the JAX backend — whatever
     ``jax.devices()`` raises propagates, so a server without a device
     fails to start instead of scheduling on the host — places the compile
-    cache before anything compiles, and imports the solver stack, so a
-    broken import fails the start and not the first eval. Later calls
-    return the same record."""
-    global _device
+    cache before anything compiles, imports the solver stack, so a
+    broken import fails the start and not the first eval, and registers
+    the listener for XLA's compile events. Later calls return the same
+    record."""
+    global _device, _xla_listening
     with _device_lock:
         if _device is None:
+            t0 = _time.perf_counter()
             import jax
 
             devices = jax.devices()
+            acquire_s = _time.perf_counter() - t0
             platform = devices[0].platform
             cache_dir = configure_compile_cache(platform)
             from nomad_tpu.tpu import solver  # noqa: F401
 
+            if not _xla_listening:  # once a process, whatever resets _device
+                jax.monitoring.register_event_duration_secs_listener(
+                    _on_xla_duration)
+                _xla_listening = True
             _device = {
                 "platform": platform,
                 "device_kind": devices[0].device_kind,
                 "count": len(devices),
                 "compile_cache": cache_dir,
+                "acquire_s": round(acquire_s, 4),
             }
         return dict(_device)
 
 
 def device_status() -> Dict[str, object]:
     """The device this process holds, for Stats()/agent-info: platform,
-    device kind, count and compile-cache directory, or ``{"acquired": False}`` in a process that
-    has not claimed one (scheduler_backend="host")."""
+    device kind, count, compile-cache directory and ``acquire_s``, or
+    ``{"acquired": False}`` in a process that has not claimed one
+    (scheduler_backend="host")."""
     with _device_lock:
         if _device is None:
             return {"acquired": False}

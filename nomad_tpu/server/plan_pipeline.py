@@ -79,6 +79,12 @@ class _PipelineTotals:
         self.refreshes = 0
         self.fused_plans = 0
         self.scalar_plans = 0
+        # Why each scalar plan left the fused pass (they sum to
+        # scalar_plans): alone in its batch (or the last of it), not
+        # fused-eligible, object rows in the snapshot, or a prefix that
+        # did not fully fit.
+        self.scalar_why = {"scalar_lone": 0, "scalar_ineligible": 0,
+                           "scalar_object_rows": 0, "scalar_unfit": 0}
         self.max_batch_seen = 0
 
     def stats(self) -> Dict[str, int]:
@@ -93,6 +99,7 @@ class _PipelineTotals:
                 "refreshes": self.refreshes,
                 "fused_plans": self.fused_plans,
                 "scalar_plans": self.scalar_plans,
+                **self.scalar_why,
                 "max_batch_seen": self.max_batch_seen,
             }
 
@@ -156,23 +163,26 @@ def _fused_eligible(plan: Plan) -> bool:
 
 
 def _fused_prefix(snap, plans: List[Plan], table,
-                  reservations=None) -> Tuple[int, List[PlanResult]]:
+                  reservations=None) -> Tuple[int, List[PlanResult], str]:
     """Verify a leading run of fused-eligible plans in ONE batched tensor
     pass over the node table: stack the K per-plan asks, prefix-cumsum
     along K (each plan sees every earlier plan's ask as committed usage —
     exactly the sequential roll), and fit-check all K x touched-rows at
-    once. Returns (m, results): the longest prefix whose plans ALL fully
-    fit, with their whole-commit results. m == 0 means the first plan
-    needs the scalar path (ineligible, or doesn't fully fit — the exact
-    partial answer comes from evaluate_plan)."""
+    once. Returns (m, results, why): the longest prefix whose plans ALL
+    fully fit, with their whole-commit results. m == 0 means the first
+    plan needs the scalar path, and ``why`` is the reason's key in
+    ``_PipelineTotals.scalar_why``: ``scalar_object_rows``, ``scalar_ineligible``,
+    or ``scalar_unfit`` (a node the table cannot fit-check, or an ask that
+    doesn't fully fit — the exact partial answer comes from
+    evaluate_plan)."""
     import numpy as np
 
     if table is None or table.n == 0:
-        return 0, []
+        return 0, [], "scalar_unfit"
     if snap.nodes_with_object_allocs():
         # Object rows change per-node usage in ways only the per-node
         # walk accounts; the whole batch takes the sequential path.
-        return 0, []
+        return 0, [], "scalar_object_rows"
 
     run: List[Plan] = []
     for plan in plans:
@@ -180,7 +190,7 @@ def _fused_prefix(snap, plans: List[Plan], table,
             break
         run.append(plan)
     if not run:
-        return 0, []
+        return 0, [], "scalar_ineligible"
 
     block_usage, net_rows, _blocks = _existing_block_usage_rows(snap, table)
 
@@ -215,7 +225,7 @@ def _fused_prefix(snap, plans: List[Plan], table,
         )
         plan_rows.append(rows)
     if eligible == 0:
-        return 0, []
+        return 0, [], "scalar_unfit"
 
     run = run[:eligible]
     # One fused pass: inclusive prefix over the K stacked asks restricted
@@ -224,7 +234,7 @@ def _fused_prefix(snap, plans: List[Plan], table,
                                      or [np.empty(0, dtype=np.int64)]))
     if union.size == 0:
         # Nothing asks for capacity: every plan trivially whole-commits.
-        return len(run), [_whole_commit_result(p) for p in run]
+        return len(run), [_whole_commit_result(p) for p in run], ""
     stacked = np.stack([a[union] for a in asks])          # [K, U, 4]
     cum = np.cumsum(stacked, axis=0)                      # inclusive
     base = table.reserved[union].astype(np.int64)
@@ -256,7 +266,7 @@ def _fused_prefix(snap, plans: List[Plan], table,
             if not fits[i, idxs].all():
                 break
         m = i + 1
-    return m, [_whole_commit_result(p) for p in run[:m]]
+    return m, [_whole_commit_result(p) for p in run[:m]], "scalar_unfit"
 
 
 def evaluate_plans(snap, plans: List[Plan],
@@ -285,10 +295,11 @@ def evaluate_plans(snap, plans: List[Plan],
     n = len(plans)
     while i < n:
         m = 0
+        reason = "scalar_lone"
         if n - i > 1:
             # A lone plan takes evaluate_plan directly — its own
             # pure-columnar fast path is the K=1 case of the fused pass.
-            m, fused_results = _fused_prefix(
+            m, fused_results, reason = _fused_prefix(
                 snap, plans[i:], _node_table(snap),
                 reservations=full_debits,
             )
@@ -316,6 +327,7 @@ def evaluate_plans(snap, plans: List[Plan],
         if totals is not None:
             with totals._lock:
                 totals.scalar_plans += 1
+                totals.scalar_why[reason] += 1
         i += 1
     return results
 
@@ -505,6 +517,7 @@ class PlanPipeline(threading.Thread):
         snap = self._opt_snap
 
         t0 = time.perf_counter()
+        cpu0 = time.thread_time() if tracer.enabled else None
         eval_spans = []
         for pending in live:
             eval_spans.append(tracer.start_span(
@@ -538,8 +551,14 @@ class PlanPipeline(threading.Thread):
             totals=self.totals,
             ledger=ledger,
         )
+        # The committer's CPU time in the batch's verification, on every
+        # plan of the batch (beside the wall: the rest is blocked time).
+        cpu_ms = (None if cpu0 is None
+                  else round((time.thread_time() - cpu0) * 1000.0, 4))
         for span, result in zip(eval_spans, results):
             span.annotate("refresh_index", result.refresh_index)
+            if cpu_ms is not None:
+                span.annotate("cpu_ms", cpu_ms)
             span.annotate("batched", len(live)).finish()
         telemetry.measure_since(("plan", "evaluate"), t0)
 

@@ -13,10 +13,11 @@ from __future__ import annotations
 
 import logging
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
-from nomad_tpu import structs
+from nomad_tpu import structs, trace
 from nomad_tpu.events import EventBroker
 from nomad_tpu.server.core_sched import CoreScheduler
 from nomad_tpu.server.eval_broker import EvalBroker
@@ -408,8 +409,6 @@ class Server:
         pending/outcome queues, plan-pipeline commit log. getattr-
         guarded — a ring that doesn't exist on this composition simply
         doesn't appear in the ledger."""
-        from nomad_tpu import trace
-
         return {
             "events": getattr(self.fsm.events, "_events", None),
             "traces": getattr(trace.get_tracer(), "_traces", None),
@@ -762,7 +761,19 @@ class Server:
         The admission front door is checked FIRST — before validation
         even, so an overload rejection stays cheap — and before any raft
         apply, so a raised RejectError proves zero side effects (the
-        typed-retry safety contract)."""
+        typed-retry safety contract).
+
+        The handler's own time is the eval's ``frontdoor.job_register``
+        span (entry -> return, with the two raft applies as children): it
+        precedes the ``eval`` root, which starts at broker enqueue."""
+        tracer = trace.get_tracer()
+        if tracer.enabled:
+            st = trace.StageTimer()
+            t_entry = trace.now()
+            cpu_entry = time.thread_time()
+        else:
+            st = trace.NULL_STAGES
+            t_entry = cpu_entry = 0.0
         self.admission.admit_job(job, client_id)
         job.validate()
         if job.type == JOB_TYPE_CORE:
@@ -789,7 +800,8 @@ class Server:
                 f"express commit for job {job.id} still in flight",
                 retry_after=1.0,
             )
-        index = self.raft.apply("job_register", {"job": job}).result()
+        with st.stage("raft_job"):
+            index = self.raft.apply("job_register", {"job": job}).result()
 
         ev = Evaluation(
             id=generate_uuid(),
@@ -800,7 +812,16 @@ class Server:
             job_modify_index=index,
             status=structs.EVAL_STATUS_PENDING,
         )
-        eval_index = self.eval_upsert([ev])
+        span = tracer.start_span(ev.id, "frontdoor.job_register",
+                                 start=t_entry)
+        try:
+            with st.stage("raft_eval"):
+                eval_index = self.eval_upsert([ev])
+        finally:
+            if st is not trace.NULL_STAGES:
+                st.emit_spans(span, prefix="frontdoor.")
+                span.annotate("cpu_ms", round(
+                    (time.thread_time() - cpu_entry) * 1000.0, 4)).finish()
         return ev.id, eval_index
 
     def job_evaluate(self, job_id: str, client_id: str = "") -> Tuple[str, int]:

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from nomad_tpu import telemetry
+from nomad_tpu import telemetry, trace
 from nomad_tpu.ops.binpack import bucket
 from nomad_tpu.parallel.mesh import node_sharded_jit, put_node_sharded
 from nomad_tpu.scheduler.feasible import (
@@ -683,25 +683,29 @@ class NodeMirror:
         node count for AllocMetric. Cached per (drivers, job constraints,
         tg constraints) for the mirror's lifetime — repeat evals against
         one state generation upload nothing. Returns (device_mask,
-        n_filtered)."""
-        key = (
-            frozenset(drivers),
-            tuple((c.l_target, c.operand, c.r_target)
-                  for c in (job_constraints or ())),
-            tuple((c.l_target, c.operand, c.r_target)
-                  for c in (tg_constraints or ())),
-        )
-        cached = self._device_mask_cache.get(key)
-        if cached is not None:
-            return cached
-        mask = self.driver_mask(drivers)
-        if job_constraints:
-            mask = mask & self.constraint_mask(ctx, job_constraints)
-        if tg_constraints:
-            mask = mask & self.constraint_mask(ctx, tg_constraints)
-        entry = (put_node_sharded(mask), int(self.n - mask[: self.n].sum()))
-        self._device_mask_cache[key] = entry
-        return entry
+        n_filtered). Cut as ``staging.mask`` (annotated ``cached``) on the
+        calling solve's stage timer."""
+        with trace.stage("staging.mask") as cut:
+            key = (
+                frozenset(drivers),
+                tuple((c.l_target, c.operand, c.r_target)
+                      for c in (job_constraints or ())),
+                tuple((c.l_target, c.operand, c.r_target)
+                      for c in (tg_constraints or ())),
+            )
+            cached = self._device_mask_cache.get(key)
+            cut.annotate("cached", cached is not None)
+            if cached is not None:
+                return cached
+            mask = self.driver_mask(drivers)
+            if job_constraints:
+                mask = mask & self.constraint_mask(ctx, job_constraints)
+            if tg_constraints:
+                mask = mask & self.constraint_mask(ctx, tg_constraints)
+            entry = (put_node_sharded(mask),
+                     int(self.n - mask[: self.n].sum()))
+            self._device_mask_cache[key] = entry
+            return entry
 
     # -- utilization tensors ----------------------------------------------
 
@@ -735,14 +739,34 @@ class NodeMirror:
         state = ctx.state
         if (state.alloc_count() == 0 and not plan.alloc_batches
                 and not plan.node_allocation and not plan.node_update):
-            return self.clean_usage()
+            with trace.stage("staging.usage_base") as cut:
+                cut.annotate("path", "clean")
+                return self.clean_usage()
         if not (hasattr(state, "allocs_objects")
                 and hasattr(state, "alloc_blocks")
                 and hasattr(state, "allocs_by_job_objects")
                 and hasattr(state, "alloc_object_by_id")
                 and hasattr(state, "job_alloc_blocks")):
             return self._build_usage_walk(ctx, job_id, tg_name)
-        base_used, base_bw = self._base_usage_for(state)
+        with trace.stage("staging.usage_base") as cut:
+            base_used, base_bw = self._base_usage_for(state, cut)
+        with trace.stage("staging.usage_job") as cut:
+            cut.annotate("plan_batches", len(plan.alloc_batches))
+            used, job_count, tg_count, bw_used = self._job_usage(
+                state, plan, job_id, tg_name, base_used, base_bw)
+        with trace.stage("staging.upload"):
+            return (
+                put_node_sharded(used, 1),
+                put_node_sharded(job_count),
+                put_node_sharded(tg_count),
+                put_node_sharded(bw_used),
+            )
+
+    def _job_usage(self, state, plan, job_id: str, tg_name: str,
+                   base_used, base_bw):
+        """The eval's own (used, job_count, tg_count, bw_used) on the
+        host: a copy of the base, the job's own allocations, and the
+        plan's in-flight rows."""
         used = base_used.copy()
         bw_used = base_bw.copy()
         job_count = np.zeros(self.padded, dtype=np.int32)
@@ -813,12 +837,7 @@ class NodeMirror:
                         tg_count[i] += 1
         self._plan_batch_usage(plan, job_id, tg_name, used, job_count,
                                tg_count)
-        return (
-            put_node_sharded(used, 1),
-            put_node_sharded(job_count),
-            put_node_sharded(tg_count),
-            put_node_sharded(bw_used),
-        )
+        return used, job_count, tg_count, bw_used
 
     def capacity_view(self, state) -> Tuple[np.ndarray, np.ndarray]:
         """(totals[padded,4] int32, used[padded,4] int32) — the express
@@ -861,26 +880,32 @@ class NodeMirror:
             self._express_usage = (uid, aidx, used, bw)
         return self.totals_np, used
 
-    def _base_usage_for(self, state) -> Tuple[np.ndarray, np.ndarray]:
+    def _base_usage_for(self, state, cut=None) -> Tuple[np.ndarray, np.ndarray]:
         """The cached job-independent (used, bw_used) base for ``state``'s
         alloc generation: reserved + every existing allocation. On a
         generation mismatch the base rolls forward through the store's
         alloc change log (recomputing only the dirty rows); a dirty set
         past the log horizon — or large enough that per-row python beats
         nothing — falls back to one full recompute. Returned arrays are
-        shared and must be copied before mutation."""
+        shared and must be copied before mutation. Which way it went is
+        noted on ``cut`` (the caller's stage cut): ``path`` = hit / roll /
+        rebuild, ``dirty_rows``, ``blocks``; rolls and rebuilds are
+        counted (telemetry, and GLOBAL_MIRROR_CACHE's ``usage_*``)."""
         uid = getattr(state, "store_uid", "")
         aidx = state.get_index("allocs")
         if not uid or getattr(state, "optimistic", False):
             # Anonymous states and optimistically-mutated snapshots name
             # content the shared change logs don't describe: never roll
             # from them, never cache them.
+            _note_usage(cut, "rebuild", state)
             return self._compute_base_usage(state)
         with self._usage_lock:
             cached = self._base_usage
         if cached is not None and cached[0] == uid and cached[1] == aidx:
+            _note_usage(cut, "hit")
             return cached[2], cached[3]
         used = bw = None
+        dirty = None
         if (cached is not None and cached[0] == uid and aidx > cached[1]
                 and hasattr(state, "alloc_node_changes_since")):
             dirty = state.alloc_node_changes_since(cached[1])
@@ -894,11 +919,16 @@ class NodeMirror:
                     bw = cached[3].copy()
                     self._usage_rows_bulk(state, dirty, used, bw)
                     telemetry.incr_counter(("mirror", "usage_rolls"))
+                    GLOBAL_MIRROR_CACHE.count_usage(rolls=1)
+                    _note_usage(cut, "roll", state, len(dirty))
                 else:
                     used, bw = cached[2], cached[3]
+                    _note_usage(cut, "hit")
         if used is None:
             used, bw = self._compute_base_usage(state)
             telemetry.incr_counter(("mirror", "usage_rebuilds"))
+            GLOBAL_MIRROR_CACHE.count_usage(rebuilds=1)
+            _note_usage(cut, "rebuild", state, len(dirty or ()))
         with self._usage_lock:
             prev = self._base_usage
             if prev is None or prev[0] != uid or prev[1] <= aidx:
@@ -1157,6 +1187,18 @@ class NodeMirror:
                     used[i] += delta.astype(np.int32)
 
 
+def _note_usage(cut, path: str, state=None, dirty_rows: int = 0) -> None:
+    """Note on a live stage cut how the usage base was served; the
+    block count is read for the note alone."""
+    if cut is None or not cut.live:
+        return
+    cut.annotate("path", path)
+    if state is not None:
+        cut.annotate("blocks", len(state.alloc_blocks()))
+        if dirty_rows:
+            cut.annotate("dirty_rows", dirty_rows)
+
+
 class MirrorCache:
     """Device-mirror registry keyed by state generation.
 
@@ -1192,6 +1234,16 @@ class MirrorCache:
         # the whole delta machinery pointless).
         self.roll_ms = 0.0
         self.rebuild_ms = 0.0
+        # How the mirrors served the usage base (NodeMirror.
+        # _base_usage_for), process-wide on GLOBAL_MIRROR_CACHE: a mirror
+        # does not know the cache it came from.
+        self.usage_rolls = 0
+        self.usage_rebuilds = 0
+
+    def count_usage(self, rolls: int = 0, rebuilds: int = 0) -> None:
+        with self._lock:
+            self.usage_rolls += rolls
+            self.usage_rebuilds += rebuilds
 
     def get(self, state, datacenters: List[str]):
         """Return (nodes, mirror) for the ready nodes of ``state`` in
@@ -1295,7 +1347,8 @@ class MirrorCache:
 
     def stats(self) -> dict:
         """Debug-surface snapshot: residency, hit ratio, and the delta
-        economy (rolls vs full rebuilds, rows re-staged)."""
+        economy (rolls vs full rebuilds, rows re-staged), and the usage
+        base's own rolls and rebuilds."""
         with self._lock:
             return {
                 "entries": len(self._entries),
@@ -1310,6 +1363,8 @@ class MirrorCache:
                 "node_buckets": sorted({
                     m.padded for _n, m in self._entries.values()
                 }),
+                "usage_rolls": self.usage_rolls,
+                "usage_rebuilds": self.usage_rebuilds,
             }
 
     def byte_ledger(self) -> dict:

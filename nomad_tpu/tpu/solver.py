@@ -192,6 +192,18 @@ class SolverPanel:
         self.equiv_members = 0
         self.equiv_copies = 0
         self.equiv_rows_saved = 0
+        # Host staging of traced solves: wall beside the staging thread's
+        # own CPU time. Staging waits for no device, so wall minus CPU is
+        # time blocked on the interpreter lock or another lock.
+        self.staging_wall_ms = 0.0
+        self.staging_cpu_ms = 0.0
+        # XLA's own compile events (scheduler.acquire_device's
+        # jax.monitoring listener): backend compiles and loads from the
+        # persistent cache, of every jitted program in the process.
+        self.xla_compiles = 0
+        self.xla_compile_ms = 0.0
+        self.xla_cache_loads = 0
+        self.xla_cache_load_ms = 0.0
 
     # -- recording -----------------------------------------------------------
 
@@ -269,6 +281,22 @@ class SolverPanel:
             row[1] += width
             row[2] += wall_ms
 
+    def record_staging(self, wall_ms: float, cpu_ms: float) -> None:
+        with self._lock:
+            self.staging_wall_ms += wall_ms
+            self.staging_cpu_ms += cpu_ms
+
+    def record_xla(self, seconds: float, loaded: bool) -> None:
+        """One XLA event: a load from the persistent compile cache
+        (``loaded``) or a backend compile."""
+        with self._lock:
+            if loaded:
+                self.xla_cache_loads += 1
+                self.xla_cache_load_ms += seconds * 1000.0
+            else:
+                self.xla_compiles += 1
+                self.xla_compile_ms += seconds * 1000.0
+
     def record_equiv(self, members: int, count: int) -> None:
         """One equivalence-class collapse: ``members`` identical task
         groups (``count`` total copies) solved as one row."""
@@ -342,6 +370,14 @@ class SolverPanel:
                     d for d, _e, _m in self._batch_widths.values()),
                 "batch_evals": sum(
                     e for _d, e, _m in self._batch_widths.values()),
+                "staging_wall_ms": round(self.staging_wall_ms, 3),
+                "staging_cpu_ms": round(self.staging_cpu_ms, 3),
+                "staging_blocked_ms": round(max(
+                    0.0, self.staging_wall_ms - self.staging_cpu_ms), 3),
+                "xla_compiles": self.xla_compiles,
+                "xla_compile_ms": round(self.xla_compile_ms, 3),
+                "xla_cache_loads": self.xla_cache_loads,
+                "xla_cache_load_ms": round(self.xla_cache_load_ms, 3),
                 "equiv": {
                     "classes": self.equiv_classes,
                     "members": self.equiv_members,
@@ -418,6 +454,7 @@ def _emit_solver_trace(st, start: float, count: int) -> None:
     telemetry.add_sample(("solver", "solve"), ms)
     if st is trace.NULL_STAGES:
         return
+    SOLVER_PANEL.record_staging(*st.wall_cpu_ms("staging"))
     span = trace.current_span()
     if span is not None:
         span.annotate("solve_count", count)
@@ -494,8 +531,9 @@ class TPUStack:
         start = time.perf_counter()
         st = _solve_stages()
         with trace.use_stages(st):
-            with st.stage("staging"):
-                tg_constr = task_group_constraints(tg)
+            with st.stage("staging", cpu=True):
+                with st.stage("staging.mask"):
+                    tg_constr = task_group_constraints(tg)
                 prep = self.prepare(tg, tg_constr)
             if prep is None:
                 if overlap is not None:
@@ -542,8 +580,9 @@ class TPUStack:
         start = time.perf_counter()
         st = _solve_stages()
         with trace.use_stages(st):
-            with st.stage("staging"):
-                tg_constr = task_group_constraints(tg)
+            with st.stage("staging", cpu=True):
+                with st.stage("staging.mask"):
+                    tg_constr = task_group_constraints(tg)
                 prep = self.prepare(tg, tg_constr)
             if prep is None:
                 if overlap is not None:
@@ -627,11 +666,13 @@ class TPUStack:
             for t in tg.tasks
             if t.resources and t.resources.networks
         )
+        with trace.stage("staging.upload"):
+            ask_dev = device_const("ask", ask_vec)
+            bw_ask_dev = device_const("i32", bw_ask_val)
         return _SolveInputs(
             mask=mask_dev, used=used, job_count=job_count,
             tg_count=tg_count, bw_used=bw_used,
-            ask=device_const("ask", ask_vec),
-            ask_np=ask_np, bw_ask=device_const("i32", bw_ask_val),
+            ask=ask_dev, ask_np=ask_np, bw_ask=bw_ask_dev,
             bw_ask_val=bw_ask_val,
             job_distinct=job_distinct, tg_distinct=tg_distinct,
         )

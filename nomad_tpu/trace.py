@@ -8,21 +8,54 @@ be decomposed after the fact. This module adds the per-evaluation view:
 lightweight spans with parent links and key/value annotations, recorded
 into a bounded, lock-protected ring of traces keyed by evaluation id.
 
-Span classification (producers in parentheses):
+Span classification (producers in parentheses). ``cpu_ms`` is a thread's
+CPU time inside the span, beside its wall, on the four spans where
+running and waiting cannot be told apart otherwise:
 
-- ``eval``                      root; broker enqueue → ack/failed (eval_broker)
-- ``broker.wait``               ready-queue wait, enqueue/nack → dequeue (eval_broker)
+- ``frontdoor.job_register``    Job.Register handler entry -> return; precedes
+                                the root and has no parent; ``cpu_ms``
+                                (server.job_register)
+- ``frontdoor.raft_job``        the job_register raft apply (server.job_register)
+- ``frontdoor.raft_eval``       the eval_update raft apply (server.job_register)
+- ``eval``                      root; broker enqueue -> ack/failed (eval_broker)
+- ``broker.wait``               ready-queue wait, enqueue/nack -> dequeue (eval_broker)
 - ``worker.wait_for_index``     FSM catch-up before snapshot (worker)
 - ``worker.invoke_scheduler``   the scheduler pass (worker)
-- ``solver.staging``            host tensorization: masks + usage (tpu/solver)
+- ``solver.staging``            host tensorization: masks + usage; ``cpu_ms``
+                                (tpu/solver)
+- ``solver.staging.mask``       task_group_constraints (tpu/solver) and
+                                device_mask, ``cached`` (tpu/mirror)
+- ``solver.staging.usage_base`` the job-independent usage base; ``path`` =
+                                hit/roll/rebuild/clean, ``dirty_rows``,
+                                ``blocks`` (tpu/mirror build_usage)
+- ``solver.staging.usage_job``  base copy + the job's own rows + plan deltas;
+                                ``plan_batches`` (tpu/mirror build_usage)
+- ``solver.staging.upload``     host -> device puts of the usage tensors and
+                                ask constants (tpu/mirror, tpu/solver)
 - ``solver.transfer``           per-eval device uploads + dispatch (tpu/solver)
-- ``solver.execute``            device execution wait (ops/binpack, ops/coalesce)
-- ``solver.readback``           D2H readback + host expansion (ops/binpack)
-- ``worker.submit_plan``        plan submit → response (worker)
-- ``plan.queue_wait``           plan-queue wait, enqueue → applier dequeue
-- ``plan.evaluate``             plan verification against the snapshot
-- ``plan.apply``                raft apply → commit (plan_apply)
+- ``solver.execute``            the rider's wait for the dispatch, and the
+                                first fetcher's device wait (ops/coalesce)
+- ``solver.execute.hold``       the rider starts to wait -> its batch taken
+                                off the pending list (ops/coalesce)
+- ``solver.execute.launch``     taken -> the jit call returned: stacking,
+                                launch, any compile; ``width``, ``kind``,
+                                ``path``, ``cpu_ms`` of the dispatcher thread
+                                (ops/coalesce)
+- ``solver.execute.wake``       event set -> the rider runs again (ops/coalesce)
+- ``solver.execute.device_wait``  block_until_ready of the first fetcher
+                                (ops/coalesce)
+- ``solver.readback``           D2H readback + host expansion (ops/coalesce,
+                                ops/binpack)
+- ``worker.submit_plan``        plan submit -> response (worker)
+- ``plan.queue_wait``           plan-queue wait, enqueue -> applier dequeue
+- ``plan.evaluate``             plan verification against the snapshot;
+                                ``cpu_ms`` of the committer's thread, shared
+                                by the batch (plan_pipeline)
+- ``plan.apply``                raft apply -> commit (plan_apply)
 - ``fsm.apply``                 one FSM log-entry apply, annotated msg_type
+
+A span that XLA compiled under carries ``compiled: true``
+(scheduler.acquire_device's jax.monitoring listener).
 
 The span context (``{"trace_id", "span_id"}``) crosses the RPC boundary in
 the request envelope: ``Plan.span_ctx`` rides Plan.Submit, and
@@ -42,7 +75,7 @@ import os
 import threading
 import time
 from contextlib import contextmanager
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 # Monotonic wall clock: epoch-anchored perf_counter, so spans from every
 # thread order consistently (time.time() can step backwards under NTP,
@@ -232,22 +265,34 @@ class Tracer:
             tr.updated = now()
 
     def record_batch(self, parent, stages, prefix: str = "") -> None:
-        """Bulk-record already-measured ``(name, start, end)`` triples as
-        finished children of ``parent`` under ONE lock hold — the solver
-        emits its four stage cuts per eval, and per-span locking was a
-        measurable slice of the tracing overhead budget."""
+        """Bulk-record already-measured stage cuts (``StageTimer.stages``:
+        ``(name, start, end, cpu_s, parent_slot, annotations)``, in start
+        order) as finished spans under ONE lock hold — the solver emits
+        its stage cuts per eval, and per-span locking was a measurable
+        slice of the tracing overhead budget. A cut opened inside another
+        parents on that cut's span, every other on ``parent``; ``cpu_s``
+        lands as the ``cpu_ms`` annotation."""
         if (not self.enabled or not stages or parent is None
                 or isinstance(parent, _NullSpan)):
             return
-        spans = []
-        for name, t0, t1 in stages:
+        spans: List[Optional[Span]] = []
+        for cut in stages:
+            if cut is None:  # a cut still open (its thread raised past it)
+                spans.append(None)
+                continue
+            name, t0, t1, cpu_s, slot, annotations = cut
+            over = spans[slot] if slot >= 0 else None
             s = Span(self, parent.trace_id, prefix + name,
-                     parent.span_id, t0)
+                     (over or parent).span_id, t0, annotations)
+            if cpu_s is not None:
+                s.annotations["cpu_ms"] = round(cpu_s * 1000.0, 4)
             s.end = t1
             spans.append(s)
         with self._lock:
             tr = self._trace_locked(parent.trace_id)
             for s in spans:
+                if s is None:
+                    continue
                 if len(tr.spans) >= self.max_spans:
                     tr.dropped += 1
                     self.spans_dropped += 1
@@ -323,7 +368,12 @@ class Tracer:
         out = []
         for tr in items:
             spans = list(tr.spans)
-            root = next((s for s in spans if not s.parent_id), None)
+            # The root is the span registered as such (root_ctx), not the
+            # first parentless one: frontdoor.job_register precedes it.
+            root_id = tr.root_ctx.get("span_id", "")
+            root = next((s for s in spans if s.span_id == root_id), None)
+            if root is None and not root_id:
+                root = next((s for s in spans if not s.parent_id), None)
             out.append({
                 "trace_id": tr.trace_id,
                 "spans": len(spans),
@@ -433,25 +483,60 @@ def use_span(span):
 
 class _StageCtx:
     """Slotted stage context: measurably cheaper than a generator-based
-    contextmanager on the per-solve hot path."""
+    contextmanager on the per-solve hot path. With ``cpu`` it reads the
+    thread's CPU clock beside the wall clock: a stage runs on one thread
+    by construction, so wall minus CPU is the time it sat blocked (a
+    lock, the interpreter lock). Asked for where running and waiting
+    cannot be told apart otherwise, not on every cut: the thread CPU
+    clock is a real system call (6-15 us a read on the sandboxed host of
+    the chip, where perf_counter costs 0.09; PERF.md, PR 26)."""
 
-    __slots__ = ("timer", "name", "t0")
+    __slots__ = ("timer", "name", "t0", "cpu0", "slot", "over",
+                 "annotations")
+    live = True  # False on the inert context: skip work done only to annotate
 
-    def __init__(self, timer: "StageTimer", name: str):
+    def __init__(self, timer: "StageTimer", name: str, cpu: bool):
         self.timer = timer
         self.name = name
+        self.cpu0: Optional[float] = 0.0 if cpu else None
+        self.annotations: Optional[Dict[str, Any]] = None
+
+    def annotate(self, key: str, value: Any) -> "_StageCtx":
+        if self.annotations is None:
+            self.annotations = {}
+        self.annotations[key] = value
+        return self
 
     def __enter__(self):
+        st = self.timer
+        # The slot is taken at enter so that ``stages`` is in start order
+        # and an enclosing cut always precedes the cuts inside it.
+        self.slot = len(st.stages)
+        st.stages.append(None)
+        self.over = st.open[-1] if st.open else -1
+        st.open.append(self.slot)
+        if self.cpu0 is not None:
+            self.cpu0 = time.thread_time()
         self.t0 = now()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        self.timer.stages.append((self.name, self.t0, now()))
+        t1 = now()
+        cpu_s = (None if self.cpu0 is None
+                 else time.thread_time() - self.cpu0)
+        st = self.timer
+        st.open.pop()
+        st.stages[self.slot] = (self.name, self.t0, t1, cpu_s, self.over,
+                                self.annotations)
         return False
 
 
 class _NullCtx:
     __slots__ = ()
+    live = False
+
+    def annotate(self, key: str, value: Any) -> "_NullCtx":
+        return self
 
     def __enter__(self):
         return self
@@ -465,23 +550,47 @@ _NULL_CTX = _NullCtx()
 
 class StageTimer:
     """Named, ordered stage cuts (staging / transfer / execute / readback —
-    the same cuts bench.py's breakdown publishes). Stages recorded on any
-    thread; emitted afterwards as child spans + telemetry samples."""
+    the same cuts bench.py's breakdown publishes), with the thread's CPU
+    seconds beside the wall where asked for (``cpu=True``). A cut opened
+    inside another (by name ``staging.mask`` inside ``staging``) is
+    emitted as its child span. One timer belongs to one solve on one
+    thread."""
 
-    __slots__ = ("stages",)
+    __slots__ = ("stages", "open")
 
     def __init__(self):
-        self.stages: List[tuple] = []  # (name, start, end)
+        # (name, start, end, cpu_s or None, slot of the enclosing cut or
+        # -1, annotations or None), in start order; None while a cut is
+        # open.
+        self.stages: List[Optional[tuple]] = []
+        self.open: List[int] = []
 
-    def stage(self, name: str) -> _StageCtx:
-        return _StageCtx(self, name)
+    def stage(self, name: str, cpu: bool = False) -> _StageCtx:
+        return _StageCtx(self, name, cpu)
+
+    def add(self, name: str, t0: float, t1: float, **annotations) -> None:
+        """Record a cut measured elsewhere (another thread's stamps), under
+        the cut open on this thread. It carries no CPU reading."""
+        self.stages.append((name, t0, t1, None,
+                            self.open[-1] if self.open else -1,
+                            annotations or None))
 
     def durations_ms(self) -> Dict[str, float]:
         """Summed per-stage wall in milliseconds."""
         out: Dict[str, float] = {}
-        for name, t0, t1 in self.stages:
-            out[name] = out.get(name, 0.0) + (t1 - t0) * 1000.0
+        for cut in self.stages:
+            if cut is not None:
+                out[cut[0]] = out.get(cut[0], 0.0) + (cut[2] - cut[1]) * 1000.0
         return out
+
+    def wall_cpu_ms(self, name: str) -> Tuple[float, float]:
+        """(wall, CPU) milliseconds summed over the cuts named ``name``."""
+        wall = cpu = 0.0
+        for cut in self.stages:
+            if cut is not None and cut[0] == name:
+                wall += cut[2] - cut[1]
+                cpu += cut[3] or 0.0
+        return wall * 1000.0, cpu * 1000.0
 
     def emit_spans(self, parent, prefix: str = "solver.") -> None:
         """Retroactively record each stage as a child span of ``parent``
@@ -492,12 +601,6 @@ class StageTimer:
         tracer = getattr(parent, "_tracer", None) or get_tracer()
         tracer.record_batch(parent, self.stages, prefix)
 
-    def emit_telemetry(self, key_prefix=("solver",)) -> None:
-        from nomad_tpu import telemetry
-
-        for name, ms in self.durations_ms().items():
-            telemetry.add_sample(tuple(key_prefix) + (name,), ms)
-
 
 class _NullStageTimer(StageTimer):
     """Inert stage timer handed out when no timer is installed: ``stage``
@@ -505,13 +608,13 @@ class _NullStageTimer(StageTimer):
 
     __slots__ = ()
 
-    def stage(self, name: str):
+    def stage(self, name: str, cpu: bool = False):
         return _NULL_CTX
 
-    def emit_spans(self, parent, prefix: str = "solver.") -> None:
+    def add(self, name: str, t0: float, t1: float, **annotations) -> None:
         pass
 
-    def emit_telemetry(self, key_prefix=("solver",)) -> None:
+    def emit_spans(self, parent, prefix: str = "solver.") -> None:
         pass
 
 

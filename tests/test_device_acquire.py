@@ -43,7 +43,8 @@ def test_acquisition_is_in_process(unacquired, monkeypatch):
     import jax
 
     got = acquire_device()
-    assert got == {
+    assert got["acquire_s"] >= 0.0
+    assert {k: v for k, v in got.items() if k != "acquire_s"} == {
         "platform": jax.devices()[0].platform,
         "device_kind": jax.devices()[0].device_kind,
         "count": len(jax.devices()),

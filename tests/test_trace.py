@@ -403,8 +403,10 @@ def test_eval_trace_end_to_end(agent):
             assert s["start"] >= parent["start"] - eps
             if parent["end"] is not None and s["end"] is not None:
                 assert s["end"] <= parent["end"] + eps
-        # Every non-root span links back into the tree.
-        if s["name"] != "eval":
+        # Every non-root span links back into the tree — but for the one
+        # that precedes the root: the front door's handler returns before
+        # (or just as) the broker opens the eval's root.
+        if s["name"] not in ("eval", "frontdoor.job_register"):
             assert s["parent_id"] in ids
 
     # Solver stages nest under the scheduler invocation.
@@ -437,6 +439,60 @@ def test_eval_trace_end_to_end(agent):
         assert e.code == 404
     else:
         pytest.fail("expected 404 for unknown trace")
+
+
+def test_frontdoor_span_precedes_the_root(agent):
+    """Job.Register's own time is in the eval's trace, ahead of the root
+    span the broker opens at enqueue; the listing still names ``eval``."""
+    from nomad_tpu import mock
+    from nomad_tpu.api import ApiClient
+
+    client = ApiClient(address=agent.http.addr)
+    job = mock.job()
+    job.task_groups[0].count = 1
+    job.task_groups[0].tasks[0].driver = "mock_driver"
+    job.task_groups[0].tasks[0].config = {"run_for": "20", "exit_code": "0"}
+    job.task_groups[0].tasks[0].resources.networks = []
+    eval_id, _meta = client.jobs().register(job)
+
+    deadline = time.monotonic() + 60
+    while time.monotonic() < deadline:
+        spans = _get_json(agent, f"/v1/evaluation/{eval_id}/trace")["spans"]
+        by_name = {s["name"]: s for s in spans}
+        if by_name.get("eval", {}).get("end") is not None:
+            break
+        time.sleep(0.1)
+    else:
+        pytest.fail("eval never completed with a finished root span")
+
+    door = by_name["frontdoor.job_register"]
+    root = by_name["eval"]
+    assert door["parent_id"] == "" and door["end"] is not None
+    assert door["start"] <= root["start"]
+    assert door["end"] <= root["start"] + 5e-3
+    assert 0.0 <= door["annotations"]["cpu_ms"] <= door["duration_ms"] + 0.5
+    for child in ("frontdoor.raft_job", "frontdoor.raft_eval"):
+        s = by_name[child]
+        assert s["parent_id"] == door["span_id"]
+        assert door["start"] <= s["start"] <= s["end"] <= door["end"]
+    assert by_name["frontdoor.raft_job"]["end"] <= \
+        by_name["frontdoor.raft_eval"]["start"]
+    # The eval's root opens inside the second apply (FSM -> broker enqueue).
+    raft_eval = by_name["frontdoor.raft_eval"]
+    assert raft_eval["start"] <= root["start"] <= raft_eval["end"] + 5e-3
+
+    # The cuts inside staging and execute ride the same trace.
+    names = {s["name"] for s in spans}
+    assert {"solver.staging.mask", "solver.staging.usage_base",
+            "solver.execute.hold", "solver.execute.launch",
+            "solver.execute.wake", "solver.execute.device_wait"} <= names
+    assert "cpu_ms" in by_name["plan.evaluate"]["annotations"]
+    assert "cpu_ms" in by_name["solver.staging"]["annotations"]
+
+    listing = _get_json(agent, "/v1/agent/traces")
+    entry = next(t for t in listing if t["trace_id"] == eval_id)
+    assert entry["root"] == "eval"
+    assert entry["duration_ms"] == pytest.approx(root["duration_ms"], abs=0.01)
 
 
 def test_agent_metrics_endpoints(agent):
