@@ -14,17 +14,34 @@ and ``weights``), and whether a round repeats (``repeat``):
 - ``{"process": "at_once", "jobs": n}``: n jobs offered as fast as the
   front door takes them. With ``"repeat": "when_placed"`` the next round
   is offered the moment the previous one is fully placed (a closed
-  loop) until ``--seconds`` have passed, and the window closes with the
-  last commit of the round then in flight (a plan of such a job commits
-  thousands of placements at once, so a window cut at a fixed instant
-  would count in steps of whole plans); with ``"never"`` there is one
-  round and the window is ``--seconds`` long.
+  loop) until ``--seconds`` have passed or the cell cannot hold the
+  next round whole, and the window closes with the last commit of the
+  last round offered (a plan of such a job commits thousands of
+  placements at once, so a window cut at a fixed instant would count in
+  steps of whole plans). With ``"never"`` there is one round, and the
+  window closes with its last commit where all of it is placed before
+  ``--seconds`` have passed, and after ``--seconds`` otherwise.
+
+A drain window so ends when its work ends or when ``--seconds`` have
+passed, whichever is first (``end`` in what ``play`` returns:
+``cell_full``, ``drained``, ``deadline``; ``rounds`` where a warm-up's
+``rounds`` key ends it), and the rate is what was committed in it over
+its length. What the cell can hold is reckoned from the configuration
+alone (``reference.free_slots``) less every placement asked so far, the
+warm-up's too (``Player.slots_left``): a round offered and not placed
+still counts as failed, every one of its tasks, and a round is never
+offered to a cell with no room for it. An open loop plays its schedule
+to the end.
 
 ``"preload": true`` (with ``at_once``) registers the round before the
 window opens, while ``hold`` keeps the server's workers from taking
 evaluations, and opens the window by releasing them: the backlog is
 there when the drain starts, and the front door's work of taking 1,000
 registrations does not share the window with the drain.
+
+Before an open loop's window the harness also plays each size of the mix
+once alone (``Player.play_alone``), so that the lone program of every
+size is compiled whichever stacks the warm-up happened to form.
 
 ``node_refresh`` re-registers ``count`` nodes, drawn from the seed,
 every ``every_s`` seconds. ``warmup`` holds the keys that differ while
@@ -91,15 +108,21 @@ def round_plan(mix: Dict, seed: int, seconds: float, round_no: int,
             for k, (off, size) in enumerate(zip(offsets, sizes))]
 
 
+def _sizes(ready: List) -> int:
+    """The placements a built round asks for."""
+    return sum(item["size"] for item, _rec, _payload in ready)
+
+
 class Player:
     """Plays a mix against a server through the fleet's RPC pools and
     records, per job: when it was due, when its register was sent, the
     eval id the server answered with, or the error."""
 
     def __init__(self, fleet, mix: Dict, config: Dict, seed: int,
-                 placed_total: Callable[[], int],
+                 placed_total: Callable[[], int], slots_left: int,
                  hold: Callable[[bool], None] = lambda held: None):
         self.hold = hold
+        self.slots_left = int(slots_left)   # less every round offered
         self.fleet = fleet
         self.mix = mix
         self.config = config
@@ -148,14 +171,15 @@ class Player:
     def play(self, seconds: float, tag: str, overrides: Optional[Dict] = None,
              target_base: int = 0,
              on_open: Callable[[], None] = lambda: None) -> Dict:
-        """Play the mix for ``seconds``. Returns {"opened", "closed",
-        "rounds", "asked"}; per-job records accumulate in ``self.jobs``.
-        ``target_base`` is the watcher's placed total before this play;
-        ``on_open`` is called as the window opens."""
+        """Play the mix for at most ``seconds``. Returns {"opened",
+        "closed", "rounds", "asked", "end"}; per-job records accumulate
+        in ``self.jobs``. ``target_base`` is the watcher's placed total
+        before this play; ``on_open`` is called as the window opens."""
         mix = dict(self.mix)
         mix.update(overrides or {})
         seconds = float(mix.get("seconds", seconds))
         max_rounds = mix.get("rounds")
+        closed_loop = mix.get("repeat", "never") == "when_placed"
         n_senders = max(1, int(mix.get("senders", 4)))
         task = self.config["task"]
         stop = threading.Event()
@@ -166,6 +190,11 @@ class Player:
         round_no = 0
         ready = self._build(
             round_plan(mix, self.seed, seconds, round_no, tag), task, mix)
+        first = _sizes(ready)
+        if first > self.slots_left:
+            raise ValueError(
+                f"{tag}: the first round asks for {first} placements and "
+                f"the cell has {self.slots_left} slots left")
         asked = target_base
 
         def send(opened):
@@ -196,39 +225,72 @@ class Player:
             self.hold(False)
         refresher.start()
         deadline = opened + seconds
-        rounds = 0
+        rounds, end = 0, "deadline"
         while True:
             rounds += 1
             senders = [] if preloaded and rounds == 1 else send(opened)
-            asked += sum(item["size"] for item, _rec, _p in ready)
-            if mix.get("repeat", "never") == "when_placed":
-                # Closed loop: the next round goes out when this one is
-                # placed; it is built while this one is being placed.
+            offered = _sizes(ready)
+            asked += offered
+            self.slots_left -= offered
+            if closed_loop:
+                # The next round goes out when this one is placed; it is
+                # built while this one is being placed.
                 round_no += 1
                 ready = self._build(
                     round_plan(mix, self.seed, seconds, round_no, tag),
                     task, mix)
             for t in senders:
                 t.join()
-            if mix.get("repeat", "never") != "when_placed":
+            if not closed_loop:
                 break
             # The round in flight when the time is up is played out: the
             # window of a closed loop ends on a round's last commit, so
             # that the rate is not cut to whole plans of 12,500.
-            while (self.placed_total() < asked
-                   and time.time() < deadline + ROUND_GRACE_S):
-                time.sleep(0.005)
-            if time.time() >= deadline or (
-                    max_rounds is not None and rounds >= int(max_rounds)):
+            self._placed_by(asked, deadline + ROUND_GRACE_S)
+            if time.time() >= deadline:
                 break
-        remaining = deadline - time.time()
-        if remaining > 0 and max_rounds is None:
-            time.sleep(remaining)
+            if max_rounds is not None and rounds >= int(max_rounds):
+                end = "rounds"
+                break
+            if _sizes(ready) > self.slots_left:
+                end = "cell_full"
+                break
+        if not closed_loop and max_rounds is None:
+            if mix["arrivals"]["process"] == "at_once":
+                # One round: placed whole before the time is up, it ends
+                # the window; the caller closes it at the last commit.
+                if self._placed_by(asked, deadline):
+                    end = "drained"
+            else:
+                time.sleep(max(0.0, deadline - time.time()))
         closed = time.time()
         stop.set()
         refresher.join(timeout=5.0)
         return {"opened": opened, "closed": closed, "rounds": rounds,
-                "asked": asked - target_base}
+                "asked": asked - target_base, "end": end}
+
+    def play_alone(self, tag: str, target_base: int, timeout: float) -> int:
+        """Each size of the mix once, one job at a time, the next when
+        the last is placed; the placements asked. An open loop's jobs
+        meet in a coalesced solve or do not by chance, and a size the
+        warm-up only solved beside another would compile its lone
+        program inside the window."""
+        asked = target_base
+        for k, size in enumerate(sorted(set(self.mix["sizes"]))):
+            plan = [{"offset": 0.0, "id": f"{tag}-{k:05d}", "size": int(size)}]
+            self._send(0.0, self._build(plan, self.config["task"], self.mix))
+            asked += int(size)
+            self.slots_left -= int(size)
+            if not self._placed_by(asked, time.time() + timeout):
+                break
+        return asked - target_base
+
+    def _placed_by(self, asked: int, limit: float) -> bool:
+        """Wait until ``asked`` placements are committed or the clock
+        passes ``limit``; whether they are."""
+        while self.placed_total() < asked and time.time() < limit:
+            time.sleep(0.005)
+        return self.placed_total() >= asked
 
     def _build(self, plan: List[Dict], task: Dict, mix: Dict):
         ready = []

@@ -3,8 +3,8 @@ window. ``snapshot`` flattens them to {name: number}; a metric names one
 (``counter``) and optionally another to divide by (``per``).
 
 Names: ``coalescer.dispatches|coalesced|batch_retries``,
-``coalescer.paths.<path>``, ``panel.<key>`` (SOLVER_PANEL's numeric keys
-and ``panel.compiles``), ``mirror.<key>``, ``pipeline.<key>``,
+``coalescer.paths.<path>``, ``panel.<key>`` (SOLVER_PANEL's numeric
+keys), ``mirror.<key>``, ``pipeline.<key>``,
 ``raft.<msg_type>.bytes`` and ``raft.<msg_type>.entries``, and the
 harness's own ``window.placements`` and ``window.evals``."""
 
@@ -31,7 +31,6 @@ def snapshot(srv) -> Dict[str, float]:
         if (isinstance(v, (int, float)) and not isinstance(v, bool)
                 and "_per_" not in key and not key.endswith("_waste")):
             out[f"panel.{key}"] = v
-    out["panel.compiles"] = panel["compiles"]["total"]
     for width, row in panel["batch_widths"].items():
         out[f"panel.width.{width}"] = row["dispatches"]
     for key, v in GLOBAL_MIRROR_CACHE.stats().items():

@@ -3,11 +3,14 @@ out from the configuration's and the traffic's plain data alone.
 
 Imports nothing of the program and takes nothing the program made but its
 answers. The same nodes and the same jobs give the same verdict whatever
-placed them. It does three things:
+placed them. It does four things:
 
 - ``place``: a straightforward first-fit placement of the jobs on the
   nodes. Its per-job totals are the answers the program's are held to
   (how many tasks of each job can be placed at all).
+- ``free_slots``: how many tasks of one shape the empty cell holds, by
+  the arithmetic ``place`` fills it with. The generator offers a closed
+  loop's next round only where it fits whole.
 - ``compare``: holds a set of answers to the configuration's guarantees:
   every job due has all its placements committed, the state store reads
   back what the event stream committed, every placement sits on a node
@@ -62,6 +65,24 @@ def eligible(node: Dict, job: Dict) -> bool:
             and all(_constraint_holds(node, c) for c in job["constraints"]))
 
 
+def _slots(free_cpu: np.ndarray, free_mem: np.ndarray, mask: np.ndarray,
+           cpu: int, mem: int) -> np.ndarray:
+    """Per node, the tasks of cpu x mem that still fit; 0 off the mask."""
+    slots = np.minimum(free_cpu // max(cpu, 1), free_mem // max(mem, 1))
+    return np.where(mask, np.maximum(slots, 0), 0)
+
+
+def free_slots(nodes: List[Dict], job: Dict) -> int:
+    """The tasks of ``job``'s shape that the empty cell holds: over the
+    nodes that admit the job, min(cpu // job cpu, memory // job memory).
+    ``job["count"]`` is not read."""
+    mask = np.array([eligible(nd, job) for nd in nodes], dtype=bool)
+    return int(_slots(
+        np.array([nd["cpu"] for nd in nodes], dtype=np.int64),
+        np.array([nd["memory_mb"] for nd in nodes], dtype=np.int64),
+        mask, job["cpu"], job["memory_mb"]).sum())
+
+
 def place(nodes: List[Dict], jobs: List[Dict],
           broken: str = "") -> Dict[str, np.ndarray]:
     """First fit, job by job in the order given: {job id: node index of
@@ -92,8 +113,7 @@ def place(nodes: List[Dict], jobs: List[Dict],
             start = (start + count) % n
         else:
             order = np.arange(n)
-        slots = np.minimum(free_cpu // max(cpu, 1), free_mem // max(mem, 1))
-        slots = np.where(mask, np.maximum(slots, 0), 0)[order]
+        slots = _slots(free_cpu, free_mem, mask, cpu, mem)[order]
         if broken == "eligibility":
             # One task per node per lap, as a spreading scheduler would.
             laps = -(-count // n)

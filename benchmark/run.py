@@ -8,10 +8,11 @@ One process. It acquires the chip through the program's own
 checkout), builds the configuration's server on its shipped defaults
 plus what the configuration's file states, registers the fleet, warms up
 by playing the cell's own traffic from a warm-up seed stream, opens the
-window, plays the mix for ``--seconds``, closes, waits for the answers
-that are due, reads the peak of device memory, and holds the answers to
-the plain reference (benchmark/reference.py). ``setup_s`` is process
-start to window open.
+window, plays the mix for ``--seconds`` or until its work ends (a cell
+full, a backlog drained), closes, waits for the answers that are due,
+reads the peak of device memory, and holds the answers to the plain
+reference (benchmark/reference.py). ``setup_s`` is process start to
+window open.
 
 The last line of stdout is the result and nothing else; the fuller
 report is the line before it and a file under benchmark/out/. Without a
@@ -301,6 +302,7 @@ def main(argv=None) -> int:
     from nomad_tpu.server.server import ServerConfig
 
     from benchmark.generators.fleet import Fleet, build_node, node_spec
+    from benchmark.generators.jobs import job_spec
     from benchmark.generators.traffic import Player
     from benchmark.generators.watcher import (
         EventTail,
@@ -354,22 +356,35 @@ def main(argv=None) -> int:
                 w.set_pause(held)
 
         tail = EventTail(srv.fsm.events).start()
+        # What the cell holds of the mix's task, from the configuration
+        # alone; each player takes from it what it offers.
+        slots = reference.free_slots(
+            nodes, job_spec(config["task"], "", mix["job_type"], 0))
         warm = Player(fleet, mix, config, args.seed ^ 0x5EED5EED,
-                      tail.placed_total, hold)
+                      tail.placed_total, slots, hold)
         played = warm.play(args.seconds, "warm", mix.get("warmup"))
         if not wait_until(lambda: tail.placed_total() >= played["asked"],
                           WARMUP_TIMEOUT_S):
             log(f"warm-up placed {tail.placed_total()}/{played['asked']}")
             return 3
         wait_quiet(srv, 60.0)
+        warmed = played["asked"]
+        if mix["arrivals"]["process"] == "poisson":
+            alone = warm.play_alone("lone", tail.placed_total(),
+                                    WARMUP_TIMEOUT_S)
+            warmed += alone
+            if tail.placed_total() < warmed:
+                log(f"warm-up alone placed {tail.placed_total()}/{warmed}")
+                return 3
+            wait_quiet(srv, 60.0)
         warm_widths()
         wait_quiet(srv, 60.0)
-        log(f"warm: {len(warm.jobs)} jobs, {played['asked']} placements")
+        log(f"warm: {len(warm.jobs)} jobs, {warmed} placements")
         gc.collect()
 
         # -- the window ---------------------------------------------------
         player = Player(fleet, mix, config, args.seed, tail.placed_total,
-                        hold)
+                        warm.slots_left, hold)
         n_warm_events = len(tail.events)
         base = tail.placed_total()
         c0 = counters.snapshot(srv)
@@ -389,9 +404,11 @@ def main(argv=None) -> int:
 
         played = player.play(args.seconds, f"s{args.seed}",
                              target_base=base, on_open=on_open)
+        mid.cancel()
         opened, closed = played["opened"], played["closed"]
-        if mix.get("repeat") == "when_placed":
-            # A closed loop's window ends with its last round's last commit.
+        if mix.get("repeat") == "when_placed" or played["end"] == "drained":
+            # A closed loop's window ends with its last round's last commit,
+            # and so does a single round placed whole before the deadline.
             commits = [e.time for e in tail.events[n_warm_events:]
                        if event_placed(e)]
             closed = max(commits) if commits else closed
@@ -399,7 +416,9 @@ def main(argv=None) -> int:
         ready_mid = ready.get("mid")
         c1 = counters.snapshot(srv)
         setup_s = opened - T_START
-        log(f"window {closed - opened:.3f}s closed; set-up {setup_s:.2f}s")
+        log(f"window {closed - opened:.3f}s closed ({played['end']}, "
+            f"{played['rounds']} rounds, {player.slots_left} slots left); "
+            f"set-up {setup_s:.2f}s")
 
         # -- wait for what is due -------------------------------------------
         if mix.get("at_close", "finish") == "pause":
@@ -544,7 +563,8 @@ def main(argv=None) -> int:
             "seconds": window_s, "setup_s": setup_s, "drain_s": drain_s,
             "drained": drained, "compare_s": compare_s,
             "jobs_due": len(due_jobs), "jobs_offered": len(player.jobs),
-            "rounds": played["rounds"], "asked": asked,
+            "rounds": played["rounds"], "window_end": played["end"],
+            "slots_left": player.slots_left, "asked": asked,
             "placed_in_window": in_window, "values": values,
             "window": ctx.window, "counters": ctx.counters,
             "controls": controls, "compared": compared, "device": device,

@@ -6,10 +6,12 @@ They hold: every cell of BENCHMARK.json resolves to its files by name;
 the result line has exactly the contract's keys; work.py's byte counts
 against hand-worked shapes; the xplane reduction on a small recorded
 trace; the traffic plan is reproducible from the seed and offers every
-seed the same work; the control (the reference in the program's place
-with one guarantee broken) comes out not correct; and a run whose timed
-path is broken underneath comes out not correct, once for each fault a
-cell can have.
+seed the same work; a drain window ends when its work ends (no round
+offered to a cell that cannot hold it, a drained backlog closed at its
+last commit); the control (the reference in the program's place with one
+guarantee broken) comes out not correct; and a run whose timed path is
+broken underneath comes out not correct, once for each fault a cell can
+have.
 """
 
 from __future__ import annotations
@@ -17,6 +19,7 @@ from __future__ import annotations
 import json
 import os
 import sys
+import threading
 
 import pytest
 
@@ -183,6 +186,131 @@ def test_apportion_exact_proportions():
     assert sum(traffic.apportion([25, 15, 25, 20, 8, 7], 1201)) == 1201
 
 
+# -- a drain window ends when its work ends -------------------------------------
+
+
+class FakeFleet:
+    """Takes registrations over the one call the player makes. A job
+    counts as placed at once where ``places(job id)`` says so."""
+
+    def __init__(self, places=lambda job_id: True):
+        self.places = places
+        self.placed = 0
+        self.registered = []
+
+    def call(self, method, args):
+        assert method == "Job.Register"
+        job = args["job"]
+        self.registered.append(job["id"])
+        if self.places(job["id"]):
+            self.placed += job["task_groups"][0]["count"]
+        return {"eval_id": "eval-" + job["id"]}
+
+
+def sixteen_nodes():
+    """16 nodes, 15 eligible, 320 tasks each: 4,800 slots; the rehearsal
+    burst's rounds ask for 4 x 300 = 1,200."""
+    config = run.load_json("configs", "rehearsal-256.json")
+    config["nodes"]["count"] = 16
+    nodes = [node_spec(config["nodes"], i) for i in range(16)]
+    mix = run.load_json("traffic", "rehearsal-burst.json")
+    slots = reference.free_slots(
+        nodes, job_spec(config["task"], "", mix["job_type"], 0))
+    return config, mix, slots
+
+
+def warmed(fleet, config, mix, slots):
+    """The window's player, after a warm-up round as run.py plays it."""
+    warm = traffic.Player(fleet, mix, config, 11, lambda: fleet.placed, slots)
+    played = warm.play(30.0, "warm", mix["warmup"])
+    assert played["rounds"] == 1 and played["end"] == "rounds"
+    return traffic.Player(fleet, mix, config, 2_500_000_321,
+                          lambda: fleet.placed, warm.slots_left)
+
+
+def test_closed_loop_offers_no_round_the_cell_cannot_hold():
+    config, mix, slots = sixteen_nodes()
+    assert slots == 4_800
+    fleet = FakeFleet()
+    player = warmed(fleet, config, mix, slots)
+    assert player.slots_left == 3_600
+    played = player.play(30.0, "s", target_base=fleet.placed)
+    assert played["end"] == "cell_full" and played["rounds"] == 3
+    assert played["asked"] == 3_600 and player.slots_left == 0
+    assert played["closed"] - played["opened"] < 10.0   # not the deadline
+    offered = [r["spec"] for r in player.jobs.values() if "due" in r]
+    assert sum(j["count"] for j in offered) == 3_600
+    assert fleet.placed == 4_800         # nothing offered failed
+    assert len(fleet.registered) == 4 + 12
+
+
+def test_round_offered_with_room_and_not_placed_counts_as_failed(monkeypatch):
+    config, mix, slots = sixteen_nodes()
+    monkeypatch.setattr(traffic, "ROUND_GRACE_S", 0.2)
+    fleet = FakeFleet(places=lambda job_id: "-r001-" not in job_id)
+    player = warmed(fleet, config, mix, slots)
+    base = fleet.placed
+    played = player.play(0.5, "s", target_base=base)
+    # The second round had room (2,400 slots) and is never placed: the
+    # loop waits for it past the deadline and offers no third.
+    assert played["end"] == "deadline" and played["rounds"] == 2
+    assert played["asked"] == 2_400 and player.slots_left == 1_200
+    assert played["asked"] - (fleet.placed - base) == 1_200   # run.py's failed
+
+
+def test_first_round_larger_than_the_cell_is_refused():
+    config, mix, slots = sixteen_nodes()
+    fleet = FakeFleet()
+    player = traffic.Player(fleet, mix, config, 5, lambda: fleet.placed, 1_199)
+    with pytest.raises(ValueError, match="has 1199 slots left"):
+        player.play(1.0, "s")
+    assert fleet.registered == []
+
+
+def test_each_size_is_played_once_alone():
+    # An open loop's warm-up: every size is solved with no other job in
+    # flight, so its lone program is compiled before the window. The fake
+    # places a job 30 ms after it registers it.
+    config, _mix, slots = sixteen_nodes()
+    mix = run.load_json("traffic", "rehearsal-steady.json")
+    placed_before = []
+
+    def places(job_id):
+        placed_before.append(fleet.placed)
+        count = player.jobs[job_id]["spec"]["count"]
+        threading.Timer(0.03, lambda: setattr(
+            fleet, "placed", fleet.placed + count)).start()
+        return False
+
+    fleet = FakeFleet(places)
+    player = traffic.Player(fleet, mix, config, 7, lambda: fleet.placed, slots)
+    asked = player.play_alone("lone", 0, 5.0)
+    sizes = sorted(set(mix["sizes"]))
+    assert asked == sum(sizes) == fleet.placed
+    assert [player.jobs[j]["spec"]["count"] for j in fleet.registered] == sizes
+    # Each went out only when all before it were placed.
+    assert placed_before == [sum(sizes[:k]) for k in range(len(sizes))]
+    assert all("due" in rec for rec in player.jobs.values())
+    assert player.slots_left == slots - asked
+
+
+@pytest.mark.parametrize("name,eligible_nodes,slots", [
+    ("cell-10k", 9_375, 3_000_000),      # 10,000 - 625 windows; x 320
+    ("c1m-5k", 4_688, 1_500_160),        # 5,000 - 312 windows; x 320
+])
+def test_free_slots_hand_worked(name, eligible_nodes, slots):
+    config = run.load_json("configs", name + ".json")
+    shape, task = config["nodes"], config["task"]
+    nodes = [node_spec(shape, i) for i in range(shape["count"])]
+    job = job_spec(task, "j", "batch", slots + 1_000)
+    assert sum(reference.eligible(nd, job) for nd in nodes) == eligible_nodes
+    # 32,000 MHz / 100 = 320 by cpu, 65,536 MB / 128 = 512 by memory.
+    assert min(shape["cpu"] // task["cpu"],
+               shape["memory_mb"] // task["memory_mb"]) == 320
+    assert reference.free_slots(nodes, job) == slots == eligible_nodes * 320
+    assert len(reference.place(nodes, [job])["j"]) == slots
+
+
 # -- the plain reference and its control --------------------------------------
 
 
@@ -246,6 +374,11 @@ def drive(capsys, workload="rehearsal-256.rehearsal-steady", seed=2_500_000_321,
     return json.loads(out.out.strip().splitlines()[-1]), out
 
 
+def report_of(out):
+    """The fuller report: the line before the result."""
+    return json.loads(out.out.strip().splitlines()[-2])
+
+
 def test_result_line_has_the_contracts_keys(capsys):
     result, out = drive(capsys)
     assert set(result) == RESULT_KEYS | {"compared"}
@@ -266,9 +399,45 @@ def test_traced_run_reports_per_layer_metrics(capsys):
     result, _ = drive(capsys, "rehearsal-256.rehearsal-burst", trace=1)
     assert result["correct"] is True
     assert {"schedule_solve_mean_ms.drain", "evals_per_dispatch.drain",
-            "compiles_in_window.drain", "plan_verify_mean_ms.drain",
+            "xla_compiles_in_window.drain", "plan_verify_mean_ms.drain",
             "raft_log_bytes_per_placement.drain"} <= set(result["metrics"])
     assert "setup_s" not in result["metrics"]
+
+
+def test_burst_fills_the_cell_and_ends_there(capsys):
+    # 240 eligible nodes x 320 = 76,800 slots, rounds of 4 x 300: the
+    # warm-up takes one, the window the other 63, and no 65th is offered.
+    result, out = drive(capsys, "rehearsal-256.rehearsal-burst", seconds=600.0)
+    report = report_of(out)
+    assert result["correct"] is True and result["failed"] == 0
+    assert result["attempted"] == 63 * 1_200
+    assert report["window_end"] == "cell_full" and report["rounds"] == 63
+    assert report["slots_left"] == 0 and report["seconds"] < 300.0
+    assert report["placed_in_window"] == 63 * 1_200
+
+
+def test_backlog_drained_early_closes_at_its_last_commit(capsys):
+    # 60 jobs x 200 tasks drain in a few seconds of the 120 offered.
+    result, out = drive(capsys, "rehearsal-256.rehearsal-backlog", seconds=120.0)
+    report = report_of(out)
+    assert result["correct"] is True and result["failed"] == 0
+    assert result["attempted"] == 12_000
+    assert report["window_end"] == "drained" and report["rounds"] == 1
+    assert report["slots_left"] == 240 * 320 - 800 - 12_000
+    assert report["seconds"] < 60.0
+    rate = result["metrics"]["placements_per_s"]["value"]
+    # The drain's own rate, not 12,000 over the 120 s offered.
+    assert rate * report["seconds"] == pytest.approx(12_000)
+
+
+def test_backlog_not_drained_closes_at_the_deadline(capsys):
+    result, out = drive(capsys, "rehearsal-256.rehearsal-backlog", seconds=0.5)
+    report = report_of(out)
+    assert result["correct"] is True and result["failed"] == 0
+    assert report["window_end"] == "deadline"
+    assert report["seconds"] == pytest.approx(0.5, abs=0.1)
+    assert report["placed_in_window"] < 12_000
+    assert report["slots_left"] == 240 * 320 - 800 - 12_000   # by what was asked
 
 
 def _break_state_unchanged(monkeypatch):

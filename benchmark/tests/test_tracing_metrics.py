@@ -55,9 +55,10 @@ NEW = {
 
 def test_every_new_metric_belongs_to_a_rehearsed_mix():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        listed = [m["name"] for m in json.load(f)["per_layer"]]
-    new = listed[listed.index("frontdoor_register_mean_ms.steady"):]
-    assert set(new) == set().union(*NEW.values())
+        listed = {m["name"] for m in json.load(f)["per_layer"]}
+    # A later PR adds metrics of its own: no name of theirs has to be
+    # written here.
+    assert set().union(*NEW.values()) <= listed
     for workload, names in NEW.items():
         assert names <= {m["name"] for m in run.Cell(workload).per_layer()}
 
