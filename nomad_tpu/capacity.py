@@ -329,7 +329,7 @@ class CapacityAccountant:
         """Recompute lane usage for ``dirty`` node ids (None = every
         resident node): zero the rows, then one pass over the object
         rows and one over the columnar blocks — O(dirty allocs + total
-        block runs), the mirror's _usage_rows_bulk shape."""
+        block runs)."""
         index_get = self._index.get
         if dirty is None:
             rows = [r for r in self._index.values()]

@@ -46,7 +46,8 @@ WatchItem = Tuple[str, str]
 # None and the consumer falls back to a full rebuild. The node horizon is
 # sized for steady heartbeat/registration churn at 10k nodes; the alloc log
 # holds one entry PER WRITE (a plan commit is one entry carrying its touched
-# node ids), so a smaller entry count covers many plans.
+# node ids and the blocks it added and removed), so a smaller entry count
+# covers many plans.
 NODE_LOG_HORIZON = 4096
 ALLOC_LOG_HORIZON = 1024
 
@@ -65,15 +66,41 @@ def _log_node_change(t: "_Tables", index: int, node_id: str,
         t.node_log = log[-NODE_LOG_HORIZON:]
 
 
-def _log_alloc_nodes(t: "_Tables", index: int, node_ids) -> None:
-    """Append one allocs-table delta: the node ids whose usage this write
-    may have changed (lock held by the caller). One entry per write — a
-    100k-placement plan commit is a single entry sharing the batch's id
-    list, not 10k appends."""
-    if not node_ids:
+class _AllocDelta:
+    """What one allocs-table write did, collected by the write helpers for
+    the change log: ``nodes`` whose usage may have changed (the feed of
+    ``alloc_node_changes_since``), the OBJECT rows it replaced as ``(old,
+    new)`` pairs (``rows``; None for a row that was not there before, or
+    is not there after), and the block objects that came (``added``) and
+    went (``removed``). A replaced block — an exclusion or a whole-block
+    update makes a COW copy — is its old object removed and its new one
+    added. ``opaque`` says the pairs do not tell the whole write: a row
+    was upserted as the very object the table held, so what it was before
+    is not known."""
+
+    __slots__ = ("nodes", "rows", "added", "removed", "opaque")
+
+    def __init__(self) -> None:
+        self.nodes: Set[str] = set()
+        self.rows: List[Tuple[Optional[Allocation],
+                              Optional[Allocation]]] = []
+        self.added: List[StoredAllocBlock] = []
+        self.removed: List[StoredAllocBlock] = []
+        self.opaque = False
+
+
+def _log_alloc_delta(t: "_Tables", index: int, delta: _AllocDelta) -> None:
+    """Append one allocs-table delta (lock held by the caller). One entry
+    per write — a 100k-placement plan commit is a single entry, not 10k
+    appends. The entry pins its rows and block objects until the log
+    trims past it, which is what lets a consumer subtract what has left
+    the table. An opaque write's entry carries None for its rows."""
+    if not (delta.nodes or delta.rows or delta.added or delta.removed):
         return
     log = t.alloc_log
-    log.append((index, tuple(node_ids)))
+    log.append((index, tuple(delta.nodes),
+                None if delta.opaque else tuple(delta.rows),
+                tuple(delta.added), tuple(delta.removed)))
     if len(log) > 2 * ALLOC_LOG_HORIZON:
         t.alloc_log_floor = log[-ALLOC_LOG_HORIZON - 1][0]
         t.alloc_log = log[-ALLOC_LOG_HORIZON:]
@@ -470,7 +497,7 @@ class _Tables:
         # rolling forward from N has complete coverage iff N >= floor.
         self.node_log: List[Tuple[int, str, str]] = []
         self.node_log_floor: int = 0
-        self.alloc_log: List[Tuple[int, Tuple[str, ...]]] = []
+        self.alloc_log: List[Tuple] = []  # _log_alloc_delta's entries
         self.alloc_log_floor: int = 0
 
     def copy(self) -> "_Tables":
@@ -655,24 +682,61 @@ class _StateView:
         out.reverse()
         return out
 
-    def alloc_node_changes_since(self, index: int) -> Optional[Set[str]]:
-        """Node ids whose allocation usage may have changed after
-        ``index`` (up to this view's allocs index), or None past the log
-        horizon. Feeds the mirror's base-usage roll forward."""
+    def _alloc_log_span(self, index: int) -> Optional[List[Tuple]]:
+        """The alloc log's entries after ``index`` up to this view's
+        allocs index, or None past the log horizon."""
         t = self._t
         # List-before-floor read order: see node_changes_since.
         log = t.alloc_log
         if index < t.alloc_log_floor:
             return None
         my = self.get_index("allocs")
-        out: Set[str] = set()
+        out: List[Tuple] = []
         for i in range(len(log) - 1, -1, -1):
             e = log[i]
             if e[0] <= index:
                 break
             if e[0] <= my:
-                out.update(e[1])
+                out.append(e)
         return out
+
+    def alloc_node_changes_since(self, index: int) -> Optional[Set[str]]:
+        """Node ids whose allocation usage may have changed after
+        ``index`` (up to this view's allocs index), or None past the log
+        horizon. Feeds the capacity books' roll forward."""
+        span = self._alloc_log_span(index)
+        if span is None:
+            return None
+        out: Set[str] = set()
+        for e in span:
+            out.update(e[1])
+        return out
+
+    def alloc_changes_since(self, index: int) -> Optional[Tuple[
+            List[Tuple[Optional[Allocation], Optional[Allocation]]],
+            List[StoredAllocBlock], List[StoredAllocBlock]]]:
+        """What came and went after ``index`` (up to this view's allocs
+        index): ``(rows, added, removed)`` — the object rows replaced, as
+        ``(old, new)`` pairs with None for a row not there before or not
+        there after, and the block objects added and removed (a replaced
+        block is both: old object removed, new one added) — or None where
+        the log cannot say: past its horizon, or across a write whose old
+        rows it does not know. Feeds the mirror's advance of the usage
+        base, whose cost is then that of the rows the writes touched,
+        whatever the table holds."""
+        span = self._alloc_log_span(index)
+        if span is None:
+            return None
+        rows: List[Tuple[Optional[Allocation], Optional[Allocation]]] = []
+        added: List[StoredAllocBlock] = []
+        removed: List[StoredAllocBlock] = []
+        for e in span:
+            if e[2] is None:
+                return None
+            rows.extend(e[2])
+            added.extend(e[3])
+            removed.extend(e[4])
+        return rows, added, removed
 
     def alloc_object_by_id(self, alloc_id: str) -> Optional[Allocation]:
         """Object-table row only (no block materialization) — the cheap
@@ -803,8 +867,13 @@ def _decr_live_objs(t: _Tables, job_id: str) -> None:
         t.live_objs_by_job.pop(job_id, None)
 
 
-def _insert_alloc_row(t: _Tables, alloc: Allocation) -> None:
+def _insert_alloc_row(t: _Tables, alloc: Allocation,
+                      delta: Optional[_AllocDelta] = None) -> None:
     prev = t.allocs.get(alloc.id)
+    if delta is not None:
+        delta.rows.append((prev, alloc))
+        if prev is alloc:
+            delta.opaque = True
     if prev is not None and not prev.terminal_status():
         _decr_live_objs(t, prev.job_id)
     if not alloc.terminal_status():
@@ -817,18 +886,25 @@ def _insert_alloc_row(t: _Tables, alloc: Allocation) -> None:
     t.allocs_by_eval.setdefault(alloc.eval_id, set()).add(alloc.id)
 
 
-def _exclude_block_members(t: _Tables, members: Dict[str, Set[int]]) -> None:
+def _exclude_block_members(t: _Tables, members: Dict[str, Set[int]],
+                           delta: Optional[_AllocDelta] = None) -> None:
     """Replace blocks with COW copies excluding ``members`` ({block_id:
     positions}). A block whose exclusion set reaches half its size
     dissolves — remaining members become object rows — so per-member
     promotion cost stays O(n) over a block's whole life instead of the
-    frozenset-union O(n^2)."""
+    frozenset-union O(n^2). ``delta`` (when given) is told which block
+    objects went and came, and the object rows a dissolve made."""
     for bid, positions in members.items():
-        blk = t.blocks[bid].with_excluded(positions)
+        old = t.blocks[bid]
+        blk = old.with_excluded(positions)
         dissolve = blk.n_live == 0 or len(blk.excluded) * 2 >= blk.n
+        if delta is not None:
+            delta.removed.append(old)
+            if not dissolve:
+                delta.added.append(blk)
         if dissolve:
             for alloc in blk.materialize():
-                _insert_alloc_row(t, alloc)
+                _insert_alloc_row(t, alloc, delta)
             del t.blocks[bid]
             for idx_map, key in ((t.blocks_by_job, blk.job_id),
                                  (t.blocks_by_eval, blk.eval_id)):
@@ -842,16 +918,16 @@ def _exclude_block_members(t: _Tables, members: Dict[str, Set[int]]) -> None:
 
 
 def _upsert_allocs(t: _Tables, index: int, allocs: List[Allocation],
-                   touched: Optional[Set[str]] = None) -> None:
-    # ``touched`` (when given) collects the node ids whose usage this
-    # write may change — the live store's alloc change-log feed. Optimistic
-    # snapshot writes pass None and stay out of the shared log.
-    if touched is not None:
+                   delta: Optional[_AllocDelta] = None) -> None:
+    # ``delta`` (when given) collects what this write changes — the live
+    # store's alloc change-log feed. Optimistic snapshot writes pass None
+    # and stay out of the shared log.
+    if delta is not None:
         for alloc in allocs:
-            touched.add(alloc.node_id)
+            delta.nodes.add(alloc.node_id)
             existing = t.allocs.get(alloc.id)
             if existing is not None and existing.node_id != alloc.node_id:
-                touched.add(existing.node_id)
+                delta.nodes.add(existing.node_id)
     # An object row superseding a block member (eviction, re-placement,
     # client-side restamp) promotes it out of the block.
     if t.blocks:
@@ -863,14 +939,14 @@ def _upsert_allocs(t: _Tables, index: int, allocs: List[Allocation],
             if found is not None:
                 bid, pos = found
                 members.setdefault(bid, set()).add(pos)
-                if touched is not None:
+                if delta is not None:
                     # A superseded member's OLD node loses its block
                     # usage — a cross-node restamp must dirty both ends.
-                    touched.add(t.blocks[bid].node_of_pos(pos))
+                    delta.nodes.add(t.blocks[bid].node_of_pos(pos))
                 if alloc.create_index == 0:
                     alloc.create_index = t.blocks[bid].create_index
         if members:
-            _exclude_block_members(t, members)
+            _exclude_block_members(t, members, delta)
     for alloc in allocs:
         existing = t.allocs.get(alloc.id)
         if existing is None:
@@ -886,13 +962,13 @@ def _upsert_allocs(t: _Tables, index: int, allocs: List[Allocation],
             if existing.eval_id != alloc.eval_id:
                 t.allocs_by_eval.get(existing.eval_id, set()).discard(alloc.id)
         alloc.modify_index = index
-        _insert_alloc_row(t, alloc)
+        _insert_alloc_row(t, alloc, delta)
     t.indexes["allocs"] = index
 
 
 def _apply_update_batches(t: _Tables, index: int, batches,
                           watch: "_Watch" = None,
-                          touched: Optional[Set[str]] = None) -> List[WatchItem]:
+                          delta: Optional[_AllocDelta] = None) -> List[WatchItem]:
     """Columnar in-place updates: whole-block field swap when a batch
     covers all live members of a stored block; promotion for partial
     coverage; row re-stamp for object allocs. Returns watch items.
@@ -927,6 +1003,9 @@ def _apply_update_batches(t: _Tables, index: int, batches,
                     b.metrics, b.eval_id, index,
                 )
                 t.blocks[bid] = new_blk
+                if delta is not None:
+                    delta.removed.append(blk)
+                    delta.added.append(new_blk)
                 if new_blk.eval_id != blk.eval_id:
                     ids = t.blocks_by_eval.get(blk.eval_id)
                     if ids is not None:
@@ -950,7 +1029,7 @@ def _apply_update_batches(t: _Tables, index: int, batches,
             else:
                 for pos in positions:
                     object_rows.append(blk.materialize_pos(pos))
-                _exclude_block_members(t, {bid: positions})
+                _exclude_block_members(t, {bid: positions}, delta)
         for existing in object_rows:
             new = existing.copy()
             new.eval_id = b.eval_id
@@ -971,13 +1050,13 @@ def _apply_update_batches(t: _Tables, index: int, batches,
                 ids = t.allocs_by_eval.get(existing.eval_id)
                 if ids is not None:
                     ids.discard(existing.id)
-            _insert_alloc_row(t, new)
+            _insert_alloc_row(t, new, delta)
             stamped_rows.append(new)
     t.indexes["allocs"] = index
-    if touched is not None:
+    if delta is not None:
         for blk in swapped_blks:
-            touched.update(blk.node_ids)
-        touched.update(r.node_id for r in stamped_rows)
+            delta.nodes.update(blk.node_ids)
+        delta.nodes.update(r.node_id for r in stamped_rows)
     if stamped_rows:
         # Container (job/eval) items fire unconditionally, deduped
         # batch-wide: every row of a batch shares its eval id, and job
@@ -1001,7 +1080,7 @@ def _apply_update_batches(t: _Tables, index: int, batches,
 
 def _upsert_alloc_blocks(t: _Tables, index: int, batches,
                          watch: "_Watch" = None,
-                         touched: Optional[Set[str]] = None) -> List[WatchItem]:
+                         delta: Optional[_AllocDelta] = None) -> List[WatchItem]:
     """Commit columnar batches as stored blocks — O(runs), no object
     expansion. Returns the watch items to notify. Per-node items (a block
     touches thousands of nodes) are built only when ``watch`` has
@@ -1020,8 +1099,9 @@ def _upsert_alloc_blocks(t: _Tables, index: int, batches,
         items.append(item_alloc_job(blk.job_id))
         items.append(item_alloc_eval(blk.eval_id))
         committed.append(blk)
-        if touched is not None:
-            touched.update(blk.node_ids)
+        if delta is not None:
+            delta.nodes.update(blk.node_ids)
+            delta.added.append(blk)
     t.indexes["allocs"] = index
     if watch is not None and watch.has_waiters_for("alloc_node"):
         for blk in committed:
@@ -1185,7 +1265,7 @@ class StateStore(_StateView):
         (reference: state_store.go DeleteEval)."""
         items: List[WatchItem] = [item_table("evals"), item_table("allocs")]
         reaped_blocks: List[StoredAllocBlock] = []
-        touched: Set[str] = set()
+        delta = _AllocDelta()
         with self._lock:
             t = self._t
             for eval_id in eval_ids:
@@ -1225,7 +1305,7 @@ class StateStore(_StateView):
                             # Watchers see block-member deletions exactly
                             # like object-row deletions.
                             blk = t.blocks[bid]
-                            touched.add(blk.node_of_pos(pos))
+                            delta.nodes.add(blk.node_of_pos(pos))
                             items.extend(
                                 [
                                     item_alloc(alloc_id),
@@ -1245,7 +1325,8 @@ class StateStore(_StateView):
                         ids.discard(alloc_id)
                         if not ids:
                             del idx_map[key]
-                touched.add(alloc.node_id)
+                delta.nodes.add(alloc.node_id)
+                delta.rows.append((alloc, None))
                 items.extend(
                     [
                         item_alloc(alloc_id),
@@ -1255,10 +1336,11 @@ class StateStore(_StateView):
                     ]
                 )
             if block_members:
-                _exclude_block_members(t, block_members)
+                _exclude_block_members(t, block_members, delta)
             for blk in reaped_blocks:
-                touched.update(blk.node_ids)
-            _log_alloc_nodes(t, index, touched)
+                delta.nodes.update(blk.node_ids)
+            delta.removed.extend(reaped_blocks)
+            _log_alloc_delta(t, index, delta)
             t.indexes["evals"] = index
             t.indexes["allocs"] = index
             # Gated member items, sampled AFTER the index stamps (the
@@ -1273,10 +1355,10 @@ class StateStore(_StateView):
 
     def upsert_allocs(self, index: int, allocs: List[Allocation]) -> None:
         items: List[WatchItem] = [item_table("allocs")]
-        touched: Set[str] = set()
+        delta = _AllocDelta()
         with self._lock:
-            _upsert_allocs(self._t, index, allocs, touched=touched)
-            _log_alloc_nodes(self._t, index, touched)
+            _upsert_allocs(self._t, index, allocs, delta=delta)
+            _log_alloc_delta(self._t, index, delta)
             for alloc in allocs:
                 items.extend(
                     [
@@ -1291,12 +1373,12 @@ class StateStore(_StateView):
     def upsert_alloc_blocks(self, index: int, batches: List[AllocBatch]) -> None:
         """Commit columnar placement batches natively (no per-Allocation
         expansion); blocking queries on the touched nodes/job/eval fire."""
-        touched: Set[str] = set()
+        delta = _AllocDelta()
         with self._lock:
             items = _upsert_alloc_blocks(
-                self._t, index, batches, watch=self.watch, touched=touched,
+                self._t, index, batches, watch=self.watch, delta=delta,
             )
-            _log_alloc_nodes(self._t, index, touched)
+            _log_alloc_delta(self._t, index, delta)
         self.watch.notify(items)
 
     def apply_update_batches(self, index: int, batches) -> None:
@@ -1306,12 +1388,12 @@ class StateStore(_StateView):
         promotes the touched members; object rows re-stamp in place. The
         observable result is exactly the batch's materialize() expansion
         upserted row-wise."""
-        touched: Set[str] = set()
+        delta = _AllocDelta()
         with self._lock:
             items = _apply_update_batches(
-                self._t, index, batches, watch=self.watch, touched=touched,
+                self._t, index, batches, watch=self.watch, delta=delta,
             )
-            _log_alloc_nodes(self._t, index, touched)
+            _log_alloc_delta(self._t, index, delta)
         self.watch.notify(items)
 
     def update_alloc_from_client(self, index: int, alloc: Allocation) -> None:
@@ -1329,6 +1411,10 @@ class StateStore(_StateView):
             t = self._t
             if t.blocks:
                 members: Dict[str, Set[int]] = {}
+                # A promotion moves no usage between nodes (``nodes`` stays
+                # empty) but between a block and the object table: the log
+                # says so, for consumers that keep the two apart.
+                delta = _AllocDelta()
                 for alloc in allocs:
                     if alloc.id in t.allocs:
                         continue
@@ -1336,9 +1422,11 @@ class StateStore(_StateView):
                     if found is not None:
                         bid, pos = found
                         members.setdefault(bid, set()).add(pos)
-                        _insert_alloc_row(t, t.blocks[bid].materialize_pos(pos))
+                        _insert_alloc_row(
+                            t, t.blocks[bid].materialize_pos(pos), delta)
                 if members:
-                    _exclude_block_members(t, members)
+                    _exclude_block_members(t, members, delta)
+                    _log_alloc_delta(t, index, delta)
             missing: List[str] = []
             for alloc in allocs:
                 existing = t.allocs.get(alloc.id)

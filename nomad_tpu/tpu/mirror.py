@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Dict, List, Optional, Set, Tuple
+from typing import Dict, List, NamedTuple, Optional, Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +49,26 @@ def _task_bw(task_resources: Dict[str, Resources]) -> int:
         if res.networks:
             total += res.networks[0].mbits
     return total
+
+
+class _UsageGen(NamedTuple):
+    """The job-independent usage base of one alloc generation: ``used`` /
+    ``bw`` = reserved + every existing allocation (shared with every
+    caller served, never mutated). ``producer`` is the thread that made
+    it."""
+
+    uid: str
+    aidx: int
+    used: np.ndarray
+    bw: np.ndarray
+    producer: int
+
+
+# Generations of the usage base a mirror keeps: evals of one wave hold
+# snapshots a few commits apart, and one behind the newest must not
+# recompute. A generation is a [padded, 4] and a [padded] int32 array,
+# 200 KB at 10k nodes.
+USAGE_RING = 8
 
 
 def _node_row_vals(node: Node) -> Tuple[Tuple, Tuple, int, int]:
@@ -220,34 +240,26 @@ class NodeMirror:
         # device.
         self._device_mask_cache: Dict[Tuple, "jnp.ndarray"] = {}
         self._clean_usage_dev = None
-        # Job-independent base usage (reserved + every existing alloc),
-        # cached per (store_uid, allocs index) and rolled forward through
-        # the store's alloc change log — per-eval usage is a copy of this
-        # plus the plan's in-flight rows, never a cluster walk.
+        # Job-independent base usage (reserved + every existing alloc) per
+        # (store_uid, allocs index): the newest USAGE_RING generations,
+        # oldest first, each advanced from the one before it through the
+        # store's alloc change log — per-eval usage is a copy of one of
+        # these plus the plan's in-flight rows, never a cluster walk.
+        # ``_usage_flights`` names the generations being produced right
+        # now: one thread produces, the others wait for its event
+        # (_base_usage_for). Both under ``_usage_lock``.
         self._usage_lock = threading.Lock()
-        self._base_usage: Optional[Tuple[str, int, np.ndarray, np.ndarray]] = None
+        self._usage_ring: List[_UsageGen] = []
+        self._usage_flights: Dict[Tuple[str, int], threading.Event] = {}
         # id(block) -> (block, rows, counts, vec, bw) of a block's live
-        # runs resolved against THIS mirror's row index: the base-usage
-        # roll folds each block into dirty rows with one scatter instead
-        # of a per-row all-blocks scan. Blocks are COW (exclusions
-        # replace the object) and the entry pins the ref, so identity
-        # keys can never serve stale runs. The dict (and its lock — NOT
-        # _usage_lock, which is per-mirror) is shared across delta-rolled
-        # mirrors and mutated by concurrent scheduler workers.
+        # runs resolved against THIS mirror's row index: the usage base
+        # adds or subtracts a block with one scatter. Blocks are COW
+        # (exclusions replace the object) and the entry pins the ref, so
+        # identity keys can never serve stale runs. The dict (and its
+        # lock — NOT _usage_lock, which is per-mirror) is shared across
+        # delta-rolled mirrors and mutated by concurrent scheduler workers.
         self._block_rows: Dict[int, Tuple] = {}
         self._block_rows_lock = threading.Lock()
-        # Express-lane private usage view (capacity_view): rolled IN
-        # PLACE through the alloc change log — unlike _base_usage (whose
-        # arrays are shared with build_usage callers and must copy per
-        # generation), this one is owned by the view and a 10k-row copy
-        # per express submission would be the dominant cost of the
-        # sub-millisecond path. (uid, allocs index, used, bw). The roll
-        # serializes on its own lock (NOT _usage_lock — the rebuild
-        # fallback calls _base_usage_for, which takes that): two
-        # concurrent rolls toward different generations would leave
-        # rows at mixed generations under a single cached index.
-        self._express_usage: Optional[Tuple] = None
-        self._express_roll_lock = threading.Lock()
 
     # -- byte economy ------------------------------------------------------
 
@@ -281,9 +293,14 @@ class NodeMirror:
                            "_device_mask_cache"):
             cache = getattr(self, cache_name, None) or {}
             cache_bytes += sum(_arr_bytes(v) for v in cache.values())
-        for extra in ("_clean_usage_dev", "_base_usage", "_express_usage",
-                      "_id_array"):
+        for extra in ("_clean_usage_dev", "_id_array"):
             cache_bytes += _arr_bytes(getattr(self, extra, None))
+        # Generations share arrays (a write that moved the index and no
+        # usage): count each array once.
+        with self._usage_lock:
+            ring_arrays = {id(a): a for gen in self._usage_ring
+                           for a in (gen.used, gen.bw)}
+        cache_bytes += sum(int(a.nbytes) for a in ring_arrays.values())
         buffer_bytes = sum(b["nbytes"] for b in buffers.values())
         return {
             "rows": self.n,
@@ -373,10 +390,7 @@ class NodeMirror:
         new.n = new_n
         new.padded = self.padded
         new._usage_lock = threading.Lock()
-        # Node writes are the rare axis: the express view rebuilds lazily
-        # from the rolled base on its next read.
-        new._express_usage = None
-        new._express_roll_lock = threading.Lock()
+        new._usage_flights = {}
         # Row numbering of resident nodes never moves on the delta path
         # (a departure forces the full rebuild above) and appends are
         # brand-new nodes no existing block can reference: cached block
@@ -494,20 +508,23 @@ class NodeMirror:
         else:
             new._clean_usage_dev = self._clean_usage_dev
 
-        # Node writes never move the allocs index, so the cached base
-        # usage survives modulo the reserved deltas of the patched rows.
-        base = self._base_usage
-        if base is None or appends:
-            new._base_usage = None
+        # Node writes never move the allocs index, so the cached usage
+        # generations survive modulo the reserved deltas of the patched
+        # rows. Appended rows are outside what the alloc log describes.
+        with self._usage_lock:
+            ring = list(self._usage_ring)
+        if appends:
+            ring = []
         elif reserved_changed:
-            uid, aidx, b_used, b_bw = base
-            b_used = b_used.copy()
-            b_bw = b_bw.copy()
-            b_used[rows_arr] += res_arr - self.reserved_np[rows_arr]
-            b_bw[rows_arr] += bwr_arr - self.bw_reserved[rows_arr]
-            new._base_usage = (uid, aidx, b_used, b_bw)
-        else:
-            new._base_usage = base
+            d_used = res_arr - self.reserved_np[rows_arr]
+            d_bw = bwr_arr - self.bw_reserved[rows_arr]
+            for k, gen in enumerate(ring):
+                g_used = gen.used.copy()
+                g_bw = gen.bw.copy()
+                g_used[rows_arr] += d_used
+                g_bw[rows_arr] += d_bw
+                ring[k] = gen._replace(used=g_used, bw=g_bw)
+        new._usage_ring = ring
         return new, len(rows)
 
     def id_array(self) -> np.ndarray:
@@ -842,98 +859,111 @@ class NodeMirror:
     def capacity_view(self, state) -> Tuple[np.ndarray, np.ndarray]:
         """(totals[padded,4] int32, used[padded,4] int32) — the express
         lane's leader-local capacity view: per-row totals next to the
-        delta-rolled job-independent base usage (reserved + every
-        existing allocation) for ``state``'s alloc generation. The SAME
-        per-row accounting the solver's build_usage starts from
-        (_usage_rows_bulk / _compute_base_usage), so an express fit
-        check and a slow-path verify read one truth (reservation debits
-        ride the express ledger on top, not these arrays).
-
-        Unlike ``_base_usage_for`` this view is mirror-private and rolls
-        IN PLACE (no per-generation array copy — a 10k-row copy per
-        submission would dominate the sub-millisecond path). Arrays are
-        SHARED with the view — callers must not mutate, and concurrent
-        submissions serialize on the lane's own lock."""
-        uid = getattr(state, "store_uid", "")
-        aidx = state.get_index("allocs")
-        if not uid or getattr(state, "optimistic", False):
-            used, _bw = self._base_usage_for(state)
-            return self.totals_np, used
-        with self._express_roll_lock:
-            cached = self._express_usage
-            if (cached is not None and cached[0] == uid
-                    and cached[1] == aidx):
-                return self.totals_np, cached[2]
-            used = bw = None
-            if (cached is not None and cached[0] == uid
-                    and aidx > cached[1]
-                    and hasattr(state, "alloc_node_changes_since")):
-                dirty = state.alloc_node_changes_since(cached[1])
-                if dirty is not None and len(dirty) <= max(1024,
-                                                           self.n // 2):
-                    used, bw = cached[2], cached[3]
-                    if dirty:
-                        self._usage_rows_bulk(state, dirty, used, bw)
-            if used is None:
-                base_used, base_bw = self._base_usage_for(state)
-                used, bw = base_used.copy(), base_bw.copy()
-            self._express_usage = (uid, aidx, used, bw)
+        job-independent base usage (reserved + every existing
+        allocation) for ``state``'s alloc generation. It IS the base
+        the solver's build_usage starts from (_base_usage_for), so an
+        express fit check and a slow-path verify read one truth
+        (reservation debits ride the express ledger on top, not these
+        arrays). Arrays are SHARED with every other reader of that
+        generation — callers must not mutate."""
+        used, _bw = self._base_usage_for(state)
         return self.totals_np, used
 
     def _base_usage_for(self, state, cut=None) -> Tuple[np.ndarray, np.ndarray]:
-        """The cached job-independent (used, bw_used) base for ``state``'s
-        alloc generation: reserved + every existing allocation. On a
-        generation mismatch the base rolls forward through the store's
-        alloc change log (recomputing only the dirty rows); a dirty set
-        past the log horizon — or large enough that per-row python beats
-        nothing — falls back to one full recompute. Returned arrays are
-        shared and must be copied before mutation. Which way it went is
-        noted on ``cut`` (the caller's stage cut): ``path`` = hit / roll /
-        rebuild, ``dirty_rows``, ``blocks``; rolls and rebuilds are
-        counted (telemetry, and GLOBAL_MIRROR_CACHE's ``usage_*``)."""
+        """The job-independent (used, bw_used) base for ``state``'s alloc
+        generation: reserved + every existing allocation. Each
+        generation is produced ONCE, by one thread, from the nearest
+        older generation the mirror holds, by adding what the store's
+        alloc change log says was committed since and subtracting what
+        left (``_advance_usage``: the cost of the rows those writes
+        touched, whatever the table holds). A thread that finds another
+        producing its generation waits for it and shares the arrays; a
+        producer that raises releases its waiters and the next of them
+        produces. A full recompute is left for what the log cannot
+        describe: first fill, a source behind the log's horizon or a
+        restored store, a write whose old rows the log does not know, a
+        mirror rebuilt with appended rows (apply_delta drops the ring),
+        and anonymous or optimistic states, which are never cached.
+        Returned arrays are shared and must be copied before mutation.
+        Which way it went is noted on ``cut`` (the caller's stage cut):
+        ``path`` = hit / shared / roll / rebuild, ``dirty_rows``,
+        ``blocks``; rolls, rebuilds and shared serves are counted
+        (``MirrorCache.count_usage``)."""
         uid = getattr(state, "store_uid", "")
         aidx = state.get_index("allocs")
         if not uid or getattr(state, "optimistic", False):
             # Anonymous states and optimistically-mutated snapshots name
             # content the shared change logs don't describe: never roll
             # from them, never cache them.
-            _note_usage(cut, "rebuild", state)
+            _note_usage(cut, "rebuild", len(state.alloc_blocks()))
             return self._compute_base_usage(state)
-        with self._usage_lock:
-            cached = self._base_usage
-        if cached is not None and cached[0] == uid and cached[1] == aidx:
+        key = (uid, aidx)
+        me = threading.get_ident()
+        waited = False
+        while True:
+            with self._usage_lock:
+                ring = self._usage_ring
+                gen = next((g for g in ring
+                            if g.aidx == aidx and g.uid == uid), None)
+                if gen is not None:
+                    newest = gen is ring[-1]
+                    break
+                flight = self._usage_flights.get(key)
+                if flight is None:
+                    flight = self._usage_flights[key] = threading.Event()
+                    # Nearest older generation of this store, if any.
+                    src = next((g for g in reversed(ring)
+                                if g.aidx < aidx and g.uid == uid), None)
+                    break
+            # Another thread is producing this generation. Its ``finally``
+            # sets the event whether it made the base or raised; the ring
+            # says which.
+            flight.wait()
+            waited = True
+        if gen is not None:
+            if waited or (newest and gen.producer != me):
+                _count_usage("shared")
+                _note_usage(cut, "shared")
+            else:
+                _note_usage(cut, "hit")
+            return gen.used, gen.bw
+        try:
+            gen = self._produce_usage(state, uid, aidx, src, cut)
+            with self._usage_lock:
+                ring = [g for g in self._usage_ring if g.uid == uid]
+                ring.append(gen)
+                ring.sort(key=lambda g: g.aidx)
+                self._usage_ring = ring[-USAGE_RING:]
+        finally:
+            with self._usage_lock:
+                del self._usage_flights[key]
+            flight.set()
+        return gen.used, gen.bw
+
+    def _produce_usage(self, state, uid: str, aidx: int,
+                       src: Optional[_UsageGen], cut) -> _UsageGen:
+        """The usage base of ``state``'s generation: advanced from
+        ``src`` where the alloc log reaches back to it, recomputed
+        otherwise."""
+        delta = None
+        changes_fn = getattr(state, "alloc_changes_since", None)
+        if src is not None and changes_fn is not None:
+            delta = changes_fn(src.aidx)
+        if delta is None:
+            arrays = self._compute_base_usage(state)
+            _count_usage("rebuild")
+            _note_usage(cut, "rebuild", len(state.alloc_blocks()))
+        elif not any(delta):
+            # The index moved and no usage did (a client status update).
+            arrays = src.used, src.bw
             _note_usage(cut, "hit")
-            return cached[2], cached[3]
-        used = bw = None
-        dirty = None
-        if (cached is not None and cached[0] == uid and aidx > cached[1]
-                and hasattr(state, "alloc_node_changes_since")):
-            dirty = state.alloc_node_changes_since(cached[1])
-            # The bulk roll is O(dirty + touched block runs), so it beats
-            # the full recompute for much larger dirty sets than the old
-            # per-row scan did (a 12.5k-placement burst commit dirties
-            # thousands of rows at once).
-            if dirty is not None and len(dirty) <= max(1024, self.n // 2):
-                if dirty:
-                    used = cached[2].copy()
-                    bw = cached[3].copy()
-                    self._usage_rows_bulk(state, dirty, used, bw)
-                    telemetry.incr_counter(("mirror", "usage_rolls"))
-                    GLOBAL_MIRROR_CACHE.count_usage(rolls=1)
-                    _note_usage(cut, "roll", state, len(dirty))
-                else:
-                    used, bw = cached[2], cached[3]
-                    _note_usage(cut, "hit")
-        if used is None:
-            used, bw = self._compute_base_usage(state)
-            telemetry.incr_counter(("mirror", "usage_rebuilds"))
-            GLOBAL_MIRROR_CACHE.count_usage(rebuilds=1)
-            _note_usage(cut, "rebuild", state, len(dirty or ()))
-        with self._usage_lock:
-            prev = self._base_usage
-            if prev is None or prev[0] != uid or prev[1] <= aidx:
-                self._base_usage = (uid, aidx, used, bw)
-        return used, bw
+        else:
+            rows, added, removed = delta
+            arrays, dirty_rows = self._advance_usage(
+                src, rows, added, removed)
+            _count_usage("roll")
+            _note_usage(cut, "roll", len(added) + len(removed), dirty_rows)
+        return _UsageGen(uid, aidx, *arrays, threading.get_ident())
 
     def _block_rows_for(self, blk):
         """(rows, counts, vec4, bw) of a block's live runs resolved
@@ -968,52 +998,62 @@ class NodeMirror:
                 cache.pop(next(iter(cache)))
         return rows, counts, vec, bw
 
-    def _usage_rows_bulk(self, state, dirty, used, bw) -> None:
-        """Recompute the ``dirty`` nodes' rows of the base-usage arrays
-        in place: reserved base, their object rows, then ONE masked
-        scatter per block restricted to dirty rows. Replaces the old
-        per-dirty-row walk whose cost was O(dirty x blocks) python — the
-        dominant per-eval term once a run had committed a few dozen
-        columnar blocks."""
-        index_get = self.index.get
-        rows_l: List[int] = []
-        nids_l: List[str] = []
-        for nid in dirty:
-            i = index_get(nid)
-            if i is not None:
-                rows_l.append(i)
-                nids_l.append(nid)
-        if not rows_l:
-            return
-        rows_arr = np.asarray(rows_l, dtype=np.int64)
-        used[rows_arr] = self.reserved_np[rows_arr]
-        bw[rows_arr] = self.bw_reserved[rows_arr]
-        for nid, i in zip(nids_l, rows_l):
-            for a in state.allocs_by_node_objects(nid):
-                if a.terminal_status():
-                    continue
-                used[i] += _res_vec(a.resources)
-                bw[i] += _task_bw(a.task_resources)
-        in_dirty = np.zeros(self.padded, dtype=bool)
-        in_dirty[rows_arr] = True
-        for blk in state.alloc_blocks():
-            b_rows, b_counts, vec, b_bw = self._block_rows_for(blk)
-            if not b_rows.size:
-                continue
-            m = in_dirty[b_rows]
-            if not m.any():
-                continue
-            hit_rows = b_rows[m]
-            hit_counts = b_counts[m]
-            # live_counts_map already summed duplicate runs per node, so
-            # hit rows are unique within a block: plain fancy-index adds.
-            used[hit_rows] += vec[None, :] * hit_counts[:, None]
+    def _scatter_block(self, blk, sign: int, used, bw) -> int:
+        """Add (``sign`` 1) or subtract (-1) a block's live runs in
+        place; the rows it touched."""
+        rows, counts, vec, b_bw = self._block_rows_for(blk)
+        if rows.size:
+            # live_counts_map summed duplicate runs per node, so a block's
+            # rows are unique: plain fancy-index adds.
+            used[rows] += sign * vec[None, :] * counts[:, None]
             if b_bw:
-                bw[hit_rows] += b_bw * hit_counts
+                bw[rows] += sign * b_bw * counts
+        return int(rows.size)
+
+    def _advance_usage(self, src: _UsageGen, rows, added, removed):
+        """``src`` advanced by what came and went since: one scatter per
+        block added or removed (a replaced block is both), and each
+        replaced object row's old usage taken out and its new one put in.
+        Integer arithmetic throughout, so the result is bit for bit what
+        ``_compute_base_usage`` gives on the newer state. Returns
+        ((used, bw), rows touched)."""
+        used = src.used.copy()
+        bw = src.bw.copy()
+        dirty_rows = 0
+        for blk in added:
+            dirty_rows += self._scatter_block(blk, 1, used, bw)
+        for blk in removed:
+            dirty_rows += self._scatter_block(blk, -1, used, bw)
+        # The object rows' net change per mirror row, summed in Python
+        # ints (a commit of small jobs is a handful of rows), then one
+        # fancy-index add over the unique rows.
+        index_get = self.index.get
+        net: Dict[int, List[int]] = {}
+        for pair in rows:
+            for a, sign in zip(pair, (-1, 1)):
+                if a is None or a.terminal_status():
+                    continue
+                i = index_get(a.node_id)
+                if i is None:
+                    continue
+                acc = net.get(i)
+                if acc is None:
+                    acc = net[i] = [0, 0, 0, 0, 0]
+                if a.resources is not None:
+                    for d, v in enumerate(a.resources.as_vector()):
+                        acc[d] += sign * v
+                acc[4] += sign * _task_bw(a.task_resources)
+        if net:
+            at = np.fromiter(net, dtype=np.int64, count=len(net))
+            d = np.array(list(net.values()), dtype=np.int32)
+            used[at] += d[:, :4]
+            bw[at] += d[:, 4]
+        return (used, bw), dirty_rows + len(net)
 
     def _compute_base_usage(self, state) -> Tuple[np.ndarray, np.ndarray]:
-        """Full base recompute: reserved + all object rows + all block
-        runs. The delta path's fallback (and first fill)."""
+        """Full base recompute: reserved + all object rows + one scatter
+        per block. First fill, and what the alloc log cannot describe
+        (_base_usage_for)."""
         used = self.reserved_np.copy()
         bw = self.bw_reserved.copy()
         index_get = self.index.get
@@ -1026,15 +1066,7 @@ class NodeMirror:
             used[i] += _res_vec(a.resources)
             bw[i] += _task_bw(a.task_resources)
         for blk in state.alloc_blocks():
-            vec = _res_vec(blk.resources)
-            tbw = _task_bw(blk.task_resources)
-            for nid, cnt in blk.live_node_counts():
-                i = index_get(nid)
-                if i is None:
-                    continue
-                used[i] += vec * cnt
-                if tbw:
-                    bw[i] += tbw * cnt
+            self._scatter_block(blk, 1, used, bw)
         return used, bw
 
     def _build_usage_walk(self, ctx, job_id: str, tg_name: str):
@@ -1187,16 +1219,30 @@ class NodeMirror:
                     used[i] += delta.astype(np.int32)
 
 
-def _note_usage(cut, path: str, state=None, dirty_rows: int = 0) -> None:
-    """Note on a live stage cut how the usage base was served; the
-    block count is read for the note alone."""
+def _note_usage(cut, path: str, blocks: Optional[int] = None,
+                dirty_rows: int = 0) -> None:
+    """Note on a live stage cut how the usage base was served: for a
+    rebuild the blocks walked, for a roll the blocks added and removed
+    and the rows they and the object writes touched."""
     if cut is None or not cut.live:
         return
     cut.annotate("path", path)
-    if state is not None:
-        cut.annotate("blocks", len(state.alloc_blocks()))
+    if blocks is not None:
+        cut.annotate("blocks", blocks)
         if dirty_rows:
             cut.annotate("dirty_rows", dirty_rows)
+
+
+# The counter of each way the usage base is served that is not a plain hit.
+_USAGE_COUNTERS = {"roll": "usage_rolls", "rebuild": "usage_rebuilds",
+                   "shared": "usage_shared"}
+
+
+def _count_usage(path: str) -> None:
+    """Count one serve of the usage base by ``path``, for the scrape and
+    for GLOBAL_MIRROR_CACHE's ``usage_*``."""
+    telemetry.incr_counter(("mirror", _USAGE_COUNTERS[path]))
+    GLOBAL_MIRROR_CACHE.count_usage(path)
 
 
 class MirrorCache:
@@ -1239,11 +1285,21 @@ class MirrorCache:
         # does not know the cache it came from.
         self.usage_rolls = 0
         self.usage_rebuilds = 0
+        self.usage_shared = 0
 
-    def count_usage(self, rolls: int = 0, rebuilds: int = 0) -> None:
+    def count_usage(self, path: str) -> None:
+        """One serve of the usage base by ``path``: ``roll``, a
+        generation produced by delta from an older one; ``rebuild``, one
+        produced by full recompute; ``shared``, a solve served by a base
+        that ANOTHER thread produced for that same generation — it
+        waited for that production, or found it ready while it was the
+        newest generation the mirror held, where before this counter
+        each such solve could have produced the base again. A hit on a
+        generation the thread produced itself, or on an older one, is a
+        plain hit and counts nowhere."""
+        name = _USAGE_COUNTERS[path]
         with self._lock:
-            self.usage_rolls += rolls
-            self.usage_rebuilds += rebuilds
+            setattr(self, name, getattr(self, name) + 1)
 
     def get(self, state, datacenters: List[str]):
         """Return (nodes, mirror) for the ready nodes of ``state`` in
@@ -1348,7 +1404,7 @@ class MirrorCache:
     def stats(self) -> dict:
         """Debug-surface snapshot: residency, hit ratio, and the delta
         economy (rolls vs full rebuilds, rows re-staged), and the usage
-        base's own rolls and rebuilds."""
+        base's own rolls, rebuilds and shared serves (count_usage)."""
         with self._lock:
             return {
                 "entries": len(self._entries),
@@ -1365,6 +1421,7 @@ class MirrorCache:
                 }),
                 "usage_rolls": self.usage_rolls,
                 "usage_rebuilds": self.usage_rebuilds,
+                "usage_shared": self.usage_shared,
             }
 
     def byte_ledger(self) -> dict:

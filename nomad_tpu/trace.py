@@ -26,7 +26,7 @@ running and waiting cannot be told apart otherwise:
 - ``solver.staging.mask``       task_group_constraints (tpu/solver) and
                                 device_mask, ``cached`` (tpu/mirror)
 - ``solver.staging.usage_base`` the job-independent usage base; ``path`` =
-                                hit/roll/rebuild/clean, ``dirty_rows``,
+                                hit/shared/roll/rebuild/clean, ``dirty_rows``,
                                 ``blocks`` (tpu/mirror build_usage)
 - ``solver.staging.usage_job``  base copy + the job's own rows + plan deltas;
                                 ``plan_batches`` (tpu/mirror build_usage)

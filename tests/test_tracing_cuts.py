@@ -85,7 +85,7 @@ def test_an_open_cut_is_left_out_and_the_rest_emitted():
 # -- mirror: how the usage base was served ------------------------------------
 
 
-N_NODES = 1100   # the roll gives way to a rebuild past max(1024, n // 2) rows
+N_NODES = 1100   # enough for one commit to dirty over 1,024 rows, once the limit of a roll
 
 
 @pytest.fixture(scope="module")
@@ -117,6 +117,7 @@ def _allocs_on(nodes):
 def test_base_usage_counts_hit_roll_and_rebuild(usage_store):
     from nomad_tpu.scheduler.context import EvalContext
     from nomad_tpu.scheduler.util import ready_nodes_in_dcs
+    from nomad_tpu.state.store import ALLOC_LOG_HORIZON
     from nomad_tpu.tpu.mirror import GLOBAL_MIRROR_CACHE, NodeMirror
 
     store, nodes = usage_store
@@ -142,7 +143,7 @@ def test_base_usage_counts_hit_roll_and_rebuild(usage_store):
             assert {"staging.usage_job", "staging.upload"} <= set(cuts)
             assert "plan_batches" in cuts["staging.usage_job"][5]
         added = {k: after[k] - before[k]
-                 for k in ("usage_rolls", "usage_rebuilds")}
+                 for k in ("usage_rolls", "usage_rebuilds", "usage_shared")}
         return base[5], added
 
     ann, added = staged("clean")
@@ -153,7 +154,8 @@ def test_base_usage_counts_hit_roll_and_rebuild(usage_store):
     store.upsert_allocs(index + 1, _allocs_on(nodes[:3]))
     ann, added = staged("rebuild")
     assert ann["path"] == "rebuild" and "blocks" in ann
-    assert added == {"usage_rolls": 0, "usage_rebuilds": 1}
+    assert added == {"usage_rolls": 0, "usage_rebuilds": 1,
+                     "usage_shared": 0}
 
     ann, added = staged("hit")
     assert ann == {"path": "hit"}
@@ -162,13 +164,26 @@ def test_base_usage_counts_hit_roll_and_rebuild(usage_store):
     store.upsert_allocs(index + 2, _allocs_on(nodes[10:15]))
     ann, added = staged("roll")
     assert ann["path"] == "roll" and ann["dirty_rows"] == 5
-    assert added == {"usage_rolls": 1, "usage_rebuilds": 0}
+    assert ann["blocks"] == 0
+    assert added == {"usage_rolls": 1, "usage_rebuilds": 0,
+                     "usage_shared": 0}
 
-    # One commit that dirties more than max(1024, n // 2) rows.
+    # One commit that dirties more than half the rows is a roll like any
+    # other: the advance costs what the commit touched.
     store.upsert_allocs(index + 3, _allocs_on(nodes[:1030]))
+    ann, added = staged("roll")
+    assert ann["path"] == "roll" and ann["dirty_rows"] == 1030
+    assert added == {"usage_rolls": 1, "usage_rebuilds": 0,
+                     "usage_shared": 0}
+
+    # Past the log's horizon the log cannot say what came and went.
+    for k in range(2 * ALLOC_LOG_HORIZON + 1):
+        store.upsert_allocs(index + 4 + k, _allocs_on(nodes[k % 7:k % 7 + 1]))
     ann, added = staged("rebuild")
-    assert ann["path"] == "rebuild" and ann["dirty_rows"] == 1030
-    assert added == {"usage_rolls": 0, "usage_rebuilds": 1}
+    assert ann["path"] == "rebuild" and ann["blocks"] == 0
+    assert "dirty_rows" not in ann
+    assert added == {"usage_rolls": 0, "usage_rebuilds": 1,
+                     "usage_shared": 0}
 
 
 # -- coalescer: the dispatcher's stamps and the rider's cuts -------------------
@@ -405,7 +420,7 @@ def test_scalar_reasons_sum_to_scalar_plans():
     ("panel", ("staging_wall_ms", "staging_cpu_ms", "staging_blocked_ms",
                "xla_compiles", "xla_compile_ms", "xla_cache_loads",
                "xla_cache_load_ms")),
-    ("mirror", ("usage_rolls", "usage_rebuilds")),
+    ("mirror", ("usage_rolls", "usage_rebuilds", "usage_shared")),
     ("pipeline", ("scalar_lone", "scalar_ineligible", "scalar_object_rows",
                   "scalar_unfit")),
 ])
