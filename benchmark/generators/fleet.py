@@ -10,10 +10,11 @@ client-facing surface is used: ``Node.BatchRegister`` and
 
 from __future__ import annotations
 
+import functools
 import heapq
 import threading
 import time
-from typing import Dict, List, Sequence
+from typing import Dict, List, Sequence, Tuple
 
 from nomad_tpu import structs
 from nomad_tpu.api.codec import to_dict
@@ -27,18 +28,57 @@ def node_id(i: int) -> str:
     return f"sim-{i:05d}"
 
 
+def node_shapes(shape: Dict) -> List[Dict]:
+    """The machine table of a configuration's ``nodes`` group: its
+    ``shapes`` (each ``{count, cpu, memory_mb, attributes}``), or, where
+    ``count``, ``cpu`` and ``memory_mb`` stand on the group itself, that
+    one shape."""
+    return shape.get("shapes") or [shape]
+
+
+def node_count(shape: Dict) -> int:
+    """The fleet's size: the sum of its shapes' counts."""
+    return sum(int(s["count"]) for s in node_shapes(shape))
+
+
+@functools.lru_cache(maxsize=8)
+def deal_shapes(counts: Tuple[int, ...]) -> Tuple[int, ...]:
+    """Which shape each node index gets, by largest remainders and no
+    seed: index ``i`` goes to the shape that is furthest behind its share
+    of the first ``i + 1`` indices (``count x (i + 1) / n`` less what it
+    has been dealt; the earlier in the table on a tie). Every shape so
+    holds its share of every prefix to within a node or two, and any run
+    of consecutive indices holds the shapes in their proportions to
+    within three: first fit does not meet all the large machines first."""
+    n = sum(counts)
+    dealt = [0] * len(counts)
+    out = []
+    for k in range(1, n + 1):
+        s = max(range(len(counts)),
+                key=lambda j: (counts[j] * k - dealt[j] * n, -j))
+        dealt[s] += 1
+        out.append(s)
+    return tuple(out)
+
+
 def node_spec(shape: Dict, i: int) -> Dict:
     """Node ``i`` of a configuration's fleet as plain data: what the
     server is told and what the plain reference judges eligibility and
-    capacity by. ``shape`` is the configuration file's ``nodes`` group."""
+    capacity by. ``shape`` is the configuration file's ``nodes`` group;
+    the node's own shape is the one ``deal_shapes`` gives index ``i``.
+    Its attributes are the group's, then its shape's, then those of each
+    variant that takes it."""
     dcs = shape["datacenters"]
-    attrs = dict(shape["attributes"])
+    shapes = node_shapes(shape)
+    own = shapes[deal_shapes(tuple(int(s["count"]) for s in shapes))[i]]
+    attrs = dict(shape.get("attributes", {}))
+    attrs.update(own.get("attributes", {}))
     for variant in shape.get("variants", ()):
         if i % int(variant["every"]) == int(variant["every"]) - 1:
             attrs.update(variant["attributes"])
     return {"id": node_id(i), "datacenter": dcs[i % len(dcs)],
-            "attributes": attrs, "cpu": int(shape["cpu"]),
-            "memory_mb": int(shape["memory_mb"]), "ready": True}
+            "attributes": attrs, "cpu": int(own["cpu"]),
+            "memory_mb": int(own["memory_mb"]), "ready": True}
 
 
 def build_node(shape: Dict, spec: Dict) -> Node:

@@ -2,8 +2,8 @@
 ``benchmark/traffic/``; this module turns (mix, seed, seconds) into a
 plan of rounds and plays it against the server's RPC front door.
 
-A mix says how jobs arrive (``arrivals``), how large they are (``sizes``
-and ``weights``), and whether a round repeats (``repeat``):
+A mix says how jobs arrive (``arrivals``), what they are (``sizes`` and
+``weights``, or ``templates``), and whether a round repeats (``repeat``):
 
 - ``{"process": "poisson", "rate_per_s": r}``: an open loop. The round
   holds round(r x seconds) jobs. Their gaps are the round's own
@@ -22,16 +22,42 @@ and ``weights``), and whether a round repeats (``repeat``):
   window closes with its last commit where all of it is placed before
   ``--seconds`` have passed, and after ``--seconds`` otherwise.
 
+What the jobs are. ``sizes`` and ``weights``: jobs of one task group of
+the configuration's ``task`` (its cpu and memory), of these sizes in the
+weights' exact proportions, of type ``job_type`` at priority 50.
+``templates`` in their place: each ``{copies, type, priority,
+constraints, groups: [{count, shape}]}`` is a job of one task group for
+each of ``groups``, in order, ``shape`` a name of the configuration's
+``task_shapes`` (name -> ``cpu``, ``memory_mb``); ``constraints`` are
+the job's own, beside those all jobs share (the configuration's
+``task``), and ``type`` and ``priority`` default to ``job_type`` and 50.
+A round is every template ``copies`` times (where ``arrivals`` gives
+another number of jobs: in the copies' exact proportions), shuffled by
+the seed. ``sizes`` / ``weights`` are the one-group templates of the
+configuration's ``task``: the same code builds both.
+
 A drain window so ends when its work ends or when ``--seconds`` have
 passed, whichever is first (``end`` in what ``play`` returns:
 ``cell_full``, ``drained``, ``deadline``; ``rounds`` where a warm-up's
 ``rounds`` key ends it), and the rate is what was committed in it over
 its length. What the cell can hold is reckoned from the configuration
-alone (``reference.free_slots``) less every placement asked so far, the
-warm-up's too (``Player.slots_left``): a round offered and not placed
-still counts as failed, every one of its tasks, and a round is never
-offered to a cell with no room for it. An open loop plays its schedule
-to the end.
+and the mix alone (``reference.rounds_that_fit`` over ``rounds_of``:
+the mix's rounds replayed by first fit on the empty cell until the
+first task is left out; for tasks of one shape that is the cell's
+slots), times the mix's ``fill_limit`` (1 where it gives none), less
+every placement asked so far, the warm-up's too
+(``Player.slots_left``): a round offered and not placed still counts as
+failed, every one of its tasks, and a round is never offered to a cell
+with no room for it. An open loop plays its schedule to the end.
+
+``fill_limit`` is under 1 where the cell's machines or the mix's tasks
+differ in shape: two sound packers strand different capacity near the
+top of such a cell, so the plain reference can hold the program to first
+fit's per-job totals only where both place every job whole. With R
+rounds placed whole by first fit and rounds of equal size, a closed loop
+then offers floor(``fill_limit`` x R) rounds, the warm-up's counted (to
+the round: floor(``fill_limit`` x tasks placed) over a round's tasks),
+and ends ``cell_full``.
 
 ``"preload": true`` (with ``at_once``) registers the round before the
 window opens, while ``hold`` keeps the server's workers from taking
@@ -73,16 +99,17 @@ the same generator plays the mix before the window (``seconds``,
 
 from __future__ import annotations
 
+import itertools
 import math
 import random
 import threading
 import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
 from nomad_tpu.api.codec import to_dict
 
 from benchmark.generators import jobs as jobs_mod
-from benchmark.generators.fleet import build_node, node_spec
+from benchmark.generators.fleet import build_node, node_count, node_spec
 
 
 HOLD_SETTLE_S = 1.0
@@ -103,7 +130,9 @@ def apportion(weights: List[float], n: int) -> List[int]:
 def round_plan(mix: Dict, seed: int, seconds: float, round_no: int,
                tag: str) -> List[Dict]:
     """One round of the mix: [{"offset", "id", "size"}], ordered by
-    offset. The same (mix, seed, seconds, round_no) gives the same plan."""
+    offset, with ``"template"`` (its index) where the mix gives
+    templates. The same (mix, seed, seconds, round_no) gives the same
+    plan."""
     rng = random.Random((int(seed) * 1_000_003 + round_no) & (2**63 - 1))
     arrivals = mix["arrivals"]
     if arrivals["process"] == "poisson":
@@ -121,13 +150,65 @@ def round_plan(mix: Dict, seed: int, seconds: float, round_no: int,
         offsets = [0.0] * n
     else:
         raise ValueError(f"unknown arrival process {arrivals['process']!r}")
+    ids = [f"{tag}-r{round_no:03d}-{k:05d}" for k in range(n)]
+    if "templates" in mix:
+        picks: List[int] = []
+        copies = [int(t.get("copies", 1)) for t in mix["templates"]]
+        for pick, k in enumerate(apportion(copies, n)):
+            picks.extend([pick] * k)
+        rng.shuffle(picks)
+        return [{"offset": off, "id": jid, "template": pick,
+                 "size": template_size(mix["templates"][pick])}
+                for off, jid, pick in zip(offsets, ids, picks)]
     sizes: List[int] = []
     for size, k in zip(mix["sizes"], apportion(mix["weights"], n)):
         sizes.extend([int(size)] * k)
     rng.shuffle(sizes)
-    return [{"offset": off, "id": f"{tag}-r{round_no:03d}-{k:05d}",
-             "size": size}
-            for k, (off, size) in enumerate(zip(offsets, sizes))]
+    return [{"offset": off, "id": jid, "size": size}
+            for off, jid, size in zip(offsets, ids, sizes)]
+
+
+def template_size(template: Dict) -> int:
+    return sum(int(g["count"]) for g in template["groups"])
+
+
+def item_spec(config: Dict, mix: Dict, item: Dict) -> Dict:
+    """The job one item of a round's plan asks for, as plain data."""
+    if "template" in item:
+        return jobs_mod.template_spec(
+            config, mix["templates"][item["template"]], item["id"],
+            mix["job_type"])
+    return jobs_mod.job_spec(config["task"], item["id"], mix["job_type"],
+                             item["size"])
+
+
+def rounds_of(mix: Dict, config: Dict, seed: int,
+              seconds: float) -> Iterator[List[Dict]]:
+    """The mix's rounds without end, each as its jobs in the order
+    offered: what ``reference.rounds_that_fit`` replays on the empty
+    cell. A function of mix, configuration and seed alone. Jobs of one
+    size or template are one spec here, whatever their ids: the replay
+    reads none."""
+    specs: Dict[int, Dict] = {}
+    for round_no in itertools.count():
+        jobs = []
+        for item in round_plan(mix, seed, seconds, round_no, "fit"):
+            kind = item.get("template", item["size"])
+            if kind not in specs:
+                specs[kind] = item_spec(config, mix, item)
+            jobs.append(specs[kind])
+        yield jobs
+
+
+def lone_plan(mix: Dict, tag: str) -> List[Dict]:
+    """Each kind of job of the mix once (each size, or each template),
+    all due at once."""
+    if "templates" in mix:
+        return [{"offset": 0.0, "id": f"{tag}-{k:05d}", "template": k,
+                 "size": template_size(t)}
+                for k, t in enumerate(mix["templates"])]
+    return [{"offset": 0.0, "id": f"{tag}-{k:05d}", "size": int(size)}
+            for k, size in enumerate(sorted(set(mix["sizes"])))]
 
 
 def _sizes(ready: List) -> int:
@@ -190,7 +271,7 @@ class Player:
             return
         rng = random.Random(self.seed ^ 0x6E6F6465)
         shape = self.config["nodes"]
-        n = int(shape["count"])
+        n = node_count(shape)
         while not stop.wait(float(spec["every_s"])):
             pick = rng.sample(range(n), min(int(spec["count"]), n))
             nodes = [build_node(shape, node_spec(shape, i)) for i in pick]
@@ -233,7 +314,6 @@ class Player:
             raise ValueError(f"{tag}: only a closed loop's jobs can end")
         stop_after = (int(mix["stop"]["after_rounds"])
                       if "stop" in mix else None)
-        task = self.config["task"]
         stop = threading.Event()
         refresher = threading.Thread(
             target=self._refresh_loop, args=(stop,), daemon=True,
@@ -241,7 +321,7 @@ class Player:
         # The first round's payloads are built before the window opens.
         round_no = 0
         ready = self._build(
-            round_plan(mix, self.seed, seconds, round_no, tag), task, mix)
+            round_plan(mix, self.seed, seconds, round_no, tag), mix)
         first = _sizes(ready)
         if first > self.slots_left:
             raise ValueError(
@@ -299,8 +379,7 @@ class Player:
                 # built while this one is being placed.
                 round_no += 1
                 ready = self._build(
-                    round_plan(mix, self.seed, seconds, round_no, tag),
-                    task, mix)
+                    round_plan(mix, self.seed, seconds, round_no, tag), mix)
             for t in senders:
                 t.join()
             if not closed_loop:
@@ -337,17 +416,16 @@ class Player:
                 "asked": asked - target_base, "end": end}
 
     def play_alone(self, tag: str, target_base: int, timeout: float) -> int:
-        """Each size of the mix once, one job at a time, the next when
-        the last is placed; the placements asked. An open loop's jobs
-        meet in a coalesced solve or do not by chance, and a size the
-        warm-up only solved beside another would compile its lone
-        program inside the window."""
+        """Each size (or template) of the mix once, one job at a time,
+        the next when the last is placed; the placements asked. An open
+        loop's jobs meet in a coalesced solve or do not by chance, and a
+        size the warm-up only solved beside another would compile its
+        lone program inside the window."""
         asked = target_base
-        for k, size in enumerate(sorted(set(self.mix["sizes"]))):
-            plan = [{"offset": 0.0, "id": f"{tag}-{k:05d}", "size": int(size)}]
-            self._send(0.0, self._build(plan, self.config["task"], self.mix))
-            asked += int(size)
-            self.slots_left -= int(size)
+        for item in lone_plan(self.mix, tag):
+            self._send(0.0, self._build([item], self.mix))
+            asked += item["size"]
+            self.slots_left -= item["size"]
             if not self._placed_by(asked, time.time() + timeout):
                 break
         return asked - target_base
@@ -394,11 +472,10 @@ class Player:
         complete."""
         return self._stopped_by(list(self.stops.values()), limit)
 
-    def _build(self, plan: List[Dict], task: Dict, mix: Dict):
+    def _build(self, plan: List[Dict], mix: Dict):
         ready = []
         for item in plan:
-            spec = jobs_mod.job_spec(task, item["id"], mix["job_type"],
-                                     item["size"])
+            spec = item_spec(self.config, mix, item)
             rec = {"spec": spec, "offset": item["offset"]}
             self.jobs[spec["id"]] = rec
             ready.append(

@@ -6,21 +6,29 @@ answers. The same nodes and the same jobs give the same verdict whatever
 placed them. It does four things:
 
 - ``place``: a straightforward first-fit placement of the jobs on the
-  nodes. Its per-job totals are the answers the program's are held to
-  (how many tasks of each job can be placed at all). Between the jobs,
-  in the order offered, may stand stop entries (``{"stop": job id,
-  "round": n}``): a stop gives the job's cpu and memory back to the
-  nodes ``place`` put it on.
-- ``free_slots``: how many tasks of one shape the empty cell holds, by
-  the arithmetic ``place`` fills it with. The generator offers a closed
-  loop's next round only where it fits whole.
+  nodes, job by job and, within a job, group by group in the job's
+  order, each group's tasks taking that group's cpu and memory. Its
+  per-job totals are the answers the program's are held to (how many
+  tasks of each job can be placed at all). Between the jobs, in the
+  order offered, may stand stop entries (``{"stop": job id, "round":
+  n}``): a stop gives back what each group of the job took, to the
+  nodes ``place`` put it on. A node too small for a task is not
+  ineligible: it has no slot.
+- ``rounds_that_fit``: what the empty cell holds of a mix, by the
+  arithmetic ``place`` fills it with: the mix's rounds replayed by first
+  fit until the first task is left out, as the whole rounds placed and
+  the tasks placed in all. For tasks of one shape the tasks are the
+  cell's slots, whatever the rounds. The generator offers a closed
+  loop's next round only where it fits whole in the mix's
+  ``fill_limit`` of those tasks.
 - ``compare``: holds a set of answers to the configuration's guarantees:
   every job due has all its placements committed, the state store reads
   back what the event stream committed, every placement sits on a node
-  the job's datacenters, driver and constraints admit, carries the
-  resources the job asked for and a unique id, no node holds more than
-  its capacity, and a job whose stop was committed reads back no
-  allocation desired ``run``. Every number is a count with the limit 0.
+  the job's datacenters, driver and constraints admit and carries a
+  unique id, a job's placements are, shape by shape, no more than its
+  groups asked for, no node holds more than its own capacity, and a job
+  whose stop was committed reads back no allocation desired ``run``.
+  Every number is a count with the limit 0.
 - ``control``: ``place`` with one guarantee broken, put in the program's
   place. ``compare`` has to fail it. With ``stop`` broken it
   acknowledges every stop and leaves every second row of a stopped job
@@ -30,7 +38,17 @@ placed them. It does four things:
 from __future__ import annotations
 
 import random
-from typing import AbstractSet, Callable, Dict, List, Sequence, Tuple
+from collections import Counter
+from typing import (
+    AbstractSet,
+    Callable,
+    Dict,
+    Iterable,
+    Iterator,
+    List,
+    Sequence,
+    Tuple,
+)
 
 import numpy as np
 
@@ -71,77 +89,137 @@ def eligible(node: Dict, job: Dict) -> bool:
             and all(_constraint_holds(node, c) for c in job["constraints"]))
 
 
-def _slots(free_cpu: np.ndarray, free_mem: np.ndarray, mask: np.ndarray,
-           cpu: int, mem: int) -> np.ndarray:
-    """Per node, the tasks of cpu x mem that still fit; 0 off the mask."""
-    slots = np.minimum(free_cpu // max(cpu, 1), free_mem // max(mem, 1))
-    return np.where(mask, np.maximum(slots, 0), 0)
-
-
-def free_slots(nodes: List[Dict], job: Dict) -> int:
-    """The tasks of ``job``'s shape that the empty cell holds: over the
-    nodes that admit the job, min(cpu // job cpu, memory // job memory).
-    ``job["count"]`` is not read."""
-    mask = np.array([eligible(nd, job) for nd in nodes], dtype=bool)
-    return int(_slots(
-        np.array([nd["cpu"] for nd in nodes], dtype=np.int64),
-        np.array([nd["memory_mb"] for nd in nodes], dtype=np.int64),
-        mask, job["cpu"], job["memory_mb"]).sum())
-
-
 def is_stop(entry: Dict) -> bool:
     return "stop" in entry
 
 
-def place(nodes: List[Dict], jobs: List[Dict],
-          broken: str = "") -> Dict[str, np.ndarray]:
-    """First fit, job by job in the order given: {job id: node index of
-    each task placed}. A stop entry gives its job's resources back to
-    those nodes. ``broken`` names a guarantee to ignore (the control)."""
-    n = len(nodes)
-    free_cpu = np.array([nd["cpu"] for nd in nodes], dtype=np.int64)
-    free_mem = np.array([nd["memory_mb"] for nd in nodes], dtype=np.int64)
-    masks: Dict[tuple, np.ndarray] = {}
-    out: Dict[str, np.ndarray] = {}
+class _Cell:
+    """The cell as first fit fills it: what every node has free."""
+
+    def __init__(self, nodes: List[Dict]):
+        self.nodes = nodes
+        self.free_cpu = np.array([nd["cpu"] for nd in nodes], dtype=np.int64)
+        self.free_mem = np.array([nd["memory_mb"] for nd in nodes],
+                                 dtype=np.int64)
+        self._masks: Dict[tuple, np.ndarray] = {}
+        self._start = 0     # where the control's round robin goes on
+
+    def mask(self, job: Dict) -> np.ndarray:
+        key = job_key(job)
+        if key not in self._masks:
+            self._masks[key] = np.array(
+                [eligible(nd, job) for nd in self.nodes], dtype=bool)
+        return self._masks[key]
+
+    def take(self, job: Dict, group: Dict, broken: str = "") -> np.ndarray:
+        """The node index of each task of ``group`` that first fit
+        places, taken off what is free. ``broken`` names a guarantee to
+        ignore (the control)."""
+        mask, n = self.mask(job), len(self.nodes)
+        count, cpu, mem = group["count"], group["cpu"], group["memory_mb"]
+        if broken == "capacity":
+            # No capacity check: bin-packing's favourite node takes all.
+            return np.full(count if mask.any() else 0, int(np.argmax(mask)))
+        order = np.arange(n)
+        if broken == "eligibility":
+            # No feasibility check: round robin over every node.
+            mask = np.ones(n, dtype=bool)
+            order = (order + self._start) % n
+            self._start = (self._start + count) % n
+        # Per node, the tasks of cpu x mem that still fit; 0 off the mask.
+        slots = np.minimum(self.free_cpu // max(cpu, 1),
+                           self.free_mem // max(mem, 1))
+        slots = np.where(mask, np.maximum(slots, 0), 0)[order]
+        if broken == "eligibility":
+            # One task per node per lap, as a spreading scheduler would.
+            slots = np.minimum(slots, -(-count // n))
+        take = np.minimum(
+            slots, np.maximum(count - (np.cumsum(slots) - slots), 0))
+        placed = np.repeat(order, take)
+        self.give(placed, group, -1)
+        return placed
+
+    def give(self, placed: np.ndarray, group: Dict, sign: int = 1) -> None:
+        np.add.at(self.free_cpu, placed, sign * group["cpu"])
+        np.add.at(self.free_mem, placed, sign * group["memory_mb"])
+
+
+def job_key(job: Dict) -> tuple:
+    """What decides which nodes admit a job."""
+    return (tuple(job["datacenters"]), job["driver"],
+            tuple(map(tuple, job["constraints"])))
+
+
+def _place(nodes: List[Dict], jobs: List[Dict],
+           broken: str = "") -> Dict[str, List[np.ndarray]]:
+    """{job id: for each group, the node index of each task placed}."""
+    cell = _Cell(nodes)
+    out: Dict[str, List[np.ndarray]] = {}
     held: Dict[str, Dict] = {}    # jobs whose resources are taken
-    start = 0
     for job in jobs:
         if is_stop(job):
             gone = held.pop(job["stop"], None)
             if gone is not None:
-                np.add.at(free_cpu, out[gone["id"]], gone["cpu"])
-                np.add.at(free_mem, out[gone["id"]], gone["memory_mb"])
+                for group, placed in zip(gone["groups"], out[gone["id"]]):
+                    cell.give(placed, group)
             continue
-        key = (tuple(job["datacenters"]), job["driver"],
-               tuple(map(tuple, job["constraints"])))
-        if key not in masks:
-            masks[key] = np.array([eligible(nd, job) for nd in nodes])
-        mask = masks[key]
-        count, cpu, mem = job["count"], job["cpu"], job["memory_mb"]
-        if broken == "capacity":
-            # No capacity check: bin-packing's favourite node takes all.
-            first = int(np.argmax(mask))
-            out[job["id"]] = np.full(count if mask.any() else 0, first)
-            continue
-        if broken == "eligibility":
-            # No feasibility check: round robin over every node.
-            mask = np.ones(n, dtype=bool)
-            order = (np.arange(n) + start) % n
-            start = (start + count) % n
-        else:
-            order = np.arange(n)
-        slots = _slots(free_cpu, free_mem, mask, cpu, mem)[order]
-        if broken == "eligibility":
-            # One task per node per lap, as a spreading scheduler would.
-            laps = -(-count // n)
-            slots = np.minimum(slots, laps)
-        take = np.minimum(slots, np.maximum(count - (np.cumsum(slots) - slots), 0))
-        placed_nodes = np.repeat(order, take)
-        out[job["id"]] = placed_nodes
-        np.subtract.at(free_cpu, placed_nodes, cpu)
-        np.subtract.at(free_mem, placed_nodes, mem)
-        held[job["id"]] = job
+        out[job["id"]] = [cell.take(job, g, broken) for g in job["groups"]]
+        if broken != "capacity":
+            held[job["id"]] = job
     return out
+
+
+def place(nodes: List[Dict], jobs: List[Dict],
+          broken: str = "") -> Dict[str, np.ndarray]:
+    """First fit, job by job in the order given and group by group in the
+    job's order: {job id: node index of each task placed}. A stop entry
+    gives back what each group of its job took. ``broken`` names a
+    guarantee to ignore (the control)."""
+    return {jid: np.concatenate(parts)
+            for jid, parts in _place(nodes, jobs, broken).items()}
+
+
+def _runs(jobs: List[Dict]) -> Iterator[Tuple[Dict, Dict]]:
+    """(job, group) for each group of ``jobs`` in order, with neighbours
+    that the same nodes admit and that ask the same cpu and memory run
+    together: first fit fills the same slots for them one by one or as
+    one."""
+    last = None
+    for job in jobs:
+        admits = job_key(job)
+        for group in job["groups"]:
+            key = (admits, group["cpu"], group["memory_mb"])
+            if last is not None and last[0] == key:
+                last[2]["count"] += group["count"]
+                continue
+            if last is not None:
+                yield last[1], last[2]
+            last = (key, job, dict(group))
+    if last is not None:
+        yield last[1], last[2]
+
+
+def rounds_that_fit(nodes: List[Dict],
+                    rounds: Iterable[List[Dict]]) -> Tuple[int, int]:
+    """What the empty cell holds of a mix: ``rounds`` (each a list of
+    jobs, in the order offered) replayed by first fit until the first
+    task is left out. (R, tasks): the rounds placed whole before it, and
+    every task placed, those of the round that was cut short too. Where
+    all tasks have one shape and the same nodes admit them, ``tasks`` is
+    the cell's slots of that shape whatever the rounds: over the nodes
+    that admit it, min(cpu // task cpu, memory // task memory)."""
+    cell = _Cell(nodes)
+    whole = tasks = 0
+    for jobs in rounds:
+        asked = placed = 0
+        for job, group in _runs(jobs):
+            asked += group["count"]
+            placed += len(cell.take(job, group))
+        tasks += placed
+        if placed < asked:
+            break
+        whole += 1
+    return whole, tasks
 
 
 class Answers:
@@ -167,8 +245,8 @@ def control(nodes: List[Dict], jobs: List[Dict], broken: str) -> Answers:
     leaves every second row of a stopped job running."""
     if broken not in GUARANTEES:
         raise ValueError(f"unknown guarantee {broken!r}")
-    placed = place(nodes, jobs,
-                   broken="" if broken in ("commit", "stop") else broken)
+    placed = _place(nodes, jobs,
+                    broken="" if broken in ("commit", "stop") else broken)
     stopped = {e["stop"] for e in jobs if is_stop(e)}
     by_job: Dict[str, List[Row]] = {}
     by_node: Dict[str, List[Row]] = {}
@@ -176,10 +254,12 @@ def control(nodes: List[Dict], jobs: List[Dict], broken: str) -> Answers:
     for job in jobs:
         if is_stop(job):
             continue
-        committed[job["id"]] = len(placed[job["id"]])
-        rows = [(f"{job['id']}/{k}", job["id"], nodes[int(i)]["id"],
-                 job["cpu"], job["memory_mb"])
-                for k, i in enumerate(placed[job["id"]])]
+        tasks = [(int(i), g["cpu"], g["memory_mb"])
+                 for g, part in zip(job["groups"], placed[job["id"]])
+                 for i in part]
+        rows = [(f"{job['id']}/{k}", job["id"], nodes[i]["id"], cpu, mem)
+                for k, (i, cpu, mem) in enumerate(tasks)]
+        committed[job["id"]] = len(rows)
         if job["id"] in stopped:
             rows = rows[::2] if broken == "stop" else []
         elif broken == "commit":
@@ -243,15 +323,21 @@ def compare(nodes: List[Dict], jobs: List[Dict], answers: Answers,
                 else answers.committed.get(job["id"], 0))
         if len(rows) != want:
             out["store_mismatch"] += 1
-        for alloc_id, _jid, nid, cpu, mem in rows:
+        for alloc_id, _jid, nid, _cpu, _mem in rows:
             n_rows += 1
             seen_ids.add(alloc_id)
             per_node[nid] = per_node.get(nid, 0) + 1
             node = by_id.get(nid)
             if node is None or not eligible(node, job):
                 out["ineligible"] += 1
-            if (cpu, mem) != (job["cpu"], job["memory_mb"]):
-                out["wrong_resources"] += 1
+        # Shape by shape, no more rows than the job's groups asked for
+        # (with store_mismatch at 0 and the job whole: exactly those).
+        asked: Counter = Counter()
+        for g in job["groups"]:
+            asked[(g["cpu"], g["memory_mb"])] += g["count"]
+        got = Counter((cpu, mem) for _a, _j, _n, cpu, mem in rows)
+        if any(k > asked[shape] for shape, k in got.items()):
+            out["wrong_resources"] += 1
     out["duplicate_ids"] = n_rows - len(seen_ids)
     # The stopped jobs read back: the last wave stopped always, and as
     # many more, drawn from the seed, as the sample holds.

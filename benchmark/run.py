@@ -171,6 +171,26 @@ def warm_widths() -> None:
             warm_exact_batch_shapes(nb, counts=counts)
 
 
+def eval_ends(events, job_id: str) -> list:
+    """How the evaluations of one job ended, as the stream tells it."""
+    return [(e.payload.get("status"), e.payload.get("status_description"))
+            for e in events
+            if e.topic == "Eval" and e.payload.get("job_id") == job_id
+            and e.payload.get("status") in ("complete", "failed")]
+
+
+def plans_per_eval(events) -> dict:
+    """{n: evaluations with n plans applied}, from the event stream."""
+    per: dict = {}
+    for e in events:
+        if e.topic == "Plan" and e.type == "PlanApplied":
+            per[e.key] = per.get(e.key, 0) + 1
+    out: dict = {}
+    for n in per.values():
+        out[n] = out.get(n, 0) + 1
+    return dict(sorted(out.items()))
+
+
 def program_answers(snap, committed, truncated,
                     stopped=frozenset()) -> reference.Answers:
     """The program's answers as the reference reads them: what the event
@@ -304,9 +324,13 @@ def main(argv=None) -> int:
     )
     from nomad_tpu.server.server import ServerConfig
 
-    from benchmark.generators.fleet import Fleet, build_node, node_spec
-    from benchmark.generators.jobs import job_spec
-    from benchmark.generators.traffic import Player
+    from benchmark.generators.fleet import (
+        Fleet,
+        build_node,
+        node_count,
+        node_spec,
+    )
+    from benchmark.generators.traffic import Player, rounds_of
     from benchmark.generators.watcher import (
         EventTail,
         event_placed,
@@ -333,7 +357,7 @@ def main(argv=None) -> int:
 
     ctx = RunContext()
     ctx.device_kind = device["kind"]
-    ctx.node_bucket = work.node_bucket(int(config["nodes"]["count"]))
+    ctx.node_bucket = work.node_bucket(node_count(config["nodes"]))
     if config.get("trace_buffer_size"):
         trace_mod.configure(max_traces=int(config["trace_buffer_size"]))
 
@@ -349,7 +373,7 @@ def main(argv=None) -> int:
         wait_for_leader([srv])
         fleet = Fleet(srv.rpc_addr)
         shape = config["nodes"]
-        nodes = [node_spec(shape, i) for i in range(int(shape["count"]))]
+        nodes = [node_spec(shape, i) for i in range(node_count(shape))]
         fleet.start_heartbeats()
         fleet.register([build_node(shape, nd) for nd in nodes])
         log(f"{len(nodes)} nodes registered")
@@ -359,10 +383,16 @@ def main(argv=None) -> int:
                 w.set_pause(held)
 
         tail = EventTail(srv.fsm.events).start()
-        # What the cell holds of the mix's task, from the configuration
-        # alone; each player takes from it what it offers.
-        slots = reference.free_slots(
-            nodes, job_spec(config["task"], "", mix["job_type"], 0))
+        # What the cell holds of the mix, from the configuration and the
+        # mix alone: what first fit places of its rounds before it leaves
+        # a task out, times the mix's fill limit. Each player takes from
+        # it what it offers.
+        fill_limit = float(mix.get("fill_limit", 1.0))
+        rounds_fit, tasks_fit = reference.rounds_that_fit(
+            nodes, rounds_of(mix, config, args.seed, args.seconds))
+        slots = int(fill_limit * tasks_fit + 1e-9)
+        log(f"first fit places {rounds_fit} rounds whole, {tasks_fit} "
+            f"tasks; fill limit {fill_limit}: {slots} slots")
         warm = Player(fleet, mix, config, args.seed ^ 0x5EED5EED,
                       tail.placed_total, slots, hold,
                       eval_done=tail.eval_done)
@@ -370,6 +400,11 @@ def main(argv=None) -> int:
         if not wait_until(lambda: tail.placed_total() >= played["asked"],
                           WARMUP_TIMEOUT_S):
             log(f"warm-up placed {tail.placed_total()}/{played['asked']}")
+            for jid, res in placements_by_job(tail.events, warm.jobs).items():
+                spec = warm.jobs[jid]["spec"]
+                if "due" in warm.jobs[jid] and res["placed"] < spec["count"]:
+                    log(f"  short: {res['placed']}/{spec['count']} of {spec}: "
+                        f"{eval_ends(tail.events, jid)}")
             return 3
         wait_quiet(srv, 60.0)
         warmed = played["asked"]
@@ -607,14 +642,24 @@ def main(argv=None) -> int:
             "seconds": window_s, "setup_s": setup_s, "drain_s": drain_s,
             "drained": drained, "compare_s": compare_s,
             "jobs_due": len(due_jobs), "jobs_offered": len(player.jobs),
+            # The first jobs due that were not placed whole: what to
+            # look at where ``failed`` or ``jobs_short`` is not 0.
+            "jobs_not_whole": [dict(j, placed=committed[j["id"]],
+                                    evals=eval_ends(events, j["id"]))
+                               for j in due_jobs
+                               if committed[j["id"]] != j["count"]][:20],
             "rounds": played["rounds"], "window_end": played["end"],
-            "slots_left": player.slots_left, "asked": asked,
+            "slots_left": player.slots_left, "rounds_fit": rounds_fit,
+            "fill_limit": fill_limit, "asked": asked,
             "placed_in_window": in_window, "values": values,
             "stops": window_stops, "stops_asked": stops_asked,
             "live_waves": len(player.live),
             "round_log": [[round(r.get(k, closed) - opened, 4)
                            for k in ("offered", "placed", "stopped")]
                           for r in player.round_log],
+            # {plans applied for one evaluation: evaluations}: one, and one
+            # more for each plan the pipeline refused in part.
+            "plans_per_eval": plans_per_eval(events),
             "window": ctx.window, "counters": ctx.counters,
             "controls": controls, "compared": compared, "device": device,
             "commits": [[round(e.time - opened, 4), event_placed(e)]
