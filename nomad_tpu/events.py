@@ -41,7 +41,9 @@ Eval       EvalUpdated, EvalDeleted (eval id)
 Alloc      AllocUpserted, AllocClientUpdated (alloc id; columnar blocks
            publish ONE event per block keyed by eval id — per-member
            fan-out would cost O(placements) per commit, the same
-           granularity contract as the state store's watch items)
+           granularity contract as the state store's watch items),
+           AllocStopped (the stopping eval's id; ONE per block stopped
+           whole, payload job_id, block_id, count, desired_status)
 Plan       PlanApplied (eval id)
 Express    ExpressPlaced (eval id; ONE deterministic event per express
            submission, payload carries the in-line placed_ms — commit/

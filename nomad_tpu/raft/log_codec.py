@@ -14,6 +14,7 @@ from nomad_tpu.api.codec import from_dict, to_dict
 from nomad_tpu.structs import (
     AllocBatch,
     Allocation,
+    AllocStopBatch,
     AllocUpdateBatch,
     Evaluation,
     Job,
@@ -32,7 +33,8 @@ _SCHEMAS: Dict[str, Dict[str, Any]] = {
     "eval_update": {"evals": [Evaluation]},
     "eval_delete": {"evals": None, "allocs": None},
     "alloc_update": {"allocs": [Allocation], "alloc_batches": "blocks",
-                     "update_batches": "ubatches"},
+                     "update_batches": "ubatches",
+                     "stop_batches": "sbatches"},
     "alloc_client_update": {"allocs": [Allocation]},
 }
 
@@ -41,9 +43,10 @@ def encode_payload(msg_type: str, payload: dict) -> dict:
     out = {}
     for k, v in payload.items():
         spec = _SCHEMAS.get(msg_type, {}).get(k)
-        if spec in ("blocks", "ubatches"):
+        if spec in ("blocks", "ubatches", "sbatches"):
             # Columnar batches carry their own compact wire form — runs/id
-            # lists + shared fields, never per-Allocation rows.
+            # lists + shared fields (a stop: the block's name), never
+            # per-Allocation rows.
             out[k] = [b.to_wire() for b in v]
         else:
             out[k] = to_dict(v)
@@ -67,6 +70,8 @@ def decode_payload(msg_type: str, payload: dict) -> dict:
             # Wire form carries member ids; the FSM resolves them against
             # its own store at apply (deterministic across replicas).
             out[key] = [AllocUpdateBatch.from_wire(v) for v in value]
+        elif spec == "sbatches":
+            out[key] = [AllocStopBatch.from_wire(v) for v in value]
         elif isinstance(spec, list):
             out[key] = [from_dict(spec[0], v) for v in value]
         else:

@@ -45,11 +45,14 @@ class CoreScheduler:
         for e in self.snap.evals():
             if not e.terminal_status() or e.modify_index > old_index:
                 continue
-            allocs = self.snap.allocs_by_eval(e.id)
-            if any(not a.terminal_status() for a in allocs):
+            # Blocks are judged whole and go with their evaluation
+            # (state/store.py delete_eval): only object rows are named,
+            # so reaping a stopped block costs what its header costs.
+            rows = self.snap.eval_gc_rows(e.id)
+            if rows is None:
                 continue
             gc_evals.append(e.id)
-            gc_allocs.extend(a.id for a in allocs)
+            gc_allocs.extend(a.id for a in rows)
 
         if gc_evals or gc_allocs:
             self.server.logger.debug(

@@ -60,6 +60,11 @@ class FSM:
         # cost and per-table row counts of the most recent
         # restore_bytes, None until one happens.
         self.last_restore: Optional[Dict[str, Any]] = None
+        # Tasks this FSM stopped through stop batches: as whole blocks,
+        # and one by one where a block had changed under its plan (plain
+        # ints; the plan pipeline's stats() reads its own FSM's).
+        self.stop_batch_members = 0
+        self.stop_batch_fallback_members = 0
         self._handlers: Dict[str, Callable[[int, dict], Any]] = {
             "node_register": self._apply_node_register,
             "node_batch_register": self._apply_node_batch_register,
@@ -185,17 +190,20 @@ class FSM:
             self.events.publish("Eval", "EvalDeleted", key=ev_id,
                                 raft_index=index)
 
+    def _publish_alloc_rows(self, index: int, allocs) -> None:
+        """Per-alloc events only for object rows: bounded by plan size."""
+        for a in allocs:
+            self.events.publish(
+                "Alloc", "AllocUpserted", key=a.id, raft_index=index,
+                payload={"node_id": a.node_id, "job_id": a.job_id,
+                         "desired_status": a.desired_status},
+            )
+
     def _apply_alloc_update(self, index: int, payload: dict) -> None:
         allocs = payload.get("allocs") or []
         if allocs:
             self.state.upsert_allocs(index, allocs)
-            # Per-alloc events only for object rows: bounded by plan size.
-            for a in allocs:
-                self.events.publish(
-                    "Alloc", "AllocUpserted", key=a.id, raft_index=index,
-                    payload={"node_id": a.node_id, "job_id": a.job_id,
-                             "desired_status": a.desired_status},
-                )
+            self._publish_alloc_rows(index, allocs)
         # Columnar placements commit as stored blocks — O(node runs), no
         # per-Allocation expansion (state/blocks.py).
         batches = payload.get("alloc_batches") or []
@@ -216,6 +224,30 @@ class FSM:
         ubatches = payload.get("update_batches") or []
         if ubatches:
             self.state.apply_update_batches(index, ubatches)
+        # Stops of whole stored blocks: the block changes tables, no member
+        # is touched (state/store.py _apply_stop_batches).
+        sbatches = payload.get("stop_batches") or []
+        if sbatches:
+            outcomes = self.state.apply_stop_batches(index, sbatches)
+            for b, rows in zip(sbatches, outcomes):
+                if rows is None:
+                    # One event per BLOCK, keyed by the evaluation that
+                    # asked for the stop. A type of its own: consumers
+                    # count a columnar AllocUpserted as placements.
+                    self.stop_batch_members += b.n_live
+                    self.events.publish(
+                        "Alloc", "AllocStopped", key=b.eval_id,
+                        raft_index=index,
+                        payload={"job_id": b.job_id,
+                                 "block_id": b.block_id,
+                                 "count": b.n_live,
+                                 "desired_status": b.desired_status},
+                    )
+                    continue
+                # The block had changed under the plan: its members
+                # stopped as object rows, and are published as such.
+                self.stop_batch_fallback_members += len(rows)
+                self._publish_alloc_rows(index, rows)
         # The plan applier marks plan commits (plan_apply.py _apply): one
         # PlanApplied per committed plan entry, after its alloc events.
         plan_meta = payload.get("plan")
@@ -264,7 +296,9 @@ class FSM:
             # Object rows and columnar blocks persist in their native forms:
             # a 100k-placement block snapshots as its runs, not 100k rows.
             "allocs": snap.allocs_objects(),
-            "blocks": snap.alloc_blocks(),
+            # Live and stopped blocks alike; block_restore tells them
+            # apart by their desired status.
+            "blocks": snap.alloc_blocks() + snap.stopped_alloc_blocks(),
             "indexes": {
                 t: snap.get_index(t) for t in ("nodes", "jobs", "evals", "allocs")
             },

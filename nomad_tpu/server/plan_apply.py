@@ -855,6 +855,14 @@ def _prevaluate_nodes_bulk_dict(snap, plan: Plan, batch_ask=None):
     return out
 
 
+def stops_only(plan: Plan) -> bool:
+    """A plan that holds stop batches and nothing else: no fit check, no
+    fused pass, committed whole."""
+    return bool(plan.stop_batches) and not (
+        plan.node_update or plan.node_allocation or plan.failed_allocs
+        or plan.alloc_batches or plan.update_batches)
+
+
 def evaluate_plan(snap, plan: Plan, reservations=None) -> PlanResult:
     """Determine the committable subset of a plan (plan_apply.go:164-227).
 
@@ -878,6 +886,14 @@ def evaluate_plan(snap, plan: Plan, reservations=None) -> PlanResult:
         node_allocation={},
         failed_allocs=plan.failed_allocs,
     )
+    # Stops of whole blocks commit whole, unchecked: a stop frees capacity
+    # (upstream's evaluateNodePlan passes a node with no new allocation),
+    # and a block cannot commit in part. In a plan that also places, what
+    # they free is not counted towards its own placements: those verify
+    # below against usage that still holds the blocks.
+    result.stop_batches = plan.stop_batches
+    if stops_only(plan):
+        return result
 
     # Per-node resource ask of the columnar placements, held by reference
     # and materialized per consumer (dense rows for the bulk verifier, a

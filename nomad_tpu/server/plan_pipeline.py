@@ -45,6 +45,7 @@ from nomad_tpu.server.plan_apply import (
     _node_table,
     _object_allocs,
     evaluate_plan,
+    stops_only,
 )
 from nomad_tpu.server.plan_queue import PendingPlan, PlanQueue
 from nomad_tpu.structs import Plan, PlanResult
@@ -85,6 +86,9 @@ class _PipelineTotals:
         # did not fully fit.
         self.scalar_why = {"scalar_lone": 0, "scalar_ineligible": 0,
                            "scalar_object_rows": 0, "scalar_unfit": 0}
+        # Plans of stop batches alone: committed whole with no fit check,
+        # so neither fused nor scalar.
+        self.stop_plans = 0
         self.max_batch_seen = 0
 
     def stats(self) -> Dict[str, int]:
@@ -100,6 +104,7 @@ class _PipelineTotals:
                 "fused_plans": self.fused_plans,
                 "scalar_plans": self.scalar_plans,
                 **self.scalar_why,
+                "stop_plans": self.stop_plans,
                 "max_batch_seen": self.max_batch_seen,
             }
 
@@ -119,6 +124,8 @@ def _plan_touched_nodes(plan: Plan) -> set:
             nodes.update(b.src_node_ids)
         elif getattr(b, "allocs", None):
             nodes.update(a.node_id for a in b.allocs)
+    for b in plan.stop_batches:
+        nodes.update(b.node_ids)
     return nodes
 
 
@@ -134,6 +141,8 @@ def apply_result_to_snapshot(snap, result: PlanResult, index: int) -> None:
         snap.upsert_alloc_blocks(index, result.alloc_batches)
     if result.update_batches:
         snap.apply_update_batches(index, result.update_batches)
+    if result.stop_batches:
+        snap.apply_stop_batches(index, result.stop_batches)
 
 
 def _whole_commit_result(plan: Plan) -> PlanResult:
@@ -154,10 +163,13 @@ def _fused_eligible(plan: Plan) -> bool:
     """A plan rides the fused K x nodes pass iff its entire ask is pure
     columnar placement batches: no per-node object placements or evictions
     (those need the scalar/object merge paths), no update batches (delta
-    semantics), and no network-carrying batches (sequential port
+    semantics), no stop batches (a plan of stops alone never gets here,
+    evaluate_plans commits it unchecked; one that also places takes the
+    scalar path), and no network-carrying batches (sequential port
     semantics — and a committed net batch flips later plans' nodes to the
     scalar path, which the cumulative-ask trick can't express)."""
-    if plan.node_allocation or plan.node_update or plan.update_batches:
+    if (plan.node_allocation or plan.node_update or plan.update_batches
+            or plan.stop_batches):
         return False
     return all(not _block_has_net(b) for b in plan.alloc_batches)
 
@@ -294,6 +306,16 @@ def evaluate_plans(snap, plans: List[Plan],
     i = 0
     n = len(plans)
     while i < n:
+        if stops_only(plans[i]):
+            # Nothing to fit: evaluate_plan hands the stops back whole.
+            result = evaluate_plan(snap, plans[i])
+            apply_result_to_snapshot(snap, result, stamp_index())
+            results.append(result)
+            if totals is not None:
+                with totals._lock:
+                    totals.stop_plans += 1
+            i += 1
+            continue
         m = 0
         reason = "scalar_lone"
         if n - i > 1:
@@ -376,7 +398,15 @@ class PlanPipeline(threading.Thread):
         self._stop.set()
 
     def stats(self) -> Dict[str, int]:
-        return self.totals.stats()
+        """The process-wide totals, and what this pipeline's own FSM made
+        of the stop batches it was sent (only the FSM knows whether a
+        block still stood as the plan saw it)."""
+        return {
+            **self.totals.stats(),
+            "stop_batch_members": self.fsm.stop_batch_members,
+            "stop_batch_fallback_members":
+                self.fsm.stop_batch_fallback_members,
+        }
 
     # -- conflict attribution ----------------------------------------------
 
@@ -648,6 +678,8 @@ class PlanPipeline(threading.Thread):
             payload["alloc_batches"] = result.alloc_batches
         if result.update_batches:
             payload["update_batches"] = result.update_batches
+        if result.stop_batches:
+            payload["stop_batches"] = result.stop_batches
         # Plan provenance rides the replicated entry so EVERY replica's
         # FSM publishes exactly one PlanApplied per committed plan.
         payload["plan"] = {
@@ -655,6 +687,7 @@ class PlanPipeline(threading.Thread):
             "allocs": len(allocs),
             "alloc_batches": len(result.alloc_batches),
             "update_batches": len(result.update_batches),
+            "stop_batches": len(result.stop_batches),
         }
         # A synchronous replication layer (InProcRaft) applies on THIS
         # thread: the active-span install lets the FSM hang its fsm.apply

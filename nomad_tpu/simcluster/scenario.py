@@ -391,10 +391,11 @@ def _spec_registry() -> Dict[str, ScenarioSpec]:
                 # 250ms and stamps a Capacity event snapshot every 5s.
                 "capacity": {"poll_interval": 0.25,
                              "events_interval": 5.0},
-                # The deregistration stop storm publishes one
-                # AllocUpserted per stopped object row; the 20 Hz
-                # watcher must never fall off the ring (truncation
-                # voids the digest contract).
+                # A deregistration that falls through to object rows
+                # publishes one AllocUpserted per stopped row (a block
+                # stopped whole is one AllocStopped); the 20 Hz watcher
+                # must never fall off the ring (truncation voids the
+                # digest contract).
                 "event_buffer_size": 32768,
                 "max_heartbeats_per_second": 2.0,
             },
@@ -2065,6 +2066,9 @@ class ScenarioRunner:
                     placed += 1
                 else:
                     stopped += 1
+            elif e.topic == "Alloc" and e.type == "AllocStopped":
+                # A whole block stopped: one event, its members' count.
+                stopped += int(e.payload.get("count", 0))
             elif e.type == "NodeHeartbeatExpired":
                 expired_nodes += 1
 

@@ -10,25 +10,45 @@ block, Allocation objects materialized lazily — per node for client
 fetches, per id for individual addressing.
 
 Invariants:
-- Blocks hold only non-terminal, desired=run allocations. Any write that
-  individually addresses a block member (client status update, eviction,
-  re-placement) *promotes* it: the member is excluded from the block and
-  the superseding Allocation object lands in the object table.
-- Stored blocks are immutable; exclusion produces a copy sharing the column
-  arrays (copy-on-write), so snapshots that captured the old table keep a
-  consistent view. Lazy caches (id→position, node→run) are shared across
-  copies — the columns they index never change.
+- The store keeps blocks in two tables. ``blocks`` (live) holds only
+  non-terminal, desired=run allocations: every reader of usage — the
+  device mirror, plan verification's ``_existing_block_usage_rows``, the
+  solver's block reconcile, the capacity books — reads that table alone
+  (``alloc_blocks()``, ``job_alloc_blocks()``) and never meets a stopped
+  member. ``stopped_blocks`` holds blocks a whole-block stop
+  (structs.AllocStopBatch, ``with_stop``) took out of the live table:
+  the same columns with the stop's ``desired_status``,
+  ``desired_description`` and ``modify_index``. Only the read side that
+  answers "which allocations are there" consults it — ``allocs_by_job``
+  / ``_by_node`` / ``_by_eval``, ``alloc_by_id``, ``allocs``,
+  ``has_allocs_for_job``, the FSM snapshot, the core GC — and sees each
+  stopped block as its expansion, as it would see the terminal object
+  rows the stop would otherwise have written.
+- Any write that individually addresses a block member (client status
+  update, eviction, re-placement) *promotes* it: the member is excluded
+  from the block and the superseding Allocation object lands in the
+  object table (a terminal row, for a member of a stopped block).
+- Stored blocks are immutable; exclusion and stopping produce a copy
+  sharing the column arrays (copy-on-write), so snapshots that captured
+  the old table keep a consistent view. Lazy caches (id→position,
+  node→run) are shared across copies — the columns they index never
+  change.
 
 Semantically a block is exactly its ``materialize()`` expansion; the
-differential tests in tests/test_alloc_batch.py and tests/test_state.py
-hold the two forms equal.
+differential tests in tests/test_alloc_batch.py, tests/test_state.py and
+tests/test_block_stop.py hold the two forms equal.
 """
 
 from __future__ import annotations
 
 from typing import Dict, FrozenSet, Iterator, List, Optional, Tuple
 
-from nomad_tpu.structs import AllocBatch, Allocation, generate_uuid
+from nomad_tpu.structs import (
+    ALLOC_DESIRED_STATUS_RUN,
+    AllocBatch,
+    Allocation,
+    generate_uuid,
+)
 
 
 class StoredAllocBlock(AllocBatch):
@@ -37,6 +57,7 @@ class StoredAllocBlock(AllocBatch):
 
     __slots__ = (
         "block_id", "job_id", "create_index", "modify_index", "excluded",
+        "desired_status", "desired_description",
         "_id_pos", "_node_run", "_live_counts", "_materialized",
     )
 
@@ -47,6 +68,8 @@ class StoredAllocBlock(AllocBatch):
         self.create_index = 0
         self.modify_index = 0
         self.excluded: FrozenSet[int] = frozenset()
+        self.desired_status = ALLOC_DESIRED_STATUS_RUN
+        self.desired_description = ""
         self._id_pos: Optional[Dict[str, int]] = None
         self._node_run: Optional[Dict[str, Tuple[int, int]]] = None
         self._live_counts: Optional[Dict[str, int]] = None
@@ -232,9 +255,9 @@ class StoredAllocBlock(AllocBatch):
 
     # -- copy-on-write exclusion ------------------------------------------
 
-    def with_excluded(self, positions) -> "StoredAllocBlock":
-        """A copy of this block with ``positions`` additionally excluded.
-        Columns and lazy caches are shared — they never change."""
+    def _cow(self) -> "StoredAllocBlock":
+        """A copy with every field as it is. Columns and the lazy caches
+        the member set does not decide are shared — they never change."""
         blk = StoredAllocBlock(
             eval_id=self.eval_id, job=self.job, tg_name=self.tg_name,
             resources=self.resources, task_resources=self.task_resources,
@@ -246,9 +269,31 @@ class StoredAllocBlock(AllocBatch):
         blk.job_id = self.job_id
         blk.create_index = self.create_index
         blk.modify_index = self.modify_index
-        blk.excluded = self.excluded | frozenset(positions)
+        blk.excluded = self.excluded
+        blk.desired_status = self.desired_status
+        blk.desired_description = self.desired_description
         blk._id_pos = self._id_pos
         blk._node_run = self._node_run
+        return blk
+
+    def with_excluded(self, positions) -> "StoredAllocBlock":
+        """A copy of this block with ``positions`` additionally excluded."""
+        blk = self._cow()
+        blk.excluded = self.excluded | frozenset(positions)
+        return blk
+
+    def with_stop(self, desired_status: str, desired_description: str,
+                  index: int) -> "StoredAllocBlock":
+        """The terminal form: a copy whose every live member reads
+        ``desired_status`` / ``desired_description`` at ``modify_index``
+        = ``index`` — what ``Plan.append_update`` + ``upsert_allocs``
+        give each member row by row, as ONE field swap. The store keeps
+        it apart from the live table (module docstring)."""
+        blk = self._cow()
+        blk.desired_status = desired_status
+        blk.desired_description = desired_description
+        blk.modify_index = index
+        blk._live_counts = self._live_counts  # same members, same counts
         return blk
 
     # -- persistence (FSM snapshot stream) --------------------------------
@@ -257,6 +302,7 @@ class StoredAllocBlock(AllocBatch):
         "eval_id", "job", "tg_name", "resources", "task_resources",
         "metrics", "node_ids", "node_counts", "name_idx", "ids_seed",
         "block_id", "job_id", "create_index", "modify_index", "excluded",
+        "desired_status", "desired_description",
     )
 
     def __getstate__(self):
@@ -274,6 +320,9 @@ class StoredAllocBlock(AllocBatch):
     def __setstate__(self, state):
         for k in self._PICKLE_SLOTS:
             setattr(self, k, state.get(k))
+        if self.desired_status is None:  # a pickle from before stops
+            self.desired_status = ALLOC_DESIRED_STATUS_RUN
+            self.desired_description = ""
         # Legacy pickles carried the expanded column under "ids_hex".
         self._ids_hex = state.get("_ids_hex", state.get("ids_hex"))
         if self._ids_hex is None and self.ids_seed is None:
@@ -289,6 +338,8 @@ class StoredAllocBlock(AllocBatch):
         d["create_index"] = self.create_index
         d["modify_index"] = self.modify_index
         d["excluded"] = sorted(self.excluded)
+        d["desired_status"] = self.desired_status
+        d["desired_description"] = self.desired_description
         return d
 
     @staticmethod
@@ -305,4 +356,6 @@ class StoredAllocBlock(AllocBatch):
         blk.create_index = int(d.get("create_index", 0))
         blk.modify_index = int(d.get("modify_index", 0))
         blk.excluded = frozenset(d.get("excluded") or ())
+        blk.desired_status = d.get("desired_status", ALLOC_DESIRED_STATUS_RUN)
+        blk.desired_description = d.get("desired_description", "")
         return blk

@@ -177,6 +177,13 @@ def item_alloc(alloc_id: str) -> WatchItem:
     return ("alloc", alloc_id)
 
 
+def _alloc_items(alloc_id: str, job_id: str, node_id: str,
+                 eval_id: str) -> List[WatchItem]:
+    """What one allocation's write or removal wakes."""
+    return [item_alloc(alloc_id), item_alloc_job(job_id),
+            item_alloc_node(node_id), item_alloc_eval(eval_id)]
+
+
 def item_alloc_node(node_id: str) -> WatchItem:
     return ("alloc_node", node_id)
 
@@ -480,6 +487,11 @@ class _Tables:
         # immutable — exclusion replaces the entry with a COW copy — so the
         # snapshot container-copy below stays cheap and consistent.
         self.blocks: Dict[str, StoredAllocBlock] = {}
+        # Blocks a whole-block stop took out of ``blocks``, in their
+        # terminal form (blocks.py with_stop). They hold no usage, so no
+        # usage reader looks here; the read side that lists allocations
+        # does, and sees each as its expansion.
+        self.stopped_blocks: Dict[str, StoredAllocBlock] = {}
         # Secondary indexes: id sets keyed by foreign key.
         self.evals_by_job: Dict[str, Set[str]] = {}
         self.allocs_by_job: Dict[str, Set[str]] = {}
@@ -487,6 +499,8 @@ class _Tables:
         self.allocs_by_eval: Dict[str, Set[str]] = {}
         self.blocks_by_job: Dict[str, Set[str]] = {}
         self.blocks_by_eval: Dict[str, Set[str]] = {}
+        self.stopped_by_job: Dict[str, Set[str]] = {}
+        self.stopped_by_eval: Dict[str, Set[str]] = {}
         # Non-terminal OBJECT rows per job — the O(1) gate for block-level
         # reconciles (a rolling update accumulates terminal stop rows that
         # a scan-based gate would re-walk on every eval). Maintained by
@@ -508,12 +522,16 @@ class _Tables:
         new.evals = dict(self.evals)
         new.allocs = dict(self.allocs)
         new.blocks = dict(self.blocks)
+        new.stopped_blocks = dict(self.stopped_blocks)
         new.evals_by_job = {k: set(v) for k, v in self.evals_by_job.items()}
         new.allocs_by_job = {k: set(v) for k, v in self.allocs_by_job.items()}
         new.allocs_by_node = {k: set(v) for k, v in self.allocs_by_node.items()}
         new.allocs_by_eval = {k: set(v) for k, v in self.allocs_by_eval.items()}
         new.blocks_by_job = {k: set(v) for k, v in self.blocks_by_job.items()}
         new.blocks_by_eval = {k: set(v) for k, v in self.blocks_by_eval.items()}
+        new.stopped_by_job = {k: set(v) for k, v in self.stopped_by_job.items()}
+        new.stopped_by_eval = {
+            k: set(v) for k, v in self.stopped_by_eval.items()}
         new.live_objs_by_job = dict(self.live_objs_by_job)
         # Logs are SHARED by reference: between trims they're append-only
         # (list.append is atomic under the GIL, and readers filter by
@@ -569,32 +587,41 @@ class _StateView:
     # -- allocs -----------------------------------------------------------
 
     def alloc_by_id(self, alloc_id: str) -> Optional[Allocation]:
-        alloc = self._t.allocs.get(alloc_id)
-        if alloc is not None or not self._t.blocks:
+        t = self._t
+        alloc = t.allocs.get(alloc_id)
+        if alloc is not None:
             return alloc
-        for blk in self._t.blocks.values():
-            pos = blk.find(alloc_id)
-            if pos is not None:
-                return blk.materialize_pos(pos)
+        for table in (t.blocks, t.stopped_blocks):
+            found = _find_block_member(table, alloc_id)
+            if found is not None:
+                return table[found[0]].materialize_pos(found[1])
         return None
 
     def allocs(self) -> List[Allocation]:
         out = list(self._t.allocs.values())
         for blk in self._t.blocks.values():
             out.extend(blk.materialize())
+        for blk in self._t.stopped_blocks.values():
+            out.extend(blk.materialize())
         return out
 
     def alloc_count(self) -> int:
         """Cheap table cardinality (used by the solver's clean-state fast
         path to skip usage tensorization entirely)."""
-        return len(self._t.allocs) + sum(
-            blk.n_live for blk in self._t.blocks.values()
-        )
+        t = self._t
+        return len(t.allocs) + sum(
+            blk.n_live for blk in t.blocks.values()
+        ) + sum(blk.n_live for blk in t.stopped_blocks.values())
 
     def alloc_blocks(self) -> List[StoredAllocBlock]:
         """Live columnar blocks — the no-materialization read for plan
         verification and the device mirror."""
         return list(self._t.blocks.values())
+
+    def stopped_alloc_blocks(self) -> List[StoredAllocBlock]:
+        """Blocks stopped whole, in their terminal form (no usage: the
+        FSM snapshot persists them beside the live ones)."""
+        return list(self._t.stopped_blocks.values())
 
     def allocs_objects(self) -> List[Allocation]:
         """Object-table rows only (the complement of alloc_blocks())."""
@@ -610,15 +637,19 @@ class _StateView:
         out = [self._t.allocs[i] for i in ids]
         for bid in self._t.blocks_by_job.get(job_id, ()):
             out.extend(self._t.blocks[bid].materialize())
+        for bid in self._t.stopped_by_job.get(job_id, ()):
+            out.extend(self._t.stopped_blocks[bid].materialize())
         return out
 
     def has_allocs_for_job(self, job_id: str) -> bool:
         """Existence check WITHOUT materializing columnar blocks — the
         guard fast paths (fresh-registration detection) need only the
-        answer, not 100k Allocation objects."""
-        if self._t.allocs_by_job.get(job_id):
-            return True
-        return bool(self._t.blocks_by_job.get(job_id))
+        answer, not 100k Allocation objects. Stopped allocations count,
+        in a block as in a row."""
+        t = self._t
+        return bool(t.allocs_by_job.get(job_id)
+                    or t.blocks_by_job.get(job_id)
+                    or t.stopped_by_job.get(job_id))
 
     def job_has_object_allocs(self, job_id: str) -> bool:
         """Whether any NON-TERMINAL allocations of the job live as object
@@ -636,9 +667,10 @@ class _StateView:
 
     def allocs_by_node(self, node_id: str) -> List[Allocation]:
         out = self.allocs_by_node_objects(node_id)
-        for blk in self._t.blocks.values():
-            if blk.node_runs().get(node_id) is not None:
-                out = out + blk.materialize_node(node_id)
+        for table in (self._t.blocks, self._t.stopped_blocks):
+            for blk in table.values():
+                if blk.node_runs().get(node_id) is not None:
+                    out = out + blk.materialize_node(node_id)
         return out
 
     def allocs_by_node_objects(self, node_id: str) -> List[Allocation]:
@@ -653,7 +685,23 @@ class _StateView:
         out = [self._t.allocs[i] for i in ids]
         for bid in self._t.blocks_by_eval.get(eval_id, ()):
             out.extend(self._t.blocks[bid].materialize())
+        for bid in self._t.stopped_by_eval.get(eval_id, ()):
+            out.extend(self._t.stopped_blocks[bid].materialize())
         return out
+
+    def eval_gc_rows(self, eval_id: str) -> Optional[List[Allocation]]:
+        """What the core GC needs of an evaluation's allocations without
+        expanding a block: None while any of them is live (a block in the
+        live table, or a non-terminal row), else its object rows. Its
+        stopped blocks are not listed member by member: ``delete_eval``
+        takes an evaluation's blocks with it wholesale."""
+        t = self._t
+        if t.blocks_by_eval.get(eval_id):
+            return None
+        rows = [t.allocs[i] for i in t.allocs_by_eval.get(eval_id, ())]
+        if any(not a.terminal_status() for a in rows):
+            return None
+        return rows
 
     # -- change logs (delta consumers: the device mirror) -----------------
 
@@ -799,6 +847,10 @@ class StateSnapshot(_StateView):
         self.optimistic = True
         _apply_update_batches(self._t, index, batches)
 
+    def apply_stop_batches(self, index: int, batches) -> None:
+        self.optimistic = True
+        _apply_stop_batches(self._t, index, batches)
+
 
 class StateRestore:
     """Bulk loader used by FSM snapshot restore
@@ -836,9 +888,8 @@ class StateRestore:
 
     def block_restore(self, block: StoredAllocBlock) -> None:
         t = self._tables
-        t.blocks[block.block_id] = block
-        t.blocks_by_job.setdefault(block.job_id, set()).add(block.block_id)
-        t.blocks_by_eval.setdefault(block.eval_id, set()).add(block.block_id)
+        _index_block(
+            t, block, stopped=block.desired_status != ALLOC_DESIRED_STATUS_RUN)
         t.indexes["allocs"] = max(
             t.indexes.get("allocs", 0), block.modify_index
         )
@@ -850,13 +901,41 @@ class StateRestore:
         self._store._install(self._tables)
 
 
-def _find_block_member(t: _Tables, alloc_id: str):
-    """(block_id, pos) of a live block member, or None."""
-    for bid, blk in t.blocks.items():
+def _find_block_member(blocks: Dict[str, StoredAllocBlock], alloc_id: str):
+    """(block_id, pos) of a member of one of ``blocks`` (the live table
+    or the stopped one) that is not excluded, or None."""
+    for bid, blk in blocks.items():
         pos = blk.find(alloc_id)
         if pos is not None:
             return bid, pos
     return None
+
+
+def _block_tables(t: _Tables, stopped: bool):
+    """(blocks, by job, by eval) of the live or the stopped table."""
+    if stopped:
+        return t.stopped_blocks, t.stopped_by_job, t.stopped_by_eval
+    return t.blocks, t.blocks_by_job, t.blocks_by_eval
+
+
+def _index_block(t: _Tables, blk: StoredAllocBlock,
+                 stopped: bool = False) -> None:
+    blocks, by_job, by_eval = _block_tables(t, stopped)
+    blocks[blk.block_id] = blk
+    by_job.setdefault(blk.job_id, set()).add(blk.block_id)
+    by_eval.setdefault(blk.eval_id, set()).add(blk.block_id)
+
+
+def _unindex_block(t: _Tables, blk: StoredAllocBlock,
+                   stopped: bool = False) -> None:
+    blocks, by_job, by_eval = _block_tables(t, stopped)
+    del blocks[blk.block_id]
+    for idx_map, key in ((by_job, blk.job_id), (by_eval, blk.eval_id)):
+        ids = idx_map.get(key)
+        if ids is not None:
+            ids.discard(blk.block_id)
+            if not ids:
+                del idx_map[key]
 
 
 def _decr_live_objs(t: _Tables, job_id: str) -> None:
@@ -887,34 +966,60 @@ def _insert_alloc_row(t: _Tables, alloc: Allocation,
 
 
 def _exclude_block_members(t: _Tables, members: Dict[str, Set[int]],
-                           delta: Optional[_AllocDelta] = None) -> None:
+                           delta: Optional[_AllocDelta] = None,
+                           stopped: bool = False) -> None:
     """Replace blocks with COW copies excluding ``members`` ({block_id:
-    positions}). A block whose exclusion set reaches half its size
-    dissolves — remaining members become object rows — so per-member
-    promotion cost stays O(n) over a block's whole life instead of the
-    frozenset-union O(n^2). ``delta`` (when given) is told which block
-    objects went and came, and the object rows a dissolve made."""
+    positions}), in the live table or (``stopped``) the stopped one. A
+    block whose exclusion set reaches half its size dissolves — remaining
+    members become object rows — so per-member promotion cost stays O(n)
+    over a block's whole life instead of the frozenset-union O(n^2).
+    ``delta`` (when given) is told which block objects went and came (a
+    stopped block holds no usage: it is neither), and the object rows a
+    dissolve made."""
+    blocks = _block_tables(t, stopped)[0]
     for bid, positions in members.items():
-        old = t.blocks[bid]
+        old = blocks[bid]
         blk = old.with_excluded(positions)
         dissolve = blk.n_live == 0 or len(blk.excluded) * 2 >= blk.n
-        if delta is not None:
+        if delta is not None and not stopped:
             delta.removed.append(old)
             if not dissolve:
                 delta.added.append(blk)
         if dissolve:
             for alloc in blk.materialize():
                 _insert_alloc_row(t, alloc, delta)
-            del t.blocks[bid]
-            for idx_map, key in ((t.blocks_by_job, blk.job_id),
-                                 (t.blocks_by_eval, blk.eval_id)):
-                ids = idx_map.get(key)
-                if ids is not None:
-                    ids.discard(bid)
-                    if not ids:
-                        del idx_map[key]
+            _unindex_block(t, blk, stopped)
         else:
-            t.blocks[bid] = blk
+            blocks[bid] = blk
+
+
+def _promote_members(t: _Tables, alloc_ids,
+                     delta: Optional[_AllocDelta] = None,
+                     on_member=None) -> None:
+    """Take the block members among ``alloc_ids`` that have no object
+    row out of their blocks, live or stopped: one COW exclusion a block.
+    ``on_member(alloc_id, block, pos, stopped)`` runs for each before
+    its block is replaced."""
+    t_allocs = t.allocs
+    for stopped in (False, True):
+        blocks = _block_tables(t, stopped)[0]
+        if not blocks:
+            continue
+        members: Dict[str, Set[int]] = {}
+        for alloc_id in alloc_ids:
+            if alloc_id in t_allocs:
+                continue
+            found = _find_block_member(blocks, alloc_id)
+            if found is None:
+                continue
+            bid, pos = found
+            if bid in members and pos in members[bid]:
+                continue
+            members.setdefault(bid, set()).add(pos)
+            if on_member is not None:
+                on_member(alloc_id, blocks[bid], pos, stopped)
+        if members:
+            _exclude_block_members(t, members, delta, stopped)
 
 
 def _upsert_allocs(t: _Tables, index: int, allocs: List[Allocation],
@@ -930,23 +1035,21 @@ def _upsert_allocs(t: _Tables, index: int, allocs: List[Allocation],
                 delta.nodes.add(existing.node_id)
     # An object row superseding a block member (eviction, re-placement,
     # client-side restamp) promotes it out of the block.
-    if t.blocks:
-        members: Dict[str, Set[int]] = {}
+    if t.blocks or t.stopped_blocks:
+        by_id: Dict[str, List[Allocation]] = {}
         for alloc in allocs:
-            if alloc.id in t.allocs:
-                continue
-            found = _find_block_member(t, alloc.id)
-            if found is not None:
-                bid, pos = found
-                members.setdefault(bid, set()).add(pos)
-                if delta is not None:
-                    # A superseded member's OLD node loses its block
-                    # usage — a cross-node restamp must dirty both ends.
-                    delta.nodes.add(t.blocks[bid].node_of_pos(pos))
+            by_id.setdefault(alloc.id, []).append(alloc)
+
+        def superseded(alloc_id, blk, pos, stopped):
+            if delta is not None and not stopped:
+                # A superseded member's OLD node loses its block
+                # usage — a cross-node restamp must dirty both ends.
+                delta.nodes.add(blk.node_of_pos(pos))
+            for alloc in by_id[alloc_id]:
                 if alloc.create_index == 0:
-                    alloc.create_index = t.blocks[bid].create_index
-        if members:
-            _exclude_block_members(t, members, delta)
+                    alloc.create_index = blk.create_index
+
+        _promote_members(t, by_id, delta, superseded)
     for alloc in allocs:
         existing = t.allocs.get(alloc.id)
         if existing is None:
@@ -989,7 +1092,7 @@ def _apply_update_batches(t: _Tables, index: int, batches,
             if row is not None:
                 object_rows.append(row)
                 continue
-            found = _find_block_member(t, aid)
+            found = _find_block_member(t.blocks, aid)
             if found is not None:
                 members.setdefault(found[0], set()).add(found[1])
             # Unknown ids: removed while the plan was in flight — exactly
@@ -1093,9 +1196,7 @@ def _upsert_alloc_blocks(t: _Tables, index: int, batches,
         if batch.n == 0:
             continue
         blk = StoredAllocBlock.from_batch(batch, index)
-        t.blocks[blk.block_id] = blk
-        t.blocks_by_job.setdefault(blk.job_id, set()).add(blk.block_id)
-        t.blocks_by_eval.setdefault(blk.eval_id, set()).add(blk.block_id)
+        _index_block(t, blk)
         items.append(item_alloc_job(blk.job_id))
         items.append(item_alloc_eval(blk.eval_id))
         committed.append(blk)
@@ -1107,6 +1208,69 @@ def _upsert_alloc_blocks(t: _Tables, index: int, batches,
         for blk in committed:
             items.extend(item_alloc_node(nid) for nid in blk.node_ids)
     return items
+
+
+def _apply_stop_batches(t: _Tables, index: int, batches,
+                        watch: "_Watch" = None,
+                        delta: Optional[_AllocDelta] = None,
+                        ) -> Tuple[List[WatchItem], List[Optional[List[Allocation]]]]:
+    """Stops of whole stored blocks (structs.AllocStopBatch). Where the
+    named block stands in the live table with the live members the
+    scheduler saw, it moves to the stopped table in its terminal form:
+    one dict removal, one COW header, O(1) whatever its size; the change
+    log is told the block went (``removed``), which is all a usage reader
+    needs. Where it does not — a member promoted, the block dissolved,
+    since the plan's snapshot — the batch resolves to the ids it names,
+    and those still running stop row by row through ``_upsert_allocs``,
+    as ``Plan.append_update`` would have stopped them. The outcome is a
+    function of the table and the batch alone: the same on every replica.
+
+    Returns (watch items, per batch None for a block stopped whole or the
+    rows stopped one by one)."""
+    items: List[WatchItem] = [item_table("allocs")]
+    outcomes: List[Optional[List[Allocation]]] = []
+    moved: List[StoredAllocBlock] = []
+    for b in batches:
+        blk = t.blocks.get(b.block_id)
+        if (blk is not None and blk.n_live == b.n_live
+                and blk.job_id == b.job_id):
+            _unindex_block(t, blk)
+            _index_block(
+                t, blk.with_stop(b.desired_status, b.desired_description,
+                                 index),
+                stopped=True)
+            if delta is not None:
+                delta.removed.append(blk)
+                delta.nodes.update(blk.node_ids)
+            items.append(item_alloc_job(blk.job_id))
+            items.append(item_alloc_eval(blk.eval_id))
+            moved.append(blk)
+            outcomes.append(None)
+            continue
+        rows: List[Allocation] = []
+        for pos, alloc_id in enumerate(b.member_ids()):
+            alloc = t.allocs.get(alloc_id)
+            if (alloc is None and blk is not None
+                    and blk.find(alloc_id) is not None):
+                alloc = blk.materialize_pos(pos)
+            if alloc is None or alloc.terminal_status():
+                continue  # gone, or stopped already
+            alloc = alloc.copy()
+            alloc.desired_status = b.desired_status
+            alloc.desired_description = b.desired_description
+            rows.append(alloc)
+        if rows:
+            _upsert_allocs(t, index, rows, delta)
+            for alloc in rows:
+                items.extend(_alloc_items(
+                    alloc.id, alloc.job_id, alloc.node_id, alloc.eval_id))
+        outcomes.append(rows)
+    t.indexes["allocs"] = index
+    if moved and watch is not None and watch.has_waiters_for("alloc_node"):
+        # A client long-polls its node's item to learn its tasks stopped.
+        for blk in moved:
+            items.extend(item_alloc_node(nid) for nid in blk.node_ids)
+    return items, outcomes
 
 
 class StateStore(_StateView):
@@ -1265,6 +1429,7 @@ class StateStore(_StateView):
         (reference: state_store.go DeleteEval)."""
         items: List[WatchItem] = [item_table("evals"), item_table("allocs")]
         reaped_blocks: List[StoredAllocBlock] = []
+        reaped_stopped: List[StoredAllocBlock] = []
         delta = _AllocDelta()
         with self._lock:
             t = self._t
@@ -1277,44 +1442,25 @@ class StateStore(_StateView):
                         if not ids:
                             del t.evals_by_job[ev.job_id]
                     items.append(item_eval(eval_id))
-                # A reaped eval takes its columnar blocks with it wholesale.
-                for bid in list(t.blocks_by_eval.get(eval_id, ())):
-                    blk = t.blocks.pop(bid, None)
-                    if blk is None:
-                        continue
-                    ids = t.blocks_by_job.get(blk.job_id)
-                    if ids is not None:
-                        ids.discard(bid)
-                        if not ids:
-                            del t.blocks_by_job[blk.job_id]
-                    items.append(item_alloc_job(blk.job_id))
-                    items.append(item_alloc_eval(blk.eval_id))
-                    reaped_blocks.append(blk)
-                t.blocks_by_eval.pop(eval_id, None)
-            block_members: Dict[str, Set[int]] = {}
+                # A reaped eval takes its columnar blocks with it wholesale,
+                # the stopped ones too (they held no usage: not the log's).
+                for stopped in (False, True):
+                    blocks, _by_job, by_eval = _block_tables(t, stopped)
+                    for bid in list(by_eval.get(eval_id, ())):
+                        blk = blocks[bid]
+                        _unindex_block(t, blk, stopped)
+                        items.append(item_alloc_job(blk.job_id))
+                        items.append(item_alloc_eval(blk.eval_id))
+                        (reaped_stopped if stopped
+                         else reaped_blocks).append(blk)
+            in_blocks: List[str] = []
             for alloc_id in alloc_ids:
                 alloc = t.allocs.pop(alloc_id, None)
-                if alloc is not None and not alloc.terminal_status():
-                    _decr_live_objs(t, alloc.job_id)
                 if alloc is None:
-                    if t.blocks:
-                        found = _find_block_member(t, alloc_id)
-                        if found is not None:
-                            bid, pos = found
-                            block_members.setdefault(bid, set()).add(pos)
-                            # Watchers see block-member deletions exactly
-                            # like object-row deletions.
-                            blk = t.blocks[bid]
-                            delta.nodes.add(blk.node_of_pos(pos))
-                            items.extend(
-                                [
-                                    item_alloc(alloc_id),
-                                    item_alloc_job(blk.job_id),
-                                    item_alloc_node(blk.node_of_pos(pos)),
-                                    item_alloc_eval(blk.eval_id),
-                                ]
-                            )
+                    in_blocks.append(alloc_id)
                     continue
+                if not alloc.terminal_status():
+                    _decr_live_objs(t, alloc.job_id)
                 for idx_map, key in (
                     (t.allocs_by_job, alloc.job_id),
                     (t.allocs_by_node, alloc.node_id),
@@ -1327,16 +1473,19 @@ class StateStore(_StateView):
                             del idx_map[key]
                 delta.nodes.add(alloc.node_id)
                 delta.rows.append((alloc, None))
-                items.extend(
-                    [
-                        item_alloc(alloc_id),
-                        item_alloc_job(alloc.job_id),
-                        item_alloc_node(alloc.node_id),
-                        item_alloc_eval(alloc.eval_id),
-                    ]
-                )
-            if block_members:
-                _exclude_block_members(t, block_members, delta)
+                items.extend(_alloc_items(
+                    alloc_id, alloc.job_id, alloc.node_id, alloc.eval_id))
+
+            def deleted_member(alloc_id, blk, pos, stopped):
+                # Watchers see block-member deletions exactly like
+                # object-row deletions.
+                node_id = blk.node_of_pos(pos)
+                if not stopped:
+                    delta.nodes.add(node_id)
+                items.extend(_alloc_items(
+                    alloc_id, blk.job_id, node_id, blk.eval_id))
+
+            _promote_members(t, in_blocks, delta, deleted_member)
             for blk in reaped_blocks:
                 delta.nodes.update(blk.node_ids)
             delta.removed.extend(reaped_blocks)
@@ -1346,8 +1495,9 @@ class StateStore(_StateView):
             # Gated member items, sampled AFTER the index stamps (the
             # has_waiters_for ordering contract): a late-registering
             # blocking query re-checks against the stamped index.
-            if reaped_blocks and self.watch.has_waiters_for("alloc_node"):
-                for blk in reaped_blocks:
+            if ((reaped_blocks or reaped_stopped)
+                    and self.watch.has_waiters_for("alloc_node")):
+                for blk in reaped_blocks + reaped_stopped:
                     items.extend(item_alloc_node(n) for n in blk.node_ids)
         self.watch.notify(items)
 
@@ -1396,6 +1546,23 @@ class StateStore(_StateView):
             _log_alloc_delta(self._t, index, delta)
         self.watch.notify(items)
 
+    def apply_stop_batches(self, index: int,
+                           batches) -> List[Optional[List[Allocation]]]:
+        """Commit stops of whole stored blocks (AllocStopBatch): each
+        block named moves to the stopped table in O(1); one that changed
+        since the plan's snapshot stops member by member. The observable
+        result is exactly every live member upserted with the batch's
+        desired status and description. Returns, per batch, None for a
+        block stopped whole or the rows stopped one by one."""
+        delta = _AllocDelta()
+        with self._lock:
+            items, outcomes = _apply_stop_batches(
+                self._t, index, batches, watch=self.watch, delta=delta,
+            )
+            _log_alloc_delta(self._t, index, delta)
+        self.watch.notify(items)
+        return outcomes
+
     def update_alloc_from_client(self, index: int, alloc: Allocation) -> None:
         self.update_allocs_from_client(index, [alloc])
 
@@ -1409,24 +1576,17 @@ class StateStore(_StateView):
         items: List[WatchItem] = [item_table("allocs")]
         with self._lock:
             t = self._t
-            if t.blocks:
-                members: Dict[str, Set[int]] = {}
+            if t.blocks or t.stopped_blocks:
                 # A promotion moves no usage between nodes (``nodes`` stays
                 # empty) but between a block and the object table: the log
-                # says so, for consumers that keep the two apart.
+                # says so, for consumers that keep the two apart. (A member
+                # of a stopped block becomes a terminal row: no usage.)
                 delta = _AllocDelta()
-                for alloc in allocs:
-                    if alloc.id in t.allocs:
-                        continue
-                    found = _find_block_member(t, alloc.id)
-                    if found is not None:
-                        bid, pos = found
-                        members.setdefault(bid, set()).add(pos)
-                        _insert_alloc_row(
-                            t, t.blocks[bid].materialize_pos(pos), delta)
-                if members:
-                    _exclude_block_members(t, members, delta)
-                    _log_alloc_delta(t, index, delta)
+                _promote_members(
+                    t, [alloc.id for alloc in allocs], delta,
+                    lambda _id, blk, pos, _stopped: _insert_alloc_row(
+                        t, blk.materialize_pos(pos), delta))
+                _log_alloc_delta(t, index, delta)
             missing: List[str] = []
             for alloc in allocs:
                 existing = t.allocs.get(alloc.id)

@@ -918,10 +918,12 @@ class AllocBatch:
         )
 
     # Stored-form overrides (state/blocks.py StoredAllocBlock): a plain
-    # batch has no commit indexes and no excluded members.
+    # batch has no commit indexes, no excluded members, and runs.
     create_index = 0
     modify_index = 0
     excluded: frozenset = frozenset()
+    desired_status = ALLOC_DESIRED_STATUS_RUN
+    desired_description = ""
 
     def _template(self) -> dict:
         job_name = self.job.name if self.job is not None else ""
@@ -931,8 +933,8 @@ class AllocBatch:
             "job_id": job_id, "job": self.job, "task_group": self.tg_name,
             "resources": self.resources,
             "task_resources": self.task_resources, "metrics": self.metrics,
-            "desired_status": ALLOC_DESIRED_STATUS_RUN,
-            "desired_description": "",
+            "desired_status": self.desired_status,
+            "desired_description": self.desired_description,
             "client_status": ALLOC_CLIENT_STATUS_PENDING,
             "client_description": "",
             "create_index": self.create_index,
@@ -1178,13 +1180,90 @@ class AllocUpdateBatch:
         )
 
 
+class AllocStopBatch:
+    """Columnar stop of one whole stored block: the block is named, never
+    its members. The scheduler emits one per StoredAllocBlock of a job
+    that is gone (tpu/solver.py ``_stop_whole_blocks``); plan verification
+    commits it without a fit check (a stop frees capacity); the FSM moves
+    the block from the live table to the stopped one in O(1)
+    (state/store.py ``_apply_stop_batches``). Semantically it is exactly
+    ``Plan.append_update(member, desired_status, desired_description)``
+    for every live member of the block.
+
+    ``n_live`` is what the scheduler saw: where the stored block no
+    longer has that many live members at apply (a member promoted, the
+    block dissolved), the store resolves the batch to ``member_ids()`` —
+    every id of the block, derived from ``ids_seed`` and ``n_total`` as
+    the block derives them — and stops those still running, row by row.
+
+    ``node_ids`` is the block's own list of nodes, held by reference for
+    the commit footprint on the leader; the raft entry leaves it out (the
+    FSM needs none of it), so a stop is a few hundred bytes on the wire
+    whatever the block's size.
+    """
+
+    __slots__ = ("eval_id", "job_id", "block_id", "n_live", "n_total",
+                 "ids_seed", "desired_status", "desired_description",
+                 "node_ids")
+
+    def __init__(self, eval_id="", job_id="", block_id="", n_live=0,
+                 n_total=0, ids_seed=0,
+                 desired_status=ALLOC_DESIRED_STATUS_STOP,
+                 desired_description="", node_ids=None):
+        self.eval_id = eval_id
+        self.job_id = job_id
+        self.block_id = block_id
+        self.n_live = int(n_live)
+        self.n_total = int(n_total)
+        self.ids_seed = int(ids_seed)
+        self.desired_status = desired_status
+        self.desired_description = desired_description
+        self.node_ids: List[str] = node_ids if node_ids is not None else []
+
+    @property
+    def n(self) -> int:
+        return self.n_live
+
+    def member_ids(self) -> List[str]:
+        """Every alloc id of the named block, position order."""
+        ids = AllocBatch(ids_seed=self.ids_seed, name_idx=range(self.n_total))
+        return [ids.alloc_id(i) for i in range(self.n_total)]
+
+    def to_wire(self) -> dict:
+        return {
+            "eval_id": self.eval_id,
+            "job_id": self.job_id,
+            "block_id": self.block_id,
+            "n_live": self.n_live,
+            "n_total": self.n_total,
+            "ids_seed": "{:032x}".format(self.ids_seed),
+            "desired_status": self.desired_status,
+            "desired_description": self.desired_description,
+        }
+
+    @staticmethod
+    def from_wire(d: dict) -> "AllocStopBatch":
+        return AllocStopBatch(
+            eval_id=d.get("eval_id", ""),
+            job_id=d.get("job_id", ""),
+            block_id=d.get("block_id", ""),
+            n_live=d.get("n_live", 0),
+            n_total=d.get("n_total", 0),
+            ids_seed=int(d.get("ids_seed") or "0", 16),
+            desired_status=d.get("desired_status",
+                                 ALLOC_DESIRED_STATUS_STOP),
+            desired_description=d.get("desired_description", ""),
+        )
+
+
 @dataclass
 class Plan:
     """Commit plan for task allocations (reference: structs.go:1462-1532).
 
     ``alloc_batches`` extends the reference's per-node Allocation lists with
     columnar placement blocks (AllocBatch) for large solves;
-    ``update_batches`` carries columnar in-place updates."""
+    ``update_batches`` carries columnar in-place updates and
+    ``stop_batches`` stops of whole stored blocks."""
 
     eval_id: str = ""
     eval_token: str = ""
@@ -1214,6 +1293,7 @@ class Plan:
     failed_allocs: List[Allocation] = field(default_factory=list)
     alloc_batches: List[AllocBatch] = field(default_factory=list)
     update_batches: List[AllocUpdateBatch] = field(default_factory=list)
+    stop_batches: List[AllocStopBatch] = field(default_factory=list)
 
     def append_update(self, alloc: Allocation, status: str, desc: str) -> None:
         new_alloc = alloc.copy()
@@ -1237,6 +1317,9 @@ class Plan:
     def append_update_batch(self, batch: AllocUpdateBatch) -> None:
         self.update_batches.append(batch)
 
+    def append_stop_batch(self, batch: AllocStopBatch) -> None:
+        self.stop_batches.append(batch)
+
     def append_failed(self, alloc: Allocation) -> None:
         self.failed_allocs.append(alloc)
 
@@ -1247,6 +1330,7 @@ class Plan:
             and not self.failed_allocs
             and not self.alloc_batches
             and not self.update_batches
+            and not self.stop_batches
         )
 
 
@@ -1259,6 +1343,7 @@ class PlanResult:
     failed_allocs: List[Allocation] = field(default_factory=list)
     alloc_batches: List[AllocBatch] = field(default_factory=list)
     update_batches: List[AllocUpdateBatch] = field(default_factory=list)
+    stop_batches: List[AllocStopBatch] = field(default_factory=list)
     refresh_index: int = 0
     alloc_index: int = 0
     # Transaction-time conflict attribution (plan_pipeline): the refresh
@@ -1274,6 +1359,7 @@ class PlanResult:
             and not self.failed_allocs
             and not self.alloc_batches
             and not self.update_batches
+            and not self.stop_batches
         )
 
     def full_commit(self, plan: Plan) -> Tuple[bool, int, int]:
@@ -1286,6 +1372,8 @@ class PlanResult:
         actual += sum(b.n for b in self.alloc_batches)
         expected += sum(b.n for b in plan.update_batches)
         actual += sum(b.n for b in self.update_batches)
+        expected += sum(b.n for b in plan.stop_batches)
+        actual += sum(b.n for b in self.stop_batches)
         return actual == expected, expected, actual
 
 

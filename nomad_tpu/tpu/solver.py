@@ -44,7 +44,7 @@ from nomad_tpu.ops.binpack import (
 from nomad_tpu.scheduler import DEVICE_BREAKER
 from nomad_tpu.scheduler.context import EvalContext
 from nomad_tpu.scheduler.feasible import _has_distinct_hosts
-from nomad_tpu.scheduler.generic import GenericScheduler
+from nomad_tpu.scheduler.generic import ALLOC_NOT_NEEDED, GenericScheduler
 from nomad_tpu.scheduler.rank import RankedNode
 from nomad_tpu.scheduler.stack import (
     BATCH_JOB_ANTI_AFFINITY_PENALTY,
@@ -63,6 +63,7 @@ from nomad_tpu.structs import (
     ALLOC_DESIRED_STATUS_FAILED,
     ALLOC_DESIRED_STATUS_RUN,
     ALLOC_DESIRED_STATUS_STOP,
+    AllocStopBatch,
     Allocation,
     Job,
     Node,
@@ -786,12 +787,20 @@ class TPUGenericScheduler(GenericScheduler):
           via AllocUpdateBatch under a per-node delta headroom check,
           never touching the per-alloc select (util.go:316-398).
 
-        Anything needing stops, migrations, destructive updates, or
-        network reoffers falls through to the reference-shaped object
-        diff (generic_sched.go:186-243).
+        - A job that is gone (deregistered): every allocation of it is a
+          stop (util.go:54-131 with nothing required). Where all of them
+          sit in stored blocks, the plan names the blocks
+          (``_stop_whole_blocks``) and no member is materialized.
+
+        Anything else needing stops (a scale-down, a group removed, a
+        rolling destructive update's evictions), migrations, destructive
+        updates, or network reoffers falls through to the
+        reference-shaped object diff (generic_sched.go:186-243).
         """
         job = self.job
         if job is None:
+            if self._stop_whole_blocks():
+                return
             return super().compute_job_allocs()
 
         # Deepest fast path: every existing alloc lives in stored columnar
@@ -1272,6 +1281,39 @@ class TPUGenericScheduler(GenericScheduler):
             self.eval, sum(b.n for b in batches), len(updates),
         )
         return super().inplace_updates(rest) if rest else rest
+
+    def _stop_whole_blocks(self) -> bool:
+        """The job is gone, so the five-way diff would stop every live
+        allocation of it whatever its node (util.go:98-100 comes before
+        the taint check). Where all of them sit in stored blocks — no
+        non-terminal object row, and every block's ids derivable from
+        its seed, which is how a stop batch names the members should the
+        block change before the stop applies — append one AllocStopBatch
+        a block and say so. Otherwise the caller materializes and diffs
+        as the reference does."""
+        state = self.state
+        if not hasattr(state, "job_alloc_blocks") or not hasattr(
+            state, "job_has_object_allocs"
+        ):
+            return False
+        job_id = self.eval.job_id
+        if state.job_has_object_allocs(job_id):
+            return False
+        blocks = state.job_alloc_blocks(job_id)
+        if not blocks or any(blk.ids_seed is None for blk in blocks):
+            return False
+        for blk in blocks:
+            self.plan.append_stop_batch(AllocStopBatch(
+                eval_id=self.eval.id, job_id=job_id,
+                block_id=blk.block_id, n_live=blk.n_live, n_total=blk.n,
+                ids_seed=blk.ids_seed,
+                desired_status=ALLOC_DESIRED_STATUS_STOP,
+                desired_description=ALLOC_NOT_NEEDED,
+                node_ids=blk.node_ids,
+            ))
+        self.logger.debug(
+            "sched: %s: %d blocks stopped whole", self.eval, len(blocks))
+        return True
 
     def _block_reconcile(self):
         """Block-level reconcile: classify whole StoredAllocBlocks as

@@ -62,6 +62,10 @@ class _Committer:
                 self.state.apply_update_batches(
                     self._index, result.update_batches
                 )
+            if result.stop_batches:
+                self.state.apply_stop_batches(
+                    self._index, result.stop_batches
+                )
         else:
             for b in result.alloc_batches:
                 allocs.extend(b.materialize())
@@ -88,10 +92,11 @@ def _mk_world(n_nodes):
     return state
 
 
-def _process(state, planner, job):
+def _process(state, planner, job,
+             trigger=structs.EVAL_TRIGGER_JOB_REGISTER):
     ev = Evaluation(
         id=generate_uuid(), priority=job.priority, type=job.type,
-        triggered_by=structs.EVAL_TRIGGER_JOB_REGISTER, job_id=job.id,
+        triggered_by=trigger, job_id=job.id,
     )
     sched = new_scheduler("tpu-batch", state.snapshot(), planner,
                          logging.getLogger("fuzz"))
@@ -182,6 +187,29 @@ def test_block_vs_object_lifecycle(seed):
             if not a.terminal_status():
                 scan[a.job_id] = scan.get(a.job_id, 0) + 1
         assert scan == t.live_objs_by_job, (seed, op)
+
+    # The job ends: deregistered in both worlds. The columnar world names
+    # whatever blocks it still holds whole and stops the rest row by row;
+    # either way every allocation reads stopped, node for node as in the
+    # object world, and holds nothing.
+    idx += 1
+    for state, planner in ((state_b, planner_b), (state_o, planner_o)):
+        state.delete_job(idx, job.id)
+        _process(state, planner, job, structs.EVAL_TRIGGER_JOB_DEREGISTER)
+
+    def stopped_view(state):
+        rows = state.allocs_by_job(job.id)
+        assert all(a.terminal_status() for a in rows), seed
+        per_node = {}
+        for a in rows:
+            if a.desired_status == structs.ALLOC_DESIRED_STATUS_STOP:
+                per_node[a.node_id] = per_node.get(a.node_id, 0) + 1
+        return len(rows), per_node
+
+    assert stopped_view(state_b) == stopped_view(state_o), seed
+    assert _world_view(state_b, job.id)[0] == 0
+    assert state_b.job_alloc_blocks(job.id) == []
+    assert state_b.alloc_blocks() == []
 
 
 @pytest.mark.parametrize("seed", range(4))

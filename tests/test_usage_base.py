@@ -26,6 +26,7 @@ from nomad_tpu import structs
 from nomad_tpu.structs import (
     AllocBatch,
     Allocation,
+    AllocStopBatch,
     AllocUpdateBatch,
     Job,
     NetworkResource,
@@ -217,6 +218,23 @@ def _log_case_whole_block_update(store, nodes, job):
     return ({nodes[0].id}, [], [new], [old])
 
 
+def _stop_batch(blk):
+    return AllocStopBatch(
+        eval_id="ev-stop", job_id=blk.job_id, block_id=blk.block_id,
+        n_live=blk.n_live, n_total=blk.n, ids_seed=blk.ids_seed,
+        desired_description="alloc not needed due to job update")
+
+
+def _log_case_whole_block_stop(store, nodes, job):
+    batch = _batch(job, [nodes[0].id, nodes[1].id], [4, 4])
+    store.upsert_alloc_blocks(9, [batch])
+    old, = store.alloc_blocks()
+    assert store.apply_stop_batches(10, [_stop_batch(old)]) == [None]
+    # The block went and nothing came: no row, no block in its place.
+    assert store.alloc_blocks() == [] and store.allocs_objects() == []
+    return ({nodes[0].id, nodes[1].id}, [], [], [old])
+
+
 def _log_case_client_update_promotes(store, nodes, job):
     batch = _batch(job, [nodes[0].id, nodes[1].id], [4, 4])
     store.upsert_alloc_blocks(9, [batch])
@@ -234,7 +252,7 @@ def _log_case_client_update_promotes(store, nodes, job):
     _log_case_block_commit, _log_case_object_upsert,
     _log_case_stop_excludes_member, _log_case_exclusion_dissolves,
     _log_case_eval_reaped, _log_case_whole_block_update,
-    _log_case_client_update_promotes,
+    _log_case_whole_block_stop, _log_case_client_update_promotes,
 ], ids=lambda f: f.__name__[len("_log_case_"):])
 def test_alloc_log_names_what_came_and_went(case):
     store, nodes = _cell(8)
@@ -394,6 +412,13 @@ def test_usage_base_advance_matches_full_walk(seed, monkeypatch):
             resources=Resources(cpu=int(rng.integers(5, 90)), memory_mb=32),
             alloc_ids=[blk.alloc_id(p) for p in blk.live_positions()])])
 
+    def stop_whole_block():
+        blks = store.alloc_blocks()
+        if not blks:
+            return commit_block()
+        blk = blks[int(rng.integers(0, len(blks)))]
+        store.apply_stop_batches(idx, [_stop_batch(blk)])
+
     def client_update():
         members = live_members(2)
         if not members:
@@ -411,8 +436,8 @@ def test_usage_base_advance_matches_full_walk(seed, monkeypatch):
         store.upsert_node(idx, node)
 
     writes = [commit_block, commit_block, upsert_objects, stop_members,
-              reap_job_blocks, update_whole_block, client_update,
-              reregister_node]
+              reap_job_blocks, update_whole_block, stop_whole_block,
+              client_update, reregister_node]
     prev = None
     for step in range(14):
         # Mostly one to three writes between two solves; now and then
