@@ -13,8 +13,8 @@ cell-scale evaluation and Sparrow's headline metric call for, PAPERS.md).
 
 The stitcher is strictly read-only on decisions: it consumes retained
 spans and events after the fact, so enabling it cannot perturb placement
-(the SIMLOAD event digest is the enforcement: r08 artifacts carry this
-section with digests identical to the pre-attribution r07 runs).
+(the canonical event digest is the enforcement: a steady-10k run carries
+this section with the digest it had before attribution existed).
 
 Stage classification (a PARTITION of submit→placed, so stage sums reconcile
 with measured end-to-end latency by construction — ``unattributed``

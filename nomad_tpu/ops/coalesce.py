@@ -189,8 +189,8 @@ class _Group:
         with self._fetch_lock:
             if self._host is None:
                 # Split the first fetcher's wall into the shared
-                # execute/readback stage cuts (bench.py's breakdown
-                # uses the same names through the same StageTimer). An
+                # execute/readback stage cuts (the solve's one
+                # StageTimer; the benchmark reads them as spans). An
                 # async device fault surfaces here and raises to the
                 # fetching eval.
                 with trace.stage("execute"), \

@@ -23,8 +23,9 @@ cost one dispatch.
 
 Semantics are those of solve_waterfill (differential-tested in interpret
 mode in tests/test_pallas_solve.py, lowered for TPU without a device in
-tests/test_pallas_lowering.py, compiled and compared on the chip by
-chip_smoke.py). Reference semantics: AllocsFit/ScoreFit
+tests/test_pallas_lowering.py; on the chip it is what every drain cell
+of the benchmark runs, with ``correct`` decided against
+benchmark/reference.py). Reference semantics: AllocsFit/ScoreFit
 (/root/reference/nomad/structs/funcs.go:44-124) and the Select loop it
 reformulates (/root/reference/scheduler/stack.go:131-159).
 """
@@ -54,7 +55,8 @@ _NODE_TILE = 8 * _LANES
 # 16 dense [N] vectors of 4 bytes, double-buffered by the grid pipeline,
 # plus about a dozen live temporaries: under 256 B/node, which is what
 # the call asks Mosaic for — 32 MiB at the 131,072 bucket, the largest
-# chip_smoke.py compiles and compares on the chip, against a v5e's
+# that was compiled and compared with the jnp water-fill on a chip
+# (PR 21; no benchmark cell reaches it: ROADMAP C4), against a v5e's
 # 128 MiB of VMEM. Larger buckets take the jnp water-fill.
 PALLAS_MAX_NODES = 131072
 

@@ -8,7 +8,7 @@ crc32(name)``, so
 - two streams with different names are independent — adding a draw at
   one site never shifts another site's decision sequence, and
 - for a fixed seed the n-th draw of a named stream is the same run after
-  run — the seed-replay contract SIMLOAD digests and fuzz families pin.
+  run — the seed-replay contract simcluster digests and fuzz families pin.
 
 The process-global ``random`` module gives neither property: every
 caller shares one cursor, so any new draw anywhere reorders everyone

@@ -41,8 +41,8 @@ three ledgers:
   samples (stdlib only: ``/proc/self/statm`` + ``getrusage``) — with a
   **projected 1M-row mirror footprint** computed from the MEASURED
   per-row cost (bytes / padded rows × the 1048576-row padding bucket):
-  the item-7 fit-check, banked in the ``profile`` section of SIMLOAD
-  artifacts.
+  the item-7 fit-check, carried in the ``profile`` section of a
+  simcluster artifact.
 
 Decision-invariance is the contract, as for every observatory before
 it: the profiler publishes only on the ``Runtime`` observer topic
@@ -57,7 +57,7 @@ JSON + ``?format=prometheus``), SDK ``client.agent().profile()`` /
 ``.runtime()``, ``nomad_profile_*`` / ``nomad_runtime_*`` /
 ``nomad_lock_*`` lines on the main Prometheus scrape, the debug
 bundle's ``profile`` and ``runtime`` sections, and a ``profile``
-section in every SIMLOAD artifact.
+section in every simcluster artifact.
 """
 
 from __future__ import annotations
@@ -481,8 +481,8 @@ class RuntimeObservatory:
                 "events_published": self.events_published}
 
     def snapshot(self) -> Dict[str, Any]:
-        """The full self-observatory report (the SIMLOAD ``profile``
-        section + bundle body): wall shares, the ranked contention
+        """The full self-observatory report (the simcluster artifact's
+        ``profile`` section + bundle body): wall shares, the ranked contention
         table, the byte economy with the 1M-row projection."""
         out = self.profile_view()
         rt = self.runtime_view()

@@ -45,8 +45,8 @@ Surfaces: ``/v1/agent/raft`` (JSON + ``?format=prometheus``), SDK
 (observer topic — excluded from the canonical determinism digest by
 construction, ``events.OBSERVER_TOPICS``), the debug bundle's ``raft``
 section, ``nomad_raft_*`` lines on the main Prometheus scrape, and a
-``raft`` section in every SIMLOAD artifact (the ``restart-under-load``
-scenario banks the recovery timeline).
+``raft`` section in every simcluster artifact (the ``restart-800``
+scenario carries the recovery timeline).
 """
 
 from __future__ import annotations
@@ -425,7 +425,7 @@ def fsm_state_digest(store) -> str:
     """Canonical digest of a state store's replicated contents — the
     restart contract's yardstick: a cold restart's replayed FSM must
     reproduce the pre-kill digest exactly (tests/test_raft_observe.py
-    e2e; the restart-under-load scenario asserts the placement subset).
+    e2e; the restart-800 scenario asserts the placement subset).
     Reduces each table to sorted, order-independent rows of the fields
     replication is responsible for."""
     snap = store.snapshot()

@@ -45,8 +45,8 @@ Surfaces: ``/v1/agent/reads`` (JSON + ``?format=prometheus``), SDK
 (observer topic — excluded from the canonical determinism digest by
 construction, ``events.OBSERVER_TOPICS``), the debug bundle's ``reads``
 section, ``nomad_read_*`` lines on the main Prometheus scrape, and a
-``reads`` section in every SIMLOAD artifact (the ``read-storm``
-scenario banks the leader-only baseline).
+``reads`` section in every simcluster artifact (the ``read-storm``
+scenario's contrast arm is the leader-only baseline).
 """
 
 from __future__ import annotations

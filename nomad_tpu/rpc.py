@@ -212,8 +212,8 @@ class RPCServer:
         # the thread blocked in accept() — the open file description
         # (and with it the LISTEN port binding) survives until that
         # syscall returns, so a server restarting on the SAME port gets
-        # EADDRINUSE from its own ghost (the restart-under-load
-        # scenario's kill/rebind found this).
+        # EADDRINUSE from its own ghost (the restart scenario's
+        # kill/rebind found this).
         _hard_close(self._listener)
         # Close accepted connections too: parked long-poll streams on
         # peers must fail fast, not sleep out their timeouts.

@@ -2,7 +2,8 @@
 
 ROADMAP item 5's second half. PR 8 made submit→placed latency and the
 250ms SLO a live, burn-rate-monitored metric; nothing yet BOUNDED what
-hits the broker — burst-100k only worked because the injector was polite.
+hits the broker — a 100k-task burst only worked because the injector was
+polite.
 Borg's front door admits by quota and sheds rather than queues
 unboundedly, and Sparrow's framing is exactly task latency under overload
 (PAPERS.md): serving millions of users means rejecting fast and cheap so
@@ -45,7 +46,7 @@ the debug bundle's ``admission`` section.
 
 Default-permissive: with no caps and no rate configured the controller
 admits on a no-lock fast path, draws nothing, and publishes nothing —
-decision-invariance the banked steady-10k / burst-100k digests pin.
+decision-invariance the steady-10k digest pins (tests/test_simcluster.py).
 """
 
 from __future__ import annotations
@@ -101,8 +102,8 @@ def lane_for_job(job) -> str:
 class AdmissionConfig:
     """Front-door tunables. The defaults are PERMISSIVE (admit
     everything, no draws, no events): admission only bites where the
-    operator configured it — the decision-invariance contract the banked
-    pre-admission SIMLOAD digests pin."""
+    operator configured it — the decision-invariance contract the
+    steady-10k digest, unchanged since before admission, pins."""
 
     enabled: bool = True
     # Per-(client, lane) token bucket: rate in admissions/s, burst =

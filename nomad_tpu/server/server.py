@@ -139,7 +139,7 @@ class ServerConfig:
     # nomad_tpu/capacity.py): the read-only accountant behind
     # /v1/agent/capacity — fragmentation, per-lane usage, stranded-
     # capacity %. None = defaults (enabled; decision-invariant by
-    # construction, pinned by the churn-fragmentation contrast arm).
+    # construction, pinned by the churn-frag-200 contrast arm).
     capacity: Optional[Dict] = None
     # Raft & recovery observatory spec (RaftObserveConfig.parse mapping,
     # nomad_tpu/raft_observe.py): the read-only observer behind

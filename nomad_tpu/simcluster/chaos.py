@@ -8,9 +8,9 @@ mapping — phases x workload mix x fault storm x kill schedule — parsed
 and validated up front (the agent-config posture: an impossible spec
 fails at parse time with a named field, never mid-run), then compiled
 into an ordinary :class:`ScenarioSpec` the existing runner executes.
-Everything downstream (simload banking, determinism verification,
-bench_watch gating, the matrix sweep) works on chaos families for free
-because the compiler's output is just another registered scenario.
+Everything downstream (the artifact, determinism verification, the
+scenario-scoped SLO verdict) works on chaos families for free because
+the compiler's output is just another registered scenario.
 
 Spec grammar (see README "Chaos scenarios & scenario compiler")::
 
@@ -957,9 +957,9 @@ FAMILIES = (RACK_FAILURE, PARTITION_FLAP, FOLLOWER_CRASH_REJOIN)
 
 def register(raw: Dict) -> ScenarioSpec:
     """Parse + compile one chaos spec and register it as an ordinary
-    named scenario (simload/matrix/bench_watch all see it); scenario-
-    scoped SLO objectives land in slo.SCENARIO_OBJECTIVES so the
-    artifact's own slo_check and the CI gate judge the same promise."""
+    named scenario (``run_scenario`` finds it by name); scenario-
+    scoped SLO objectives land in slo.SCENARIO_OBJECTIVES, where the
+    artifact's own slo_check reads them."""
     cspec = ChaosSpec.parse(raw)
     spec = cspec.compile()
     SCENARIOS[cspec.name] = spec

@@ -28,11 +28,9 @@ every run emits one JSON artifact:
   waterfall (queue-wait vs service-time, each stage's share of the p95
   tail) stitched from the run's own trace spans + event stream, and the
   artifact's SLO verdicts (nomad_tpu.slo.evaluate_artifact). The layer
-  is read-only on decisions: the event digest pins that an r08 run with
-  attribution equals the banked pre-attribution r07 digest. The
-  tracing-overhead arm (tools/simload.py --overhead-arm) re-runs the
-  scenario with the layer off (tracer disabled, SLO monitor off) and
-  stamps the plan-p50 delta here.
+  is read-only on decisions: the canonical event digest of steady-10k
+  is the same with it as it was before it existed
+  (tests/test_simcluster.py pins the value).
 """
 
 from __future__ import annotations
@@ -55,7 +53,6 @@ from nomad_tpu.server.cluster import ClusterConfig, ClusterServer, wait_for_lead
 from nomad_tpu.simcluster.simnode import SimFleet, sim_node
 from nomad_tpu.simcluster.workload import (
     Action,
-    BatchBurstInjector,
     ExpressStreamInjector,
     FragmentationChurnInjector,
     LeaderRestartInjector,
@@ -94,26 +91,22 @@ class ScenarioSpec:
     # allocs, which concurrent placement does not pin).
     deterministic: bool = True
     # Optional CONTRAST arm: server-override deltas for a second run
-    # whose trimmed summary lands in the artifact's "contrast" section
-    # (the overdrive scenarios' admission-OFF arm — same offered load,
-    # front door disabled, documenting the unbounded-growth cliff).
+    # whose trimmed summary lands in the artifact's "contrast" section.
+    # Every contrast arm is an observatory-off arm: turning a read-only
+    # observer off must be decision-invariant, so the artifact says
+    # whether it reproduced the MAIN arm's canonical event digest
+    # (``contrast.digest_matches``).
     contrast_overrides: Optional[Dict] = None
-    # Whether the contrast arm must reproduce the MAIN arm's canonical
-    # event digest (the observatory-off arm: turning a read-only
-    # observer off must be decision-invariant). The admission-off
-    # contrast legitimately diverges (more work admitted) and leaves
-    # this False.
-    contrast_digest_invariant: bool = False
     # Durable raft state: the runner creates a temp data dir so every
     # entry journals and the leader can be killed and restarted from
-    # disk mid-run (the restart-under-load scenario). Cleaned up after.
+    # disk mid-run (the restart-800 scenario). Cleaned up after.
     durable_raft: bool = False
     # ClusterConfig overrides (snapshot_threshold, trailing_logs, ...):
     # the restart scenario compresses the compaction cadence so a cold
     # restart exercises snapshot restore AND log-tail replay.
     cluster_overrides: Dict = field(default_factory=dict)
     # Raft cluster size. 1 keeps the classic single-member runner path
-    # byte-for-byte (every banked digest rides it); >1 stands up a real
+    # byte-for-byte (the steady-10k digest rides it); >1 stands up a real
     # multi-member cell (shared peers table, one elected leader, the
     # fleet pointed at it) — the partition-flap / follower-crash-rejoin
     # chaos families' substrate.
@@ -161,7 +154,6 @@ def _spec_registry() -> Dict[str, ScenarioSpec]:
             # read-storm posture, applied to the process's own
             # profiler.
             contrast_overrides={"profile": {"enabled": False}},
-            contrast_digest_invariant=True,
             description="the north-star control-plane scale: 10k live "
                         "nodes, 24 service jobs x420 tasks over ~18s "
                         "(10,080 placements) under steady node-refresh "
@@ -175,7 +167,7 @@ def _spec_registry() -> Dict[str, ScenarioSpec]:
             server_overrides={
                 # 100k/10 = 10000s TTLs: beats never come due inside the
                 # run, so loaded-box beat starvation can't expire live
-                # nodes (the overdrive-100k posture at 10x the fleet).
+                # nodes.
                 "max_heartbeats_per_second": 10.0,
                 # The 100k-node registration tranche events + the
                 # steady-10k-shaped placement flow must fit the 20 Hz
@@ -190,16 +182,6 @@ def _spec_registry() -> Dict[str, ScenarioSpec]:
                         "every node; the solver panel's device-time-per-"
                         "placement is the meter the 'same warm-path cost "
                         "class as 10k' claim is judged against",
-        ),
-        "burst-100k": ScenarioSpec(
-            name="burst-100k", n_nodes=10_000,
-            injectors=lambda seed: [BatchBurstInjector(
-                seed, bursts=1, jobs_per_burst=8, tasks_per_job=12_500,
-            )],
-            quiesce_timeout=420.0, ack_cap=0,
-            description="one 100k-task burst (8 batch jobs x12.5k) at 10k "
-                        "nodes — the BASELINE config-3 ask through the "
-                        "whole pipeline",
         ),
         "overdrive-1k": ScenarioSpec(
             name="overdrive-1k", n_nodes=400,
@@ -225,61 +207,6 @@ def _spec_registry() -> Dict[str, ScenarioSpec]:
                         "admission rate lanes admit 2/client (burst), "
                         "the rest reject RATE_LIMITED typed",
         ),
-        "overdrive-100k": ScenarioSpec(
-            name="overdrive-100k", n_nodes=10_000,
-            injectors=lambda seed: [OverdriveInjector(
-                seed, clients=5, jobs_per_client=50, tasks_per_job=400,
-            )],
-            server_overrides={
-                # burst=1, glacial refill: exactly ONE admission per
-                # client lane, deterministically (refill over the whole
-                # blast << 1 token). The admitted spike (5 evals x 400
-                # tasks, the columnar device path) is sized to what the
-                # box drains inside the 250ms placed-latency SLO —
-                # that's the POINT of the front door: admitted work
-                # keeps its promise, the overload is turned away typed.
-                "admission": {"client_rate": 0.02, "client_burst": 1},
-                "eval_pending_cap": 128,
-                "plan_queue_cap": 64,
-                # The rejection storm's Admission events plus the
-                # admitted work's lifecycle must fit the watcher's poll
-                # stride without ring truncation.
-                "event_buffer_size": 16384,
-                # 10k/10 = 1000s TTLs: beats never come due inside the
-                # run, so loaded-box beat starvation can't expire live
-                # nodes (nondeterministic fan-out; the r09 bank's first
-                # attempt caught exactly that).
-                "max_heartbeats_per_second": 10.0,
-                "scheduler_workers": 8,
-                # Independent solves, no coalescer burst-hold: with only
-                # ~5 admitted evals in flight the hold window (waiting
-                # for announced batch members to stack) adds 50-150ms of
-                # run-to-run jitter to the tail — batching pays at
-                # hundreds of evals (the contrast arm), not five.
-                "eval_batch_size": 1,
-            },
-            # The admission-OFF arm: identical offered load, front door
-            # disabled and queues unbounded — the documented cliff.
-            contrast_overrides={
-                "admission": {"enabled": False},
-                "eval_pending_cap": 0,
-                "plan_queue_cap": 0,
-                "event_buffer_size": 16384,
-                "max_heartbeats_per_second": 10.0,
-                "scheduler_workers": 8,
-                "eval_batch_size": 4,
-            },
-            quiesce_timeout=600.0, ack_cap=0,
-            description="the impolite front-door proof: 5 clients blast "
-                        "250 batch jobs (100k tasks offered) at a 10k-"
-                        "node cell with no self-throttling; admission ON "
-                        "admits 1/client (5 jobs, 2000 tasks) and "
-                        "rejects the rest RATE_LIMITED typed, keeping "
-                        "admitted p95 submit-to-placed under the 250ms "
-                        "SLO with every queue bounded; the contrast arm "
-                        "re-runs with admission OFF and documents the "
-                        "unbounded-queue latency cliff",
-        ),
         "express-1k": ScenarioSpec(
             name="express-1k", n_nodes=400,
             injectors=lambda seed: [
@@ -303,45 +230,6 @@ def _spec_registry() -> Dict[str, ScenarioSpec]:
                         "stream through the leader-local lane "
                         "(sub-ms in-line placement, async commit)",
         ),
-        "express-mix": ScenarioSpec(
-            name="express-mix", n_nodes=10_000,
-            injectors=lambda seed: [
-                # The steady-10k service background, verbatim: the
-                # express lane must hit its latency floor UNDER the
-                # north-star load, not on an idle cell.
-                SteadyServiceInjector(
-                    seed, jobs=24, tasks_per_job=420, over=18.0,
-                ),
-                NodeRefreshInjector(
-                    seed, count=12, every=0.9, start=0.7, until=17.5,
-                ),
-                # The express probe: ~300 short express tasks riding the
-                # same window (one tiny express batch job each, in-line
-                # placement + async commit per submission).
-                ExpressStreamInjector(
-                    seed, tasks=300, every=0.05, start=2.0, until=17.0,
-                ),
-            ],
-            server_overrides={
-                "express": {"enabled": True},
-                # The express stream adds ~5 events per submission on
-                # top of the steady-10k flow; headroom so the 20 Hz
-                # watcher can never fall off the ring (truncation would
-                # void the digest contract).
-                "event_buffer_size": 8192,
-            },
-            # ack_cap=0: the post-quiesce harness acks would land as a
-            # multi-second submit_to_running observation and fail the
-            # first-round ABSOLUTE slo gate on plumbing, not placement
-            # (the overdrive banks made the same cut).
-            quiesce_timeout=300.0, ack_cap=0,
-            description="the latency-floor proof: steady-10k's service "
-                        "load + node-refresh writes, with a ~300-task "
-                        "express stream placed in-line by the leader-"
-                        "local lane under leased reservations — "
-                        "express p50 submit→placed < 1ms while the "
-                        "service lane keeps its 250ms SLO",
-        ),
         "churn-frag-200": ScenarioSpec(
             name="churn-frag-200", n_nodes=200,
             injectors=lambda seed: [FragmentationChurnInjector(
@@ -363,64 +251,12 @@ def _spec_registry() -> Dict[str, ScenarioSpec]:
                 "event_buffer_size": 16384,
                 "max_heartbeats_per_second": 2.0,
             },
-            contrast_digest_invariant=True,
             quiesce_timeout=120.0, ack_cap=0, warmup_count=100,
             description="tier-1 observatory smoke: 200 nodes, 6 fill "
                         "jobs x400 small tasks, half deregistered, a "
                         "chunky probe wave — capacity/solver "
                         "trajectories banked, observatory-off contrast "
                         "arm digest-equal",
-        ),
-        "churn-fragmentation": ScenarioSpec(
-            name="churn-fragmentation", n_nodes=600,
-            injectors=lambda seed: [FragmentationChurnInjector(
-                seed, fill_jobs=18, tasks_per_job=1000,
-                dereg_fraction=0.5, probe_jobs=3, probe_tasks=150,
-                fill_over=6.0, dereg_start=8.0, dereg_over=4.0,
-                probe_start=14.0, probe_over=3.0,
-                # The probe shape fits a fully-filled node's free
-                # 1000-cpu headroom too: whether a probe eval's snapshot
-                # lands before or after a racing stop plan, every probe
-                # places — the digest contract must not depend on that
-                # race. Stranding is measured against the REFERENCE
-                # shapes, not the probe.
-                probe_cpu=800, probe_memory_mb=768,
-            )],
-            server_overrides={
-                # Fresh trajectory samples: the accountant rolls every
-                # 250ms and stamps a Capacity event snapshot every 5s.
-                "capacity": {"poll_interval": 0.25,
-                             "events_interval": 5.0},
-                # A deregistration that falls through to object rows
-                # publishes one AllocUpserted per stopped row (a block
-                # stopped whole is one AllocStopped); the 20 Hz watcher
-                # must never fall off the ring (truncation voids the
-                # digest contract).
-                "event_buffer_size": 32768,
-                "max_heartbeats_per_second": 2.0,
-            },
-            # The observatory-OFF arm: identical workload, capacity
-            # accountant disabled. Its canonical digest must EQUAL the
-            # main arm's — the proof the observatory reads cluster
-            # state without perturbing one decision (Omega's
-            # shared-state observer posture).
-            contrast_overrides={
-                "capacity": {"enabled": False},
-                "event_buffer_size": 32768,
-                "max_heartbeats_per_second": 2.0,
-            },
-            contrast_digest_invariant=True,
-            quiesce_timeout=300.0, ack_cap=0,
-            description="the fragmentation baseline the defrag arc is "
-                        "judged against: 18 batch jobs x1000 small "
-                        "tasks pack a 600-node cell to ~75% cpu, a "
-                        "seeded half deregisters (density shreds, "
-                        "capacity strands), then 3 chunky service "
-                        "probe jobs land in the wreckage; the "
-                        "capacity observatory banks stranded-% and "
-                        "padding-waste trajectories, and an "
-                        "observatory-off contrast arm proves digest "
-                        "equality (decision invariance)",
         ),
         "read-storm": ScenarioSpec(
             name="read-storm", n_nodes=10_000,
@@ -467,19 +303,17 @@ def _spec_registry() -> Dict[str, ScenarioSpec]:
             },
             # The leader-only arm: identical write load AND identical
             # read fleet, read lanes and observatory disabled — every
-            # read lands on the leader's front end (the r16 posture,
-            # the pile-up the follower plane exists to relieve). Its
+            # read lands on the leader's front end (the posture before
+            # PR 19, the pile-up the follower plane exists to relieve). Its
             # canonical digest must EQUAL the main arm's — reads never
             # touch the decision path, however they are routed.
             contrast_overrides={
                 "reads": {"enabled": False},
                 "read_path": {"enabled": False},
             },
-            contrast_digest_invariant=True,
             # ack_cap=0: the post-quiesce harness acks would land as a
             # multi-second submit_to_running observation and fail the
-            # first-round ABSOLUTE slo gate on plumbing, not placement
-            # (the express-mix bank made the same cut).
+            # artifact's slo_check on plumbing, not placement.
             quiesce_timeout=300.0, ack_cap=0,
             description="the follower-read-plane proof: the steady-10k "
                         "write load (24 service jobs x420 tasks over "
@@ -531,7 +365,6 @@ def _spec_registry() -> Dict[str, ScenarioSpec]:
                 "event_buffer_size": 8192,
                 "max_heartbeats_per_second": 2.0,
             },
-            contrast_digest_invariant=True,
             quiesce_timeout=120.0, ack_cap=0, warmup_count=100,
             description="tier-1 read-path smoke: 800 nodes x 3-member "
                         "cell, 6 service jobs x120 tasks under a small "
@@ -540,60 +373,6 @@ def _spec_registry() -> Dict[str, ScenarioSpec]:
                         "fronts on the stale/linearizable lanes; reads "
                         "+ lanes sections banked, leader-only contrast "
                         "arm digest-equal",
-        ),
-        "restart-under-load": ScenarioSpec(
-            name="restart-under-load", n_nodes=10_000,
-            injectors=lambda seed: [
-                # The steady-10k service workload, verbatim: the restart
-                # must be survived UNDER the north-star load, not on an
-                # idle cell.
-                SteadyServiceInjector(
-                    seed, jobs=24, tasks_per_job=420, over=18.0,
-                ),
-                # The cut: mid-window, while placements are in flight.
-                # Evals caught on the wrong side of it redeliver from
-                # durable state after the restart — the canonical
-                # per-key lifecycles (and therefore the digest) must not
-                # depend on which side of the kill a plan landed.
-                LeaderRestartInjector(seed, at=9.0),
-            ],
-            durable_raft=True,
-            cluster_overrides={
-                # Compressed compaction so the restart exercises BOTH
-                # halves of recovery: snapshot restore (the 10k-node
-                # registration prefix compacts away) and log-tail replay
-                # (the short trailing tail plus everything since the
-                # last compaction re-applies through the FSM).
-                "snapshot_threshold": 64,
-                "trailing_logs": 16,
-            },
-            server_overrides={
-                # The restart replays the committed prefix into a FRESH
-                # event ring before the runner's watcher pages it;
-                # headroom keeps the (floor-filtered) replay burst from
-                # truncating the stream.
-                "event_buffer_size": 16384,
-                # 10k/10 = 1000s TTLs: no heartbeat traffic inside the
-                # window, so fleet beats can't race the downtime and
-                # expiry fan-out can't touch the digest (the
-                # overdrive-100k posture).
-                "max_heartbeats_per_second": 10.0,
-            },
-            quiesce_timeout=600.0, ack_cap=0,
-            description="ROADMAP item 2's kill-and-recover proof, "
-                        "measurement half: the steady-10k service "
-                        "workload (24 jobs x420 tasks over ~18s) at 10k "
-                        "nodes with a DURABLE raft log (journal + "
-                        "compressed snapshot cadence); at t=9s the "
-                        "leader is killed outright and restarted from "
-                        "its data dir on the same port — every pre-kill "
-                        "placement must survive the replay, in-flight "
-                        "evals redeliver and finish, the canonical "
-                        "event digest stays seed-deterministic across "
-                        "the cut (events dedup by raft index), and the "
-                        "artifact banks the recovery timeline "
-                        "(snapshot-restore wall, entries replayed, "
-                        "replay rate, time-to-leader/serving)",
         ),
         "restart-800": ScenarioSpec(
             name="restart-800", n_nodes=800,
@@ -656,7 +435,7 @@ def canonical_events(events) -> Dict:
     periodic snapshots) are excluded BY CONSTRUCTION: they publish on a
     wall-clock cadence, so how many land in a run is box-speed noise,
     and an observer being on vs off must be digest-invariant — that
-    exclusion is what lets the churn-fragmentation contrast arm prove
+    exclusion is what lets the churn-frag-200 contrast arm prove
     the observatory decision-invariant.
 
     The "Fault" topic (faults.py's FaultInjected broadcast) is excluded
@@ -744,17 +523,10 @@ class _MemberHttpShim:
 
 class ScenarioRunner:
     def __init__(self, spec: ScenarioSpec, seed: int = 42,
-                 logger: Optional[logging.Logger] = None,
-                 n_nodes: Optional[int] = None,
-                 attribution_layer: bool = True):
+                 logger: Optional[logging.Logger] = None):
         self.spec = spec
         self.seed = int(seed)
-        self.n_nodes = int(n_nodes or spec.n_nodes)
-        # The tracing-overhead arm: False runs the identical scenario with
-        # the whole attribution layer off — tracer disabled (no spans),
-        # SLO monitor unconstructed — so the plan-p50 delta IS the layer's
-        # hot-path cost. Decisions must not depend on it (digest-pinned).
-        self.attribution_layer = bool(attribution_layer)
+        self.n_nodes = int(spec.n_nodes)
         self.logger = logger or logging.getLogger("nomad_tpu.simcluster")
         self._events: List = []
         self._events_lock = threading.Lock()
@@ -775,13 +547,13 @@ class ScenarioRunner:
         self._offered = 0
         self._rejected: Dict[str, int] = {}
         # Capacity-observatory + solver-panel trajectories (the
-        # churn-fragmentation artifact's banked time series): sampled at
+        # churn-frag-200 artifact's time series): sampled at
         # 2 Hz by the depth sampler when the observatory is on.
         self._capacity_samples: List[Dict] = []
         self._panel_samples: List[Dict] = []
         self._t_measure0 = 0.0
         self._panel0: Optional[Dict] = None
-        # Restart bookkeeping (restart-under-load): the event watcher's
+        # Restart bookkeeping (restart-800): the event watcher's
         # raft-index floor (post-restart, replayed events at or below it
         # are dupes of already-collected ones and are dropped), carried
         # per-server counter baselines (a fresh server's pipeline/
@@ -1230,8 +1002,8 @@ class ScenarioRunner:
         # payload's bound (every 5th poll rides the linearizable lane
         # instead, pinning read-index freshness), SSE tails ride each
         # follower's own event ring. Lanes off (the leader-only
-        # contrast arm) keeps the r16 posture byte-for-byte: everything
-        # hammers the leader's front end, plain GETs.
+        # contrast arm) keeps the posture before PR 19 byte-for-byte:
+        # everything hammers the leader's front end, plain GETs.
         lanes_on = bool(self._srv.config.read_path_config.enabled)
         if lanes_on and len(self._members) > 1 and not self._follower_https:
             for m in self._followers():
@@ -1571,8 +1343,6 @@ class ScenarioRunner:
             seed=self.seed,
         )
         cfg_kwargs.update(spec.server_overrides)
-        if not self.attribution_layer:
-            cfg_kwargs["slo_objectives"] = {}
         self._cfg_kwargs = cfg_kwargs
         # Lock-contention attribution for the run: install the timing
         # watchdog (telemetry.LockWatchdog with the statically proven
@@ -1580,10 +1350,10 @@ class ScenarioRunner:
         # knob) so the banked profile section carries the ranked
         # contention table. Timing-only: decisions cannot observe it,
         # so the canonical digest is unaffected. Skipped in the
-        # profiler-off contrast arm and the attribution-off overhead arm.
+        # profiler-off contrast arm.
         self._watchdog = None
         prof_enabled = (cfg_kwargs.get("profile") or {}).get("enabled", True)
-        if self.attribution_layer and prof_enabled:
+        if prof_enabled:
             try:
                 from tools.nomadlint import lockorder
                 from tools.nomadlint.project import Project
@@ -1604,12 +1374,6 @@ class ScenarioRunner:
         srv = self._srv = members[0]
         fleet = SimFleet(srv.rpc_addr, logger=self.logger)
         threads: List[threading.Thread] = []
-        from nomad_tpu import trace as trace_mod
-
-        tracer = trace_mod.get_tracer()
-        tracing_was = tracer.enabled
-        if not self.attribution_layer:
-            tracer.enabled = False
         t_run0 = time.perf_counter()
         try:
             for m in members:
@@ -1923,7 +1687,6 @@ class ScenarioRunner:
         finally:
             self._stop.set()
             self._stop_watcher()
-            tracer.enabled = tracing_was
             if self._watchdog is not None:
                 try:
                     self._watchdog.uninstall()
@@ -2112,7 +1875,7 @@ class ScenarioRunner:
                 # Over the MEASURED window (hb0 is sampled at its start):
                 # dividing by the full run wall — which includes fleet
                 # bring-up and the warmup compile — would understate the
-                # rate several-fold in the banked artifacts.
+                # rate several-fold.
                 "renewals_per_sec_measured": round(
                     renewals / max(measured, 1e-9), 2),
                 # Transient: Σ 1/(beat_fraction·ttl) over CURRENT grants.
@@ -2213,36 +1976,31 @@ class ScenarioRunner:
         artifact["reads"] = self._reads_section(srv)
         artifact["profile"] = self._profile_section(srv)
         artifact["solver_panel"] = self._solver_panel_section()
-        if self.attribution_layer:
-            from nomad_tpu import lifecycle, slo
+        from nomad_tpu import lifecycle, slo
 
-            timelines = lifecycle.stitch(events)
-            # Express timelines are a different latency regime by
-            # design (sub-ms in-line placement): they get their own
-            # quantile block below, and mixing them into the service-
-            # path waterfall would dilute both stories.
-            slow_tls = [t for t in timelines.values()
-                        if t.triggered_by != "express"]
-            att = lifecycle.attribution(slow_tls)
-            # Scenario-scoped objectives (slo.SCENARIO_OBJECTIVES): the
-            # artifact's own verdict and the bench_watch gate consult
-            # the SAME table, so they can never disagree about which
-            # promise a family is judged against.
-            objectives = slo.SCENARIO_OBJECTIVES.get(self.spec.name)
-            if express_ms:
-                att["express_placed_ms"] = _quantiles(
-                    [ms / 1000.0 for ms in express_ms])
-                objectives = {**(objectives or slo.DEFAULT_OBJECTIVES),
-                              **slo.EXPRESS_OBJECTIVES}
-            att["slo_check"] = slo.evaluate_artifact(att, objectives)
-            artifact["latency_attribution"] = att
-            artifact["slo"] = (
-                srv.slo_monitor.snapshot()
-                if srv.slo_monitor is not None else None
-            )
-        else:
-            artifact["latency_attribution"] = None
-            artifact["slo"] = None
+        timelines = lifecycle.stitch(events)
+        # Express timelines are a different latency regime by
+        # design (sub-ms in-line placement): they get their own
+        # quantile block below, and mixing them into the service-
+        # path waterfall would dilute both stories.
+        slow_tls = [t for t in timelines.values()
+                    if t.triggered_by != "express"]
+        att = lifecycle.attribution(slow_tls)
+        # Scenario-scoped objectives (slo.SCENARIO_OBJECTIVES): the
+        # promise this family is judged against, where it is not the
+        # default cell SLO.
+        objectives = slo.SCENARIO_OBJECTIVES.get(self.spec.name)
+        if express_ms:
+            att["express_placed_ms"] = _quantiles(
+                [ms / 1000.0 for ms in express_ms])
+            objectives = {**(objectives or slo.DEFAULT_OBJECTIVES),
+                          **slo.EXPRESS_OBJECTIVES}
+        att["slo_check"] = slo.evaluate_artifact(att, objectives)
+        artifact["latency_attribution"] = att
+        artifact["slo"] = (
+            srv.slo_monitor.snapshot()
+            if srv.slo_monitor is not None else None
+        )
         if self.spec.faults_spec is not None:
             artifact["faults"] = faults.get_registry().snapshot()
         if self.spec.chaos_check is not None:
@@ -2555,17 +2313,13 @@ def _backend_name() -> str:
 
 
 def run_scenario(name: str, seed: int = 42, out_path: Optional[str] = None,
-                 n_nodes: Optional[int] = None,
                  logger: Optional[logging.Logger] = None,
-                 attribution_layer: bool = True,
                  contrast: bool = True) -> Dict:
     """Run one named scenario; optionally write the JSON artifact.
-    ``attribution_layer=False`` is the tracing-overhead arm: same
-    scenario, tracer + SLO monitor off. When the spec declares a
-    contrast arm (overdrive's admission-OFF run), it runs after the main
-    arm and a trimmed summary lands in ``artifact["contrast"]``;
-    ``contrast=False`` skips it (determinism re-verification compares
-    main arms only)."""
+    When the spec declares a contrast arm (an observatory-OFF run), it
+    runs after the main arm and a trimmed summary lands in
+    ``artifact["contrast"]``; ``contrast=False`` skips it (determinism
+    re-verification compares main arms only)."""
     import dataclasses
 
     spec = SCENARIOS.get(name)
@@ -2573,21 +2327,15 @@ def run_scenario(name: str, seed: int = 42, out_path: Optional[str] = None,
         raise KeyError(
             f"unknown scenario {name!r} (have: {sorted(SCENARIOS)})"
         )
-    artifact = ScenarioRunner(
-        spec, seed=seed, n_nodes=n_nodes, logger=logger,
-        attribution_layer=attribution_layer,
-    ).run()
+    artifact = ScenarioRunner(spec, seed=seed, logger=logger).run()
     if contrast and spec.contrast_overrides is not None:
         overrides = dict(spec.server_overrides)
         overrides.update(spec.contrast_overrides)
         contrast_spec = dataclasses.replace(
             spec, server_overrides=overrides, contrast_overrides=None,
         )
-        full = ScenarioRunner(
-            contrast_spec, seed=seed, n_nodes=n_nodes, logger=logger,
-            attribution_layer=attribution_layer,
-        ).run()
-        att = full.get("latency_attribution") or {}
+        full = ScenarioRunner(contrast_spec, seed=seed, logger=logger).run()
+        att = full["latency_attribution"]
         artifact["contrast"] = {
             "server_overrides": overrides,
             "placements": full["placements"],
@@ -2599,19 +2347,18 @@ def run_scenario(name: str, seed: int = 42, out_path: Optional[str] = None,
             "events": {"observed": full["events"]["observed"],
                        "truncated": full["events"]["truncated"]},
         }
-        if spec.contrast_digest_invariant:
-            # The observatory-off arm's decision-invariance verdict: an
-            # observer being on vs off must leave every per-entity
-            # lifecycle identical. This is the artifact's headline
-            # proof, not a side note.
-            artifact["contrast"]["events"]["digest"] = \
-                full["events"]["digest"]
-            artifact["contrast"]["digest_matches"] = (
-                full["events"]["digest"] == artifact["events"]["digest"]
-            )
-            artifact["contrast"]["capacity"] = full.get("capacity")
-            artifact["contrast"]["reads"] = full.get("reads")
-            artifact["contrast"]["profile"] = full.get("profile")
+        # The observatory-off arm's decision-invariance verdict: an
+        # observer being on vs off must leave every per-entity
+        # lifecycle identical. This is the artifact's headline
+        # proof, not a side note.
+        artifact["contrast"]["events"]["digest"] = \
+            full["events"]["digest"]
+        artifact["contrast"]["digest_matches"] = (
+            full["events"]["digest"] == artifact["events"]["digest"]
+        )
+        artifact["contrast"]["capacity"] = full.get("capacity")
+        artifact["contrast"]["reads"] = full.get("reads")
+        artifact["contrast"]["profile"] = full.get("profile")
         if ((spec.contrast_overrides.get("profile") or {})
                 .get("enabled") is False):
             # Profiler-overhead verdict: the sampler walking

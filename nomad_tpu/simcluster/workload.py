@@ -301,7 +301,7 @@ class OverdriveInjector(Injector):
 
 class ExpressStreamInjector(Injector):
     """A stream of express-eligible short tasks riding alongside a
-    service background (the express-mix scenario's latency probe): one
+    service background (the express-1k scenario's latency probe): one
     tiny express-flagged batch job every ``every`` seconds with jittered
     gaps, from ``start`` until ``until``. Each submission exercises the
     whole express path — admission's express lane, the leader-local
@@ -370,7 +370,7 @@ class FragmentationChurnInjector(Injector):
     workload whose placement quality the future defragmenter is
     supposed to rescue. The capacity observatory's stranded-% and the
     solver panel's padding-waste trajectories across these phases ARE
-    the banked artifact this scenario exists to produce.
+    the artifact this scenario exists to produce.
 
     Fully seed-determined: job ids, shapes, the deregistration subset
     and all pacing derive from the injector's name-salted stream, so
@@ -445,8 +445,7 @@ class FragmentationChurnInjector(Injector):
 class LeaderRestartInjector(Injector):
     """Kill-and-recover: at ``at`` seconds the runner shuts the leader
     down mid-load and restarts it from its durable raft state (same
-    data dir, same RPC port) — ROADMAP item 2's cold-restart-under-load
-    ask. The runner handles the mechanics (event-stream dedup by raft
+    data dir, same RPC port): a cold restart under load. The runner handles the mechanics (event-stream dedup by raft
     index across the restart, fleet reconnection, recovery-timeline
     capture); this injector only schedules the cut. Requires a spec
     with ``durable_raft`` — an in-memory leader has nothing to recover

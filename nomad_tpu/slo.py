@@ -59,41 +59,33 @@ EXPRESS_OBJECTIVES: Dict[str, float] = {
     "express_placed_p50_ms": 1.0,
 }
 
-# Scenario-scoped objectives: SIMLOAD families whose CONTRACT is not the
-# default cell SLO. The gate (tools/bench_watch.py) and the scenario
-# runner's in-artifact slo_check both consult this table by scenario
-# name, so a banked artifact and its CI verdict can never disagree about
-# which promise was being judged.
+# Scenario-scoped objectives: simcluster families whose CONTRACT is not
+# the default cell SLO. The scenario runner's in-artifact slo_check
+# consults this table by scenario name.
 #
-# - churn-fragmentation (and its tier-1 smoke): the scenario's claim is
-#   the capacity/stranding trajectory, and its probe wave INTENTIONALLY
-#   races a ~9000-alloc deregistration stop storm — the p95 tail is the
-#   storm, not placement health. The scenario-scoped bound (1s) catches
-#   a real regression (the r13 bank's p95 is ~455ms) without pretending
-#   the run ever promised the 250ms steady-state SLO.
-# - restart-under-load (and its smoke): evals caught mid-flight by the
-#   leader kill wait out the downtime (~1-3s: re-election + snapshot
-#   restore + log replay) and THEN place — survival and recovery speed
-#   are the contract (the recovery gate judges those), so the placed
-#   bound absorbs the declared downtime.
+# - churn-frag-200: the scenario's claim is the capacity/stranding
+#   trajectory, and its probe wave INTENTIONALLY races a deregistration
+#   stop storm — the p95 tail is the storm, not placement health. The
+#   scenario-scoped bound (1s) catches a real regression without
+#   pretending the run ever promised the 250ms steady-state SLO.
+# - restart-800: evals caught mid-flight by the leader kill wait out
+#   the downtime (~1-3s: re-election + snapshot restore + log replay)
+#   and THEN place — survival and recovery speed are the contract, so
+#   the placed bound absorbs the declared downtime.
 # - read-storm (and its smoke): the leader's HTTP front end serves an
 #   impolite read fleet BY DESIGN while the steady-10k write load
 #   places — the GIL contention between serving and planning is the
-#   number the artifact banks (plan p50 under read pressure), and the
-#   read lanes themselves are judged by bench_watch's read gate. The
-#   1s placed bound catches a real write-path regression without
+#   number the artifact carries (plan p50 under read pressure), and
+#   the read lanes themselves are judged by evaluate_read_lanes. The
+#   placed bound catches a real write-path regression without
 #   pretending the run ever promised the uncontended 250ms SLO.
 SCENARIO_OBJECTIVES: Dict[str, Dict[str, float]] = {
-    "churn-fragmentation": {**DEFAULT_OBJECTIVES,
-                            "submit_to_placed_p95_ms": 1000.0},
     "churn-frag-200": {**DEFAULT_OBJECTIVES,
                        "submit_to_placed_p95_ms": 1000.0},
-    "restart-under-load": {**DEFAULT_OBJECTIVES,
-                           "submit_to_placed_p95_ms": 15000.0},
     "restart-800": {**DEFAULT_OBJECTIVES,
                     "submit_to_placed_p95_ms": 15000.0},
     # The read-storm families run a REPLICATED 3-member cell since the
-    # follower read plane (r19): every plan is one raft entry fsynced
+    # follower read plane (PR 19): every plan is one raft entry fsynced
     # and replicated on the 100ms heartbeat cadence, under election
     # timeouts widened to 2.5-5s for digest determinism — placement
     # p95 is replication-dominated (~3s observed), not scheduler-bound.
@@ -106,13 +98,13 @@ SCENARIO_OBJECTIVES: Dict[str, Dict[str, float]] = {
                        "submit_to_placed_p95_ms": 5000.0},
     # Chaos families (nomad_tpu/simcluster/chaos.py; the specs declare
     # the SAME bounds and register() re-merges them — declared here too
-    # so a process that never imports the chaos compiler, like the
-    # bench_watch slo-gate scan, judges the banked artifacts against
-    # the declared bounds, and test_chaos.py pins the two in sync):
+    # so a process that never imports the chaos compiler judges an
+    # artifact against the declared bounds, and test_chaos.py pins the
+    # two in sync):
     # - rack-failure drains a 256-job full-node fill through ONE
     #   scheduler worker (determinism) — the fill's serial queue
-    #   backlog IS the p95, and the chaos gate separately judges the
-    #   expiry->re-placement quantiles the family actually promises.
+    #   backlog IS the p95; the family's own promise is the
+    #   expiry->re-placement quantiles in the artifact's chaos section.
     # - partition-flap drops the leader's append stream half of every
     #   flap period BY DESIGN — commit stalls during the storm are the
     #   scenario's point; the bound catches a real scheduling
@@ -131,10 +123,9 @@ SCENARIO_OBJECTIVES: Dict[str, Dict[str, float]] = {
 # Read-lane objectives (ROADMAP item 2's follower read plane): not
 # latency-percentile objectives — contract checks on the consistency
 # lanes a read-carrying artifact banks in its ``reads.lanes`` section.
-# Judged offline by evaluate_read_lanes (the bench_watch read-lane
-# gate), never by the live SLOMonitor: the lanes' promises (bound
-# honored, share served by followers, zero linearizable violations) are
-# per-run invariants, not rolling budgets.
+# Judged offline by evaluate_read_lanes, never by the live SLOMonitor:
+# the lanes' promises (bound honored, share served by followers, zero
+# linearizable violations) are per-run invariants, not rolling budgets.
 READ_LANE_OBJECTIVES: Dict[str, float] = {
     # Followers must absorb at least this share of lane-entered reads
     # when the plane is on and the cell has followers to serve.
@@ -251,7 +242,7 @@ class SLOMonitor(threading.Thread):
 
     Deliberately a CONSUMER of the bounded event ring rather than a
     hot-path hook: the control plane publishes exactly what it published
-    before (SIMLOAD event digests pin this), and a wedged monitor can
+    before (the simcluster digests pin this), and a wedged monitor can
     never block an apply. The cost of that posture is honesty about
     loss: if the monitor ever falls further behind than the ring, the
     gap is counted (``truncated_gaps``), not silently absorbed."""
@@ -475,9 +466,8 @@ class SLOMonitor(threading.Thread):
 def evaluate_artifact(attribution: Dict[str, Any],
                       objectives: Optional[Dict[str, float]] = None,
                       ) -> List[Dict[str, Any]]:
-    """Offline check of a SIMLOAD ``latency_attribution`` section against
-    objectives (the bench_watch / CI gate path): for each objective,
-    compare the artifact's observed percentile of the metric against the
+    """Offline check of a simcluster artifact's ``latency_attribution``
+    section against objectives: for each objective, compare the artifact's observed percentile of the metric against the
     threshold. Artifact percentiles come at fixed cuts (p50/p95/p99) —
     an objective at another percentile is checked against the next
     STRICTER recorded cut (conservative, never lenient)."""
@@ -504,9 +494,8 @@ def evaluate_artifact(attribution: Dict[str, Any],
 def evaluate_read_lanes(artifact: Dict[str, Any],
                         objectives: Optional[Dict[str, float]] = None,
                         ) -> List[Dict[str, Any]]:
-    """Offline check of a SIMLOAD artifact's ``reads.lanes`` section
-    against the read-lane objectives (the bench_watch read-lane gate
-    path). Empty when the artifact never ran the read plane (no lanes
+    """Offline check of a simcluster artifact's ``reads.lanes`` section
+    against the read-lane objectives. Empty when the artifact never ran the read plane (no lanes
     section, or ``enabled: false`` — the leader-only contrast arm):
     the lane contract can only be judged where lanes were served."""
     lanes = ((artifact.get("reads") or {}).get("lanes")) or {}

@@ -58,9 +58,9 @@ def _flat(key: Key) -> str:
 
 
 # Bounded reservoir per sample series (Vitter's algorithm R): big enough
-# that p99 over a bench run is meaningful, small enough that a sink
-# retaining hundreds of series stays cheap. Mean/max alone cannot answer
-# "is the agent's own p50 consistent with bench.py's claim?" — quantiles
+# that p99 over a run is meaningful, small enough that a sink retaining
+# hundreds of series stays cheap. Mean/max alone cannot answer "is the
+# agent's own p50 consistent with what a client measured?" — quantiles
 # need (a sketch of) the distribution.
 RESERVOIR_SIZE = 256
 
@@ -580,8 +580,8 @@ def prometheus_text(inmem: InmemSink) -> str:
         name = _prom_name(key) + "_ms"
         s = samples[key]
         # Summary with quantile labels (the Prometheus summary type's
-        # native shape): reservoir-backed, so bench.py's p50 claims are
-        # cross-checkable against the agent's own exposition.
+        # native shape): reservoir-backed, so a client's measured p50
+        # is cross-checkable against the agent's own exposition.
         lines.append(f"# TYPE {name} summary")
         for qname, q in QUANTILES:
             lines.append(

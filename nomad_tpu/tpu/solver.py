@@ -128,7 +128,7 @@ class SolverPanel:
 
     Pure observer: counters recorded AFTER a solve's readback, on the
     worker's own thread, under a private lock no decision path takes.
-    Decision-invariance is pinned by the churn-fragmentation scenario's
+    Decision-invariance is pinned by the churn-frag-200 scenario's
     observatory-off digest-equality arm.
 
     Books (process-wide, like PIPELINE_TOTALS):
@@ -446,8 +446,8 @@ def _solve_stages() -> "trace.StageTimer":
 
 def _emit_solver_trace(st, start: float, count: int) -> None:
     """Publish one solve's stage cuts: child spans under the eval's active
-    span (solver.staging/transfer/execute/readback — the SAME cuts
-    bench.py's breakdown publishes, through the same StageTimer), plus
+    span (solver.staging/transfer/execute/readback and the cuts nested
+    in them, as the StageTimer recorded them), plus
     the aggregate device-solve wall as a telemetry sample. Per-stage
     aggregates live in the spans, not the sink — four extra sink writes
     per solve measurably eat the <5% tracing-overhead budget."""

@@ -476,8 +476,8 @@ def use_span(span):
 
 
 # ---------------------------------------------------------------------------
-# Stage timing — the ONE stage-cut path shared by the production solver and
-# bench.py's device-time breakdown (no second parallel timer).
+# Stage timing — the ONE stage-cut path of a solve: the solver's spans and
+# the panel's staging books both come from it (no second parallel timer).
 # ---------------------------------------------------------------------------
 
 
@@ -549,8 +549,8 @@ _NULL_CTX = _NullCtx()
 
 
 class StageTimer:
-    """Named, ordered stage cuts (staging / transfer / execute / readback —
-    the same cuts bench.py's breakdown publishes), with the thread's CPU
+    """Named, ordered stage cuts (staging / transfer / execute / readback,
+    published as the ``solver.*`` spans), with the thread's CPU
     seconds beside the wall where asked for (``cpu=True``). A cut opened
     inside another (by name ``staging.mask`` inside ``staging``) is
     emitted as its child span. One timer belongs to one solve on one

@@ -1,9 +1,9 @@
 """Test configuration.
 
 Tests run on a virtual 8-device CPU mesh so multi-chip sharding logic is
-exercised without TPU hardware (chip_smoke.py is what runs on the real
-chip, through the chip tool). These env vars must be set before jax is
-imported anywhere.
+exercised without TPU hardware (benchmark/run.py is what runs on the
+real chip, through the chip tool). These env vars must be set before jax
+is imported anywhere.
 """
 
 import os

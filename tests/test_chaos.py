@@ -512,10 +512,9 @@ def test_shipped_families_registered():
         assert SCENARIOS[name].chaos_check is not None
         assert name in slo.SCENARIO_OBJECTIVES
         # slo.py declares the same bounds statically (so a process that
-        # never imports the chaos compiler — the bench_watch slo-gate
-        # scan — judges banked chaos artifacts identically). register()
-        # merges the spec's bounds over DEFAULT_OBJECTIVES; the two
-        # sources must agree key-for-key.
+        # never imports the chaos compiler judges a chaos artifact
+        # identically). register() merges the spec's bounds over
+        # DEFAULT_OBJECTIVES; the two sources must agree key-for-key.
         assert slo.SCENARIO_OBJECTIVES[name] == {
             **slo.DEFAULT_OBJECTIVES, **raw.get("objectives", {})}
     assert SCENARIOS["partition-flap"].cluster_members == 3
@@ -528,41 +527,3 @@ def test_shipped_families_registered():
     assert len(kills) == 1
     assert kills[0].payload["node_ids"] == [
         f"sim-{i:05d}" for i in range(24, 32)]
-
-
-# ---------------------------------------------------------------------------
-# bench_watch chaos gate
-# ---------------------------------------------------------------------------
-
-def _chaos_artifact(ok=True, rejoin=1000.0, expiry_p95=500.0):
-    return {"chaos": {
-        "family": "follower-crash-rejoin",
-        "ok": ok,
-        "checks": [{"check": "rejoin_digest_equal", "ok": ok}],
-        "time_to_rejoin_ms": rejoin,
-        "expiry_replacement_ms": {"n": 8, "p95_ms": expiry_p95},
-    }}
-
-
-def test_chaos_gate_scopes_and_verdicts():
-    import tools.bench_watch as bw
-
-    assert bw.chaos_gate({"placements": {}}, None) is None
-    # Absolute: invariants hold every round, baseline or not.
-    v = bw.chaos_gate(_chaos_artifact(ok=True), None)
-    assert v["ok"] is True
-    v = bw.chaos_gate(_chaos_artifact(ok=False), None)
-    assert v["ok"] is False
-    # Relative: >tolerance growth in rejoin time regresses.
-    v = bw.chaos_gate(_chaos_artifact(rejoin=1600.0),
-                      _chaos_artifact(rejoin=1000.0))
-    assert v["ok"] is False
-    assert any(c["check"] == "time_to_rejoin_ms" and c["regressed"]
-               for c in v["checks"])
-    v = bw.chaos_gate(_chaos_artifact(rejoin=1400.0),
-                      _chaos_artifact(rejoin=1000.0))
-    assert v["ok"] is True
-    # Expiry->replacement p95 regression trips the same way.
-    v = bw.chaos_gate(_chaos_artifact(expiry_p95=900.0),
-                      _chaos_artifact(expiry_p95=500.0))
-    assert v["ok"] is False

@@ -188,8 +188,8 @@ def test_compile_cache_not_placed_for_the_cpu_backend(monkeypatch):
 
 def test_acquisition_places_the_cache_before_the_solver_compiles(
         unacquired, monkeypatch):
-    """bench.py, chip_smoke.py, tools/simload.py and the agent all get the
-    cache by going through acquisition; none sets one of its own."""
+    """The agent and the benchmark both get the cache by going through
+    acquisition; neither sets one of its own."""
     order = []
     monkeypatch.setattr(
         sched, "configure_compile_cache",
@@ -197,7 +197,6 @@ def test_acquisition_places_the_cache_before_the_solver_compiles(
     assert acquire_device()["compile_cache"] == "/placed"
     assert order == [("cache", "cpu")]
     assert device_status()["compile_cache"] == "/placed"
-    for script in ("bench.py", "chip_smoke.py", "tools/simload.py",
-                   "nomad_tpu/agent.py"):
+    for script in ("nomad_tpu/agent.py", "benchmark/run.py"):
         text = open(os.path.join(REPO, script)).read()
         assert "jax_compilation_cache_dir" not in text, script

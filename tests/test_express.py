@@ -228,8 +228,8 @@ def test_express_end_to_end():
         eval_id, _ = srv.job_register(job)
         submit_ms = (time.perf_counter() - t0) * 1000.0
         # In-line answer: no broker/worker/plan-queue on the submit path
-        # (generous bound — suite boxes are noisy; the real latency
-        # claim is the banked express-mix artifact).
+        # (generous bound — suite boxes are noisy; the lane has no
+        # measured latency claim: no benchmark cell drives it).
         assert submit_ms < 250.0
         lane = srv.express_lane
         assert lane.placed == 1 and lane.tasks_placed == 3

@@ -5,8 +5,10 @@ rules — BlockSpec checks, memory spaces, every primitive's Mosaic rule —
 without a device, so a kernel the installed JAX refuses is caught here on
 the CPU-pinned suite. Interpret mode skips all of that: it accepted the
 per-eval ``(1, ·)`` SMEM blocks that do not lower for B > 1. Whether the
-Mosaic COMPILER then accepts the module only a chip can say; chip_smoke.py
-compiles and compares the same shapes there.
+Mosaic COMPILER then accepts the module only a chip can say: the
+benchmark's drain cells run the kernel there (``benchmark/run.py``;
+``jit_solve_waterfill_pallas_batched`` in the ledger's ``device_ops``),
+with ``correct`` decided against ``benchmark/reference.py``.
 """
 
 import jax
@@ -16,7 +18,7 @@ import pytest
 from nomad_tpu.ops.coalesce import MAX_BATCH_BUCKET
 from nomad_tpu.ops.pallas_solve import solve_waterfill_pallas_batched
 
-NODE_BUCKET = 16384  # steady-10k / burst-100k: bucket(10_000)
+NODE_BUCKET = 16384  # cell-10k: bucket(10_000)
 
 
 def _arg_shapes(b, n, d=4):

@@ -4,7 +4,7 @@ The kernel must be bit-identical to ops/binpack.solve_waterfill (which is
 itself differential-fuzzed against the host oracle), so the pallas path
 inherits the whole oracle-parity chain. Runs in interpret mode on the CPU
 backend; tests/test_pallas_lowering.py lowers it for TPU without a device
-and chip_smoke.py compiles and compares it on the chip."""
+and the benchmark's drain cells run it on the chip."""
 
 import numpy as np
 import pytest

@@ -3,9 +3,9 @@ config parse validation, the thread-role classification (pinned), golden
 collapsed-stack and speedscope export formats, seeded-cadence
 determinism, the lock watchdog's contention timing + closure-based
 violation semantics, the byte-economy ledger (rings, mirror
-bucket×dtype books, the measured-per-row 1M projection), the
-bench_watch runtime gate, and the /v1/agent/profile + /v1/agent/runtime
-+ SDK + bundle surfaces over a live agent."""
+bucket×dtype books, the measured-per-row 1M projection), and the
+/v1/agent/profile + /v1/agent/runtime + SDK + bundle surfaces over a
+live agent."""
 
 import json
 import sys
@@ -425,51 +425,6 @@ def test_observatory_locks_view_reads_active_watchdog():
         view = obs.runtime_view()["locks"]
         assert view["installed"] is True
         assert view["contention"][0]["lock"] == "a"
-
-
-# -- bench_watch runtime gate -------------------------------------------------
-
-
-def _profile_artifact(rss=1000, per_row=50.0, wait_p95=1.0):
-    return {"profile": {
-        "enabled": True,
-        "bytes": {"rss": {"peak_bytes": rss},
-                  "mirror": {"per_row_bytes": per_row}},
-        "locks": {"contention": [
-            {"lock": "a", "wait_ms": {"p95": wait_p95}},
-            {"lock": "b", "wait_ms": {"p95": wait_p95 / 2}},
-        ]},
-    }}
-
-
-def test_runtime_gate_scoped_and_first_round():
-    from tools.bench_watch import runtime_gate
-
-    assert runtime_gate({}, None) is None
-    assert runtime_gate({"profile": {"enabled": False}}, None) is None
-    verdict = runtime_gate(_profile_artifact(), None)
-    assert verdict["ok"] is True
-    assert {c["check"] for c in verdict["checks"]} == {
-        "rss_peak_bytes", "mirror_per_row_bytes", "lock_wait_p95_ms"}
-    assert all(c["baseline"] is None for c in verdict["checks"])
-
-
-def test_runtime_gate_regression_detection():
-    from tools.bench_watch import runtime_gate
-
-    base = _profile_artifact(rss=1000, per_row=50.0, wait_p95=1.0)
-    ok = runtime_gate(_profile_artifact(rss=1400), base)
-    assert ok["ok"] is True                      # within 50% tolerance
-    bad = runtime_gate(_profile_artifact(rss=2000), base)
-    assert bad["ok"] is False
-    assert [c["check"] for c in bad["checks"] if c["regressed"]] == [
-        "rss_peak_bytes"]
-    worse_rows = runtime_gate(_profile_artifact(per_row=200.0), base)
-    assert worse_rows["ok"] is False
-    # A disabled-profile baseline gates nothing (first-round posture).
-    assert runtime_gate(
-        _profile_artifact(rss=9999),
-        {"profile": {"enabled": False}})["ok"] is True
 
 
 # -- live agent e2e -----------------------------------------------------------

@@ -412,7 +412,7 @@ def test_raft_endpoint_e2e(agent):
     # honestly — persistence/replication stages zero-wide, fsm_apply
     # carries the cost, the full stage set still partitions. (The
     # RaftNode face is covered by the raw-node tests above and the
-    # restart-under-load scenario.)
+    # restart-800 scenario.)
     assert "job_register" in snap["write_path"]
     books = snap["write_path"]["job_register"]
     assert books["count"] >= 1

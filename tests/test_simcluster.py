@@ -40,6 +40,11 @@ from nomad_tpu.simcluster.workload import (
 
 log = logging.getLogger("test_simcluster")
 
+# steady-10k, seed 42: the canonical event digest of the CPU run, the
+# same value in every run since the decision path's draws were seeded.
+STEADY_10K_DIGEST = (
+    "2318d581f27c35f7eb6e534fe200cbb08bee52c69aec175e3538fd77020a5b8a")
+
 
 # ---------------------------------------------------------------------------
 # Injector determinism (the faults.py seeded-stream posture)
@@ -202,7 +207,7 @@ def test_heartbeat_wheel_counters(sim_server):
 
 
 def test_steady_1k_smoke(tmp_path):
-    out = tmp_path / "SIMLOAD_steady-1k_smoke.json"
+    out = tmp_path / "steady-1k_smoke.json"
     art = run_scenario("steady-1k", seed=7, out_path=str(out))
     assert out.exists()
     # 6 jobs x 260 tasks, all placed through broker→worker→solver→
@@ -236,8 +241,7 @@ def test_steady_1k_smoke(tmp_path):
 
 def test_steady_100k_nodes_registered():
     """The 100k-node scenario is registered with the intended shape (the
-    run itself is a bank-time event — tools/simload.py — not a tier-1
-    test: registration alone takes ~30s)."""
+    run itself is not a tier-1 test: registration alone takes ~30s)."""
     spec = SCENARIOS["steady-100k-nodes"]
     assert spec.n_nodes == 100_000
     assert spec.deterministic is True
@@ -254,7 +258,7 @@ def test_steady_smoke_batch_width_and_equiv_sections(tmp_path):
     """The artifact's solver_panel window carries the new batch-width
     and equivalence-class axes (present even when zero — consumers diff
     them across rounds)."""
-    out = tmp_path / "SIMLOAD_steady-1k_panel.json"
+    out = tmp_path / "steady-1k_panel.json"
     art = run_scenario("steady-1k", seed=11, out_path=str(out))
     window = art["solver_panel"]["window"]
     assert "batch_widths" in window
@@ -273,7 +277,7 @@ def test_overdrive_1k_smoke(tmp_path):
     glacial refill) admit exactly 2 per client DETERMINISTICALLY, the
     rest reject RATE_LIMITED typed, every queue stays under its cap, and
     admitted work all places."""
-    out = tmp_path / "SIMLOAD_overdrive-1k_smoke.json"
+    out = tmp_path / "overdrive-1k_smoke.json"
     art = run_scenario("overdrive-1k", seed=42, out_path=str(out))
     adm = art["admission"]
     assert adm["injector"]["offered"] == 6 * 8
@@ -298,7 +302,7 @@ def test_express_1k_smoke(tmp_path):
     places in-line (ExpressPlaced events = submissions), every entry
     commits asynchronously with nothing left on the ledger, and the
     artifact carries the express quantiles + slo_check rows."""
-    out = tmp_path / "SIMLOAD_express-1k_smoke.json"
+    out = tmp_path / "express-1k_smoke.json"
     art = run_scenario("express-1k", seed=42, out_path=str(out))
     lane = art["express"]["lane"]
     assert lane["enabled"] is True
@@ -336,7 +340,7 @@ def test_churn_frag_200_smoke(tmp_path):
     bank the stranded/padding trajectories, and the observatory-OFF
     contrast arm must reproduce the main arm's canonical digest — the
     decision-invariance proof."""
-    out = tmp_path / "SIMLOAD_churn-frag-200_smoke.json"
+    out = tmp_path / "churn-frag-200_smoke.json"
     art = run_scenario("churn-frag-200", seed=42, out_path=str(out))
     # 6x400 fill + 2x40 probes placed; 3 deregistered jobs stop 1200.
     assert art["placements"]["placed"] == 6 * 400 + 2 * 40
@@ -386,7 +390,7 @@ def test_restart_800_smoke(tmp_path):
     survive the replay verbatim (same alloc id, same node), the run
     still places everything, and the artifact banks a populated
     recovery timeline."""
-    out = tmp_path / "SIMLOAD_restart-800_smoke.json"
+    out = tmp_path / "restart-800_smoke.json"
     art = run_scenario("restart-800", seed=42, out_path=str(out))
     assert art["placements"]["placed"] == 6 * 120
     assert art["events"]["truncated"] is False
@@ -445,7 +449,7 @@ def test_read_storm_800_smoke(tmp_path):
     fleet's client-side view; the leader-only contrast arm must
     reproduce the main arm's canonical digest — the read-path
     decision-invariance proof."""
-    out = tmp_path / "SIMLOAD_read-storm-800_smoke.json"
+    out = tmp_path / "read-storm-800_smoke.json"
     art = run_scenario("read-storm-800", seed=42, out_path=str(out))
     assert art["placements"]["placed"] == 6 * 120
     assert art["events"]["truncated"] is False
@@ -553,9 +557,8 @@ def test_read_storm_smoke_is_seed_deterministic():
 
 @pytest.mark.slow
 def test_read_storm_scenario():
-    """The full 10k-node follower-read-plane proof (the committed
-    SIMLOAD_read-storm_* artifacts use tools/simload.py; this keeps it
-    executable in-suite): the steady-10k write load on a 3-member cell
+    """The full 10k-node follower-read-plane proof: the steady-10k
+    write load on a 3-member cell
     under a 15-reader fleet riding the follower fronts, with the
     leader's plan latency banked as the headline read-relief number."""
     art = run_scenario("read-storm", seed=42)
@@ -616,9 +619,9 @@ def test_overdrive_injector_determinism():
 
 
 def test_same_seed_reproduces_canonical_event_sequence():
-    """The simload replay contract at smoke scale: same seed → same
-    canonical event digest (sorted multiset of per-key event-type
-    sequences), the reduction the SIMLOAD artifacts bank."""
+    """The replay contract at smoke scale: same seed → same canonical
+    event digest (sorted multiset of per-key event-type sequences), the
+    reduction every artifact carries."""
     spec = ScenarioSpec(
         name="steady-mini", n_nodes=300,
         injectors=lambda seed: [SteadyServiceInjector(
@@ -834,8 +837,8 @@ def test_churn_scenario_runs():
 
 @pytest.mark.slow
 def test_steady_10k_scenario():
-    """The seeded 10k-node artifact scenario (the committed SIMLOAD_*
-    runs use tools/simload.py; this keeps it executable in-suite)."""
+    """The seeded 10k-node scenario, 24 service jobs x420 tasks under
+    node-refresh writes, and its canonical digest."""
     art = run_scenario("steady-10k", seed=42)
     assert art["placements"]["placed"] == 24 * 420
     assert art["heartbeat"]["timers"] == 10_000
@@ -843,16 +846,9 @@ def test_steady_10k_scenario():
             <= art["heartbeat"]["rate_cap_per_sec"])
     assert art["plan_latency_ms"]["n"] == 24
     assert art["events"]["truncated"] is False
-    # Same-seed replay pins the BANKED canonical digest: moving the
-    # decision-path draws (node shuffle, broker scheduler choice,
-    # heartbeat jitter) off the global random module onto seeded
-    # per-context streams (nomadlint DET001) must leave the canonical
-    # event history byte-identical to the committed r07 artifact.
-    import json
-    import os
-
-    banked_path = os.path.join(os.path.dirname(__file__), "..",
-                               "SIMLOAD_steady-10k_s42_r07.json")
-    with open(banked_path) as f:
-        banked = json.load(f)
-    assert art["events"]["digest"] == banked["events"]["digest"]
+    # Same-seed replay pins the canonical digest: the decision-path
+    # draws (node shuffle, broker scheduler choice, heartbeat jitter)
+    # ride seeded per-context streams (nomadlint DET001), so the
+    # canonical event history of seed 42 is this one value, byte-equal
+    # since the draws left the global random module.
+    assert art["events"]["digest"] == STEADY_10K_DIGEST

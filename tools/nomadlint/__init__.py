@@ -6,7 +6,7 @@ correctness story rests on but nothing previously *checked*:
 - **determinism** (DET0xx): scheduler / FSM / plan / simcluster decision
   paths must not draw from the global ``random`` module, stamp intervals
   with ``time.time()``, or iterate unordered sets — the seed-replay
-  contract (SIMLOAD event digests, fuzz families) only holds when every
+  contract (simcluster event digests, fuzz families) only holds when every
   source of nondeterminism is a name-salted seeded stream (the
   ``faults.py`` pattern) or ``time.monotonic()``.
 - **lockorder** (LCK0xx): extracts the whole-program lock graph (which

@@ -2,7 +2,7 @@
 seeds.
 
 Scope rationale: DET001/DET003 cover the modules whose outputs feed the
-seed-replay contract (SIMLOAD event digests, fuzz differential families)
+seed-replay contract (simcluster event digests, fuzz differential families)
 — scheduler, server, raft, state, simcluster, device solve, structs,
 network, events, faults. Observability modules (telemetry/trace/bundle)
 are excluded from DET001/DET003: a reservoir sample or span id draw

@@ -6,7 +6,7 @@
 ``nomad_tpu/profile_observe.py`` (the runtime self-observatory) observe
 cluster state through change logs and plain-data books, and must stay
 invisible to every decision path — the decision-invariance proofs (the
-churn-fragmentation observatory-off contrast arm's digest equality; the
+churn-frag-200 observatory-off contrast arm's digest equality; the
 steady-10k digest staying byte-equal with the raft observatory on; the
 read-storm reads-off contrast arm's digest equality) only mean
 something if no placement, verify, or apply path can even *reach* an

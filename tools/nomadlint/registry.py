@@ -92,7 +92,7 @@ RULES: Dict[str, Rule] = {
              "decision paths must never import it — a placement that "
              "consults the observer's books couples decisions to poll "
              "timing and voids the decision-invariance contract the "
-             "churn-fragmentation digest arm pins. Only the composition "
+             "churn-frag-200 digest arm pins. Only the composition "
              "roots (server/server.py wiring, api/ exposition) may "
              "construct or read it."),
         Rule("META001", "meta",

@@ -63,28 +63,6 @@ def capture_bundle(path: str) -> str:
                 "note": "suite ran as a subprocess; set "
                         "NOMAD_TPU_DEBUG_AGENT to capture a live agent",
             }
-        latest = _latest_simload_artifact()
-        if latest:
-            try:
-                with open(latest) as f:
-                    bundle["simload_artifact"] = {
-                        "path": latest, "data": json.load(f),
-                    }
-                # SLO verdicts over the embedded artifact: the bundle's
-                # own `slo`/`timelines` sections capture THIS process
-                # (live state), while the artifact check records whether
-                # the last banked control-plane run was inside the
-                # objectives — both views ride a red run.
-                att = bundle["simload_artifact"]["data"].get(
-                    "latency_attribution")
-                if att:
-                    from nomad_tpu.slo import evaluate_artifact
-
-                    bundle["simload_artifact"]["slo_check"] = (
-                        evaluate_artifact(att))
-            except (OSError, ValueError) as e:
-                bundle["simload_artifact"] = {"path": latest,
-                                              "error": str(e)}
         with open(path, "w") as f:
             json.dump(bundle, f, indent=2, default=str)
         return path
@@ -92,28 +70,6 @@ def capture_bundle(path: str) -> str:
         print(f"tier1: debug bundle capture failed: {e}", file=sys.stderr)
         return ""
 
-
-def _latest_simload_artifact() -> str:
-    """Newest SIMLOAD_*.json (repo root, then /tmp): a failed run's bundle
-    carries the most recent control-plane scale capture, so a regression
-    hunt can compare the red run's environment against the last-known
-    pipeline throughput without re-running the scenario."""
-    import glob
-
-    def mtime(p):
-        # /tmp is shared: an artifact deleted between glob and stat must
-        # not abort the WHOLE bundle capture for an optional attachment.
-        try:
-            return os.path.getmtime(p)
-        except OSError:
-            return 0.0
-
-    candidates = sorted(
-        glob.glob(os.path.join(REPO, "SIMLOAD_*.json"))
-        + glob.glob("/tmp/SIMLOAD_*.json"),
-        key=mtime, reverse=True,
-    )
-    return candidates[0] if candidates else ""
 
 PYTEST_ARGS = [
     "-m", "pytest", "tests/", "-q", "-m", "not slow",
