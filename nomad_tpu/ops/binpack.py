@@ -53,18 +53,17 @@ _DEVICE_CONST_CACHE: dict = {}
 
 
 def device_const(kind: str, value):
-    """Small device-resident constants (ask vectors, penalties, bandwidth
+    """Small device-resident constants ("ask" vectors, "i32" bandwidth
     asks). Every host->device transfer pays a fixed dispatch cost, so even
-    16-byte uploads are worth caching across evals."""
+    16-byte uploads are worth caching across evals. (Counts and penalties
+    ride the dispatch itself as host arrays: ops/coalesce.py.)"""
     key = (kind, value)
     cached = _DEVICE_CONST_CACHE.get(key)
     if cached is None:
         if kind == "ask":
             cached = jnp.asarray(list(value), dtype=jnp.int32)
-        elif kind == "i32":
-            cached = jnp.int32(value)
         else:
-            cached = jnp.float32(value)
+            cached = jnp.int32(value)
         if len(_DEVICE_CONST_CACHE) > 512:
             _DEVICE_CONST_CACHE.clear()
         _DEVICE_CONST_CACHE[key] = cached
@@ -153,50 +152,6 @@ def solve_greedy(
         step, (used0, job_count0, tg_count0, bw_used0), active
     )
     return idxs, oks, scores
-
-
-@partial(jax.jit, static_argnames=("k", "job_distinct", "tg_distinct"))
-def solve_greedy_batched(
-    total, sched_cap, used0, job_count0, tg_count0, bw_avail, bw_used0,
-    eligible, ask, bw_ask, active, penalty, k, job_distinct, tg_distinct,
-):
-    """vmap of the exact greedy scan over the eval axis: every input is
-    stacked on axis 0 ([B, ...]) and each row runs the IDENTICAL
-    sequential scan it would run alone — rows never read each other, so
-    a stacked dispatch is decision-identical to B individual dispatches
-    (the fuzz differential pins bit equality). This is the cross-eval
-    batching of the small-count path: K concurrent evals' exact solves
-    cost one device round trip instead of K."""
-    return jax.vmap(
-        solve_greedy,
-        in_axes=(0,) * 12 + (None, None, None),
-    )(
-        total, sched_cap, used0, job_count0, tg_count0, bw_avail, bw_used0,
-        eligible, ask, bw_ask, active, penalty, k, job_distinct, tg_distinct,
-    )
-
-
-@partial(jax.jit, static_argnames=("k", "job_distinct", "tg_distinct"))
-def solve_greedy_batched_shared(
-    total, sched_cap, used0, job_count0, tg_count0, bw_avail, bw_used0,
-    eligible, ask, bw_ask, active, penalty, k, job_distinct, tg_distinct,
-):
-    """solve_greedy_batched with the NODE tensors (total, sched_cap,
-    bw_avail) shared across the eval axis instead of stacked: the
-    coalescer groups exact entries by mirror identity, so every row of a
-    stacked dispatch reads the same mirror — broadcasting beats
-    materializing B copies of the [N, .] node data (at width 8 on the
-    131072-row bucket, ~40MB of device memory and 8x the node-axis
-    traffic per dispatch). Decision-identical to the all-stacked form:
-    vmap broadcast semantics, not a kernel change."""
-    return jax.vmap(
-        solve_greedy,
-        in_axes=(None, None, 0, 0, 0, None, 0, 0, 0, 0, 0, 0,
-                 None, None, None),
-    )(
-        total, sched_cap, used0, job_count0, tg_count0, bw_avail, bw_used0,
-        eligible, ask, bw_ask, active, penalty, k, job_distinct, tg_distinct,
-    )
 
 
 @partial(jax.jit, static_argnames=("job_distinct", "tg_distinct"))
@@ -393,7 +348,7 @@ def solve_many_async(
     if count <= exact_threshold:
         # The exact scan rides the coalescing engine like the water-fill:
         # concurrent workers' small-count solves of one shape bucket
-        # stack on the eval axis (solve_greedy_batched) and cost ONE
+        # stack on the eval axis (coalesce.solve_greedy_rows) and cost ONE
         # device dispatch instead of K. Each stacked row runs the
         # identical independent scan, so results are bit-equal to a lone
         # dispatch (fuzz-pinned).

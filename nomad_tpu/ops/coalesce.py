@@ -18,6 +18,19 @@ those K solves have all arrived or a short deadline passes — without
 this, the K eval threads' staggered host prep (snapshot, masks) lands
 their submits a few ms apart and the burst fragments into several
 small dispatches instead of one stacked one.
+
+One program a dispatch: the launch (_launch_rows) is ONE call into the
+device runtime for either program family at any width. The riders' rows
+go into the jitted entry (solve_waterfill_rows / solve_greedy_rows) as
+the device arrays each entry already holds, the per-eval counts and
+penalties as two small typed host arrays; the stacking on the eval axis
+(a lone solve is B = 1), the exact scan's active masks and the choice of
+water-fill kernel all happen inside that program. Nothing eager runs on
+the dispatcher thread beside it: each eager op or transfer costs as much
+host time as the launch itself (0.7-0.8 ms on the benchmark's host, where
+a width-1 water-fill used to make 19 such calls around a 27 us kernel).
+The span solver.execute.launch covers exactly this: taken off the
+pending list -> that one call returned.
 """
 
 from __future__ import annotations
@@ -35,12 +48,8 @@ import numpy as np
 
 from nomad_tpu import telemetry, trace
 from nomad_tpu.ops import pallas_solve
-from nomad_tpu.ops.binpack import (
-    solve_greedy,
-    solve_greedy_batched,
-    solve_greedy_batched_shared,
-    solve_waterfill,
-)
+from nomad_tpu.ops.binpack import bucket, solve_greedy, solve_waterfill
+from nomad_tpu.parallel import mesh as mesh_lib
 
 logger = logging.getLogger("nomad_tpu.coalesce")
 
@@ -69,33 +78,85 @@ BURST_WINDOW_S = float(os.environ.get("NOMAD_TPU_COALESCE_WINDOW", "0.25"))
 _BURST_TLS = threading.local()
 
 
-@partial(jax.jit, static_argnames=("job_distinct", "tg_distinct"))
-def solve_waterfill_batched(
-    total, sched_cap, used0, job_count0, tg_count0, bw_avail, bw_used0,
-    eligible, ask, bw_ask, count, penalty, job_distinct, tg_distinct,
-):
-    """vmap of the closed-form water-fill over the eval axis. Every input
-    is stacked on axis 0 ([B, ...]); evals solve independently against
-    their own optimistic view, like concurrent reference workers."""
-    return jax.vmap(
-        solve_waterfill,
-        in_axes=(0,) * 12 + (None, None),
+def _stack_columns(rows):
+    """[B rows of per-eval tensors] -> one [B, ...] tensor a column."""
+    return tuple(jnp.stack(col) for col in zip(*rows))
+
+
+@partial(jax.jit,
+         static_argnames=("job_distinct", "tg_distinct", "kernel", "mesh"))
+def solve_waterfill_rows(rows, counts, penalties, job_distinct, tg_distinct,
+                         kernel="jnp", mesh=None):
+    """ONE program a water-fill dispatch, whatever its width. ``rows`` is
+    a tuple of B rows of the ten device arrays of solve_waterfill's
+    positional order, as each rider holds them; ``counts`` (int32[B]) and
+    ``penalties`` (float32[B]) ride as host arrays, typed, so that no
+    value retraces. The stacking on the eval axis (the ``[None]`` of a
+    lone solve is the case B = 1) happens in here, then the Pallas kernel
+    (``kernel="pallas"``, decided before the call by pallas_solve.selected)
+    or the vmapped closed form. Every eval solves independently against
+    its own optimistic view, like concurrent reference workers. Returns
+    (counts[B, N], remaining[B])."""
+    stacked = _stack_columns(rows)
+    if mesh is not None:
+        stacked = mesh_lib.constrain_eval_stack(
+            mesh, stacked, mesh_lib.WF_SPECS)
+    if kernel == "pallas":
+        return pallas_solve.solve_waterfill_pallas_batched(
+            *stacked, counts, penalties, job_distinct, tg_distinct)
+    return jax.vmap(solve_waterfill, in_axes=(0,) * 12 + (None, None))(
+        *stacked, counts, penalties, job_distinct, tg_distinct)
+
+
+# Which of the ten tensors of a row the evals of one exact dispatch share
+# (total, sched_cap, bw_avail: the mirror's) and which are each eval's own.
+_SHARED_COLS = (0, 1, 5)
+_EVAL_COLS = (2, 3, 4, 6, 7, 8, 9)
+
+
+@partial(jax.jit,
+         static_argnames=("k", "job_distinct", "tg_distinct", "mesh"))
+def solve_greedy_rows(shared, rows, counts, penalties, k, job_distinct,
+                      tg_distinct, mesh=None):
+    """ONE program an exact-scan dispatch, whatever its width: the vmap of
+    solve_greedy over the eval axis, each row the IDENTICAL sequential
+    scan it would run alone (rows never read each other: bit-equal to B
+    lone dispatches, fuzz-pinned). ``shared`` is the mirror's (total,
+    sched_cap, bw_avail), read once by every row (the dispatcher groups
+    exact entries by mirror identity; broadcasting beats materializing B
+    copies of the [N, .] node data); ``rows`` is a tuple of B rows of the
+    seven per-eval tensors (_EVAL_COLS). The active masks are built in
+    here from ``counts`` (int32[B], host). Returns (idxs[B, k],
+    oks[B, k])."""
+    total, sched_cap, bw_avail = shared
+    stacked = _stack_columns(rows)
+    if mesh is not None:
+        stacked = mesh_lib.constrain_eval_stack(
+            mesh, stacked, [mesh_lib.WF_SPECS[i] for i in _EVAL_COLS])
+    used0, job_count0, tg_count0, bw_used0, eligible, ask, bw_ask = stacked
+    active = jnp.arange(k, dtype=jnp.int32)[None, :] < counts[:, None]
+    idxs, oks, _scores = jax.vmap(
+        solve_greedy,
+        in_axes=(None, None, 0, 0, 0, None, 0, 0, 0, 0, 0, 0,
+                 None, None, None),
     )(
         total, sched_cap, used0, job_count0, tg_count0, bw_avail, bw_used0,
-        eligible, ask, bw_ask, count, penalty, job_distinct, tg_distinct,
+        eligible, ask, bw_ask, active, penalties, k, job_distinct,
+        tg_distinct,
     )
+    return idxs, oks
 
 
-def _record_dispatch_width(width: int, wall_ms: float) -> None:
-    """Feed the solver panel's batch-width axis (SOLVER_PANEL is the
-    process-wide /v1/agent/solver book). Late import: the coalescer must
+def _panel():
+    """The solver panel (SOLVER_PANEL is the process-wide
+    /v1/agent/solver book), or None. Late import: the coalescer must
     stay importable (and the dispatch must not fail) when the solver
     stack never initialized — e.g. pure-kernel benchmarks."""
     try:
         from nomad_tpu.tpu.solver import SOLVER_PANEL
     except Exception:  # pragma: no cover - import breakage only
-        return
-    SOLVER_PANEL.record_dispatch(width, wall_ms)
+        return None
+    return SOLVER_PANEL
 
 
 class _Entry:
@@ -117,7 +178,7 @@ class _Entry:
         # Made on its rider's thread: does the rider carry a stage timer?
         # Only then the dispatcher stamps it, on the span clock
         # (trace.now): its batch taken off the pending list (the hold is
-        # over), and the jit call returned (stacking, launch and any
+        # over), and the dispatch's one jit call returned (launch and any
         # compile done; the event is set next), with the dispatcher
         # thread's CPU seconds from taken to launched. The group carries
         # t_ready. taken <= launched <= ready.
@@ -208,10 +269,12 @@ class _Group:
                     # Dispatch→ready wall, rider-attributed like the
                     # panel's per-solve device_ms (an upper bound when
                     # the fetcher arrives late).
-                    _record_dispatch_width(
-                        self.width,
-                        (time.perf_counter() - self.t0) * 1000.0,
-                    )
+                    panel = _panel()
+                    if panel is not None:
+                        panel.record_dispatch(
+                            self.width,
+                            (time.perf_counter() - self.t0) * 1000.0,
+                        )
 
     def fetch(self, index: int) -> Tuple[np.ndarray, int]:
         self._materialize()
@@ -368,11 +431,9 @@ class CoalescingSolver:
         """Queue one exact greedy scan (count <= EXACT_THRESHOLD).
         Concurrent exact solves of one (node bucket, count bucket,
         distinct flags) shape stack on the eval axis and dispatch as ONE
-        solve_greedy_batched program — each stacked row runs the
+        solve_greedy_rows program — each stacked row runs the
         identical independent scan, so results are bit-equal to a lone
         dispatch. Returns fetch() -> (node_indices[count], ok[count])."""
-        from nomad_tpu.ops.binpack import bucket
-
         entry = _Entry((
             total, sched_cap, used0, job_count0, tg_count0, bw_avail,
             bw_used0, eligible, ask, bw_ask, count, penalty,
@@ -472,7 +533,7 @@ class CoalescingSolver:
         # never share a key. Exact entries additionally key on MIRROR
         # IDENTITY (id of the total tensor — entries hold refs, so ids
         # are stable for the dispatch): a stacked exact dispatch shares
-        # the node tensors across its rows (solve_greedy_batched_shared)
+        # the node tensors across its rows (solve_greedy_rows' ``shared``)
         # instead of materializing B copies, which is only sound when
         # every row reads the same mirror. Same-generation burst members
         # do; cross-generation stragglers dispatch separately.
@@ -496,11 +557,10 @@ class CoalescingSolver:
                     self._dispatch_group(chunk, jd, td)
                 except Exception:
                     # Solve each entry individually so waiters never hang
-                    # on a batch-level error — the SAME program family at
-                    # width 1, counted and logged so a stacked program
-                    # that cannot run is never silent. An entry whose
-                    # retry also fails carries the exception to its
-                    # fetch() caller.
+                    # on a batch-level error — the SAME entry at B = 1,
+                    # counted and logged so a stacked program that cannot
+                    # run is never silent. An entry whose retry also
+                    # fails carries the exception to its fetch() caller.
                     self.batch_retries += 1
                     telemetry.incr_counter(
                         ("scheduler", "coalesce", "batch_retry"))
@@ -509,60 +569,10 @@ class CoalescingSolver:
                         "re-solving one at a time", len(chunk))
                     for e in chunk:
                         try:
-                            (a_dev, b_dev), path = self._solve_one(e)
-                            self._count_path(path)
-                            cls = (_ExactGroup if e.kind == "exact"
-                                   else _Group)
-                            e.group = cls(a_dev[None], b_dev[None],
-                                          path=path)
-                            e.index = 0
+                            self._launch([e], jd, td)
                         except Exception as exc:
                             e.error = exc
-                        finally:
                             self._launched([e])
-
-    @staticmethod
-    def _solve_one(e: _Entry):
-        """Single-entry dispatch, node-axis sharded over the configured
-        mesh when one exists (parallel/mesh.py). Water-fill entries: on
-        an unsharded TPU backend the whole solve runs as one
-        VMEM-resident pallas kernel (pallas_solve.selected). Exact
-        entries run the greedy scan (no pallas variant). Returns
-        ((a_dev, b_dev), path) — (counts, remaining) for wf, (idxs, oks)
-        for exact; path names the program family that carried it."""
-        from nomad_tpu.parallel import mesh as mesh_lib
-
-        from nomad_tpu.ops.binpack import device_const
-
-        args10 = e.args[:10]
-        mesh = mesh_lib.mesh_for_nodes(args10[0].shape[0])
-        if e.kind == "exact":
-            # Cached device constant, like the pre-coalescer inline path.
-            penalty = device_const("f32", e.args[11])
-            active = jnp.arange(e.k) < e.args[10]
-            if mesh is not None:
-                args10 = mesh_lib.shard_waterfill_args(mesh, args10)
-                active, penalty = mesh_lib.replicate_on_mesh(
-                    mesh, active, penalty
-                )
-            idxs, oks, _scores = solve_greedy(
-                *args10, active, penalty, e.k, e.args[12], e.args[13],
-            )
-            return (idxs, oks), "exact"
-        penalty = jnp.float32(e.args[11])
-        count = jnp.int32(e.args[10])
-        if mesh is None:
-            if pallas_solve.selected(args10[0].shape[0]):
-                return pallas_solve.solve_waterfill_pallas(
-                    *args10, count, penalty, e.args[12], e.args[13],
-                ), "pallas"
-        else:
-            args10 = mesh_lib.shard_waterfill_args(mesh, args10)
-            count, penalty = mesh_lib.replicate_on_mesh(mesh, count, penalty)
-        return (
-            solve_waterfill(*args10, count, penalty, e.args[12], e.args[13]),
-            "jnp",
-        )
 
     def _launched(self, entries: List[_Entry]) -> None:
         """Wake one dispatch's riders; where one of the batch is traced,
@@ -588,132 +598,65 @@ class CoalescingSolver:
         telemetry.add_sample(
             ("scheduler", "coalesce", "batch_size"), float(len(entries))
         )
-        t0 = time.perf_counter()
-        if len(entries) == 1:
-            e = entries[0]
-            (a_dev, b_dev), path = self._solve_one(e)
-            self._count_path(path)
-            cls = _ExactGroup if e.kind == "exact" else _Group
-            e.group = cls(a_dev[None], b_dev[None], width=1, t0=t0,
-                          path=path)
-            e.index = 0
-            self._launched(entries)
-            return
+        if len(entries) > 1:
+            self.coalesced += len(entries)
+        if self._launch(entries, jd, td, t0=time.perf_counter()):
+            panel = _panel()
+            if panel is not None:
+                panel.record_single_program_dispatch()
 
-        self.coalesced += len(entries)
-        if entries[0].kind == "exact":
-            idxs_dev, oks_dev = _stack_and_solve_exact(
-                [e.args for e in entries], entries[0].k, jd, td
-            )
-            self._count_path("exact")
-            group: _Group = _ExactGroup(
-                idxs_dev, oks_dev, width=len(entries), t0=t0, path="exact"
-            )
-        else:
-            counts_dev, remaining_dev, path = _stack_and_solve(
-                [e.args for e in entries], jd, td
-            )
-            self._count_path(path)
-            group = _Group(counts_dev, remaining_dev,
-                           width=len(entries), t0=t0, path=path)
+    def _launch(self, entries: List[_Entry], jd: bool, td: bool,
+                t0: Optional[float] = None) -> bool:
+        """One dispatch's launch (_launch_rows), its riders pointed at
+        the group and woken. Returns whether it went out as one call."""
+        head = entries[0]
+        a_dev, b_dev, path, single = _launch_rows(
+            [e.args for e in entries], head.kind, head.k, jd, td)
+        self._count_path(path)
+        cls = _ExactGroup if head.kind == "exact" else _Group
+        group = cls(a_dev, b_dev, width=len(entries), t0=t0, path=path)
         for i, e in enumerate(entries):
             e.group = group
             e.index = i
         self._launched(entries)
+        return single
 
 
-def _stack_rows(rows, jd: bool, td: bool):
-    """Pad the eval axis to its power-of-two bucket and stack the arg
-    columns. Padding rows repeat row 0 with count=0 (a no-op solve)."""
-    from nomad_tpu.ops.binpack import bucket
-
-    b = bucket(len(rows), floor=2)
-    rows = list(rows)
-    rows.extend([rows[0][:10] + (0, 0.0, jd, td)] * (b - len(rows)))
-    cols = list(zip(*(r[:10] for r in rows)))
-    stacked = [jnp.stack(col) for col in cols]
-    counts = jnp.asarray([r[10] for r in rows], dtype=jnp.int32)
-    penalties = jnp.asarray([r[11] for r in rows], dtype=jnp.float32)
-    return stacked, counts, penalties
-
-
-def _stack_and_solve(rows, jd: bool, td: bool):
-    """Stack the eval axis (_stack_rows), shard on the mesh, dispatch the
-    batched water-fill. The ONE stacking implementation — shared by the
-    dispatcher and warm_batch_shapes so warmup provably compiles the exact
-    shapes real dispatches use. Returns (counts, remaining, path)."""
-    from nomad_tpu.parallel import mesh as mesh_lib
-
-    stacked, counts, penalties = _stack_rows(rows, jd, td)
-    mesh = mesh_lib.mesh_for_nodes(stacked[0].shape[1])
-    if mesh is None:
-        if pallas_solve.selected(stacked[0].shape[1]):
-            return (
-                *pallas_solve.solve_waterfill_pallas_batched(
-                    *stacked, counts, penalties, jd, td),
-                "pallas",
-            )
-    else:
-        stacked, counts, penalties = mesh_lib.shard_waterfill_batch_args(
-            mesh, stacked, counts, penalties
-        )
-    return (
-        *solve_waterfill_batched(*stacked, counts, penalties, jd, td),
-        "jnp",
-    )
-
-
-def _stack_rows_exact(rows, k: int, jd: bool, td: bool):
-    """Pad the exact-entry list to its power-of-two eval-axis bucket
-    (padding rows repeat row 0 with count=0 — an all-inactive scan) and
-    build the stacked active masks + penalties from the per-entry
-    counts. Returns (rows_padded, active, penalties)."""
-    from nomad_tpu.ops.binpack import bucket
-
-    b = bucket(len(rows), floor=2)
-    rows = list(rows)
-    rows.extend([rows[0][:10] + (0, 0.0, jd, td)] * (b - len(rows)))
-    counts = np.asarray([r[10] for r in rows], dtype=np.int32)
-    active = jnp.asarray(np.arange(k, dtype=np.int32)[None, :]
-                         < counts[:, None])
-    penalties = jnp.asarray([r[11] for r in rows], dtype=jnp.float32)
-    return rows, active, penalties
-
-
-def _stack_and_solve_exact(rows, k: int, jd: bool, td: bool):
-    """Stack the eval axis and dispatch ONE batched exact greedy scan.
-    The dispatcher's identity grouping guarantees every row reads the
-    SAME mirror, so the node tensors (total, sched_cap, bw_avail) ride
-    once — broadcast by vmap (solve_greedy_batched_shared) — and only
-    the per-eval tensors stack. On a configured mesh the fully-stacked
-    SPMD form runs instead (the eval axis can then shard over the
-    mesh's eval extent). Shared by the dispatcher and
-    warm_exact_batch_shapes so warmup provably compiles the exact
-    shapes real dispatches use. Returns (idxs_dev[B, k], oks_dev[B, k])."""
-    from nomad_tpu.parallel import mesh as mesh_lib
-
-    rows, active, penalties = _stack_rows_exact(rows, k, jd, td)
-    mesh = mesh_lib.mesh_for_nodes(rows[0][0].shape[0])
+def _launch_rows(rows, kind: str, k: int, jd: bool, td: bool):
+    """THE launch of a dispatch of either family at any width, shared by
+    the dispatcher, its per-entry retry (B = 1) and the warm calls, so
+    the warmed set is provably the dispatched set. Off the mesh it is ONE
+    call into the device runtime: the riders' rows go in as they are, the
+    per-eval scalars as two small typed host arrays, and everything else
+    (stacking, active masks, the kernel) happens inside the jitted entry.
+    The eval axis pads to its power-of-two bucket; padding rows repeat
+    row 0's arrays with count 0 (a no-op solve). On a configured mesh
+    (parallel/mesh.py) the rows' placement calls run first, in front of
+    the same entries. Returns (a_dev[B, .], b_dev[B, .], path, single):
+    (counts, remaining) for "wf", (idxs, oks) for "exact"; path names the
+    program family that carried it; single says the launch was one call."""
+    pad = bucket(len(rows), floor=1) - len(rows)
+    counts = np.array([r[10] for r in rows] + [0] * pad, dtype=np.int32)
+    penalties = np.array([r[11] for r in rows] + [0.0] * pad,
+                         dtype=np.float32)
+    rows10 = [r[:10] for r in rows]
+    n_padded = rows10[0][0].shape[0]
+    mesh = mesh_lib.mesh_for_nodes(n_padded)
     if mesh is not None:
-        cols = list(zip(*(r[:10] for r in rows)))
-        stacked = [jnp.stack(col) for col in cols]
-        stacked, active, penalties = mesh_lib.shard_greedy_batch_args(
-            mesh, stacked, active, penalties
-        )
-        idxs, oks, _scores = solve_greedy_batched(
-            *stacked, active, penalties, k, jd, td
-        )
-        return idxs, oks
-    total, sched_cap, bw_avail = rows[0][0], rows[0][1], rows[0][5]
-    per_eval = {
-        i: jnp.stack([r[i] for r in rows]) for i in (2, 3, 4, 6, 7, 8, 9)
-    }
-    idxs, oks, _scores = solve_greedy_batched_shared(
-        total, sched_cap, per_eval[2], per_eval[3], per_eval[4],
-        bw_avail, per_eval[6], per_eval[7], per_eval[8], per_eval[9],
-        active, penalties, k, jd, td,
-    )
-    return idxs, oks
+        rows10 = [mesh_lib.shard_waterfill_args(mesh, r) for r in rows10]
+    rows10 = tuple(rows10) + (rows10[0],) * pad
+    if kind == "exact":
+        path = "exact"
+        out = solve_greedy_rows(
+            tuple(rows10[0][i] for i in _SHARED_COLS),
+            tuple(tuple(r[i] for i in _EVAL_COLS) for r in rows10),
+            counts, penalties, k, jd, td, mesh)
+    else:
+        path = ("pallas" if mesh is None and pallas_solve.selected(n_padded)
+                else "jnp")
+        out = solve_waterfill_rows(
+            rows10, counts, penalties, jd, td, path, mesh)
+    return (*out, path, mesh is None)
 
 
 # Process-wide engine shared by all workers (like GLOBAL_MIRROR_CACHE).
@@ -774,32 +717,35 @@ import atexit  # noqa: E402  (intentionally after module definitions)
 atexit.register(quiesce_all, 2.0)
 
 
-def warm_batch_shapes(n_padded: int, buckets=(1, 2, 4, 8), stop=None) -> int:
-    """Pre-compile the water-fill for each eval-axis bucket at one
-    node-axis bucket. Dispatch chunking caps real batches at
-    MAX_BATCH_BUCKET, so the default buckets are the ENTIRE steady-state
-    compile surface — and both paths run through the coalescer's own code
-    (_solve_one / _stack_and_solve), so warm shapes can't drift from real
-    dispatch shapes. Values are no-op solves (count 0). Returns the
-    number of dispatches issued."""
+
+
+def _noop_row(n_padded: int):
+    """One entry's args at one node-axis bucket whose solve is a no-op
+    (count 0): what the warm calls stack."""
     zero4 = jnp.zeros((n_padded, 4), dtype=jnp.int32)
     zcap = jnp.zeros((n_padded, 2), dtype=jnp.float32)
     zvec = jnp.zeros((n_padded,), dtype=jnp.int32)
     elig = jnp.zeros((n_padded,), dtype=bool)
-    args = (zero4, zcap, zero4, zvec, zvec, zvec, zvec, elig,
+    return (zero4, zcap, zero4, zvec, zvec, zvec, zvec, elig,
             jnp.zeros((4,), dtype=jnp.int32), jnp.int32(0),
             0, 0.0, False, False)
+
+
+def warm_batch_shapes(n_padded: int, buckets=(1, 2, 4, 8), stop=None) -> int:
+    """Pre-compile the water-fill for each eval-axis bucket at one
+    node-axis bucket. Dispatch chunking caps real batches at
+    MAX_BATCH_BUCKET, so the default buckets are the ENTIRE steady-state
+    compile surface — and they run through the dispatcher's own launch
+    (_launch_rows), so warm shapes can't drift from real dispatch shapes.
+    Returns the number of dispatches issued."""
+    args = _noop_row(n_padded)
     done = 0
     with device_activity():
         for b in buckets:
             if stop is not None and stop():
                 return done
-            if b == 1:
-                (counts_dev, _rem), _path = CoalescingSolver._solve_one(
-                    _Entry(args))
-            else:
-                counts_dev, _rem, _path = _stack_and_solve(
-                    [args] * b, False, False)
+            counts_dev, _rem, _path, _single = _launch_rows(
+                [args] * b, "wf", 0, False, False)
             jax.block_until_ready(counts_dev)
             done += 1
     return done
@@ -813,27 +759,18 @@ def warm_exact_batch_shapes(n_padded: int, counts=(8, 16, 32, 64, 128),
     warm_shapes' real solve_group dispatches; the widths here are the
     coalesced ones a burst's first drain would otherwise compile
     in-window (blamed, correctly, on bucket_crossing by the compile-
-    attribution ring). Runs through _stack_and_solve_exact — the SAME
-    stacking real dispatches use — so warm shapes can't drift. Returns
-    the number of dispatches issued."""
-    from nomad_tpu.ops.binpack import bucket
-
-    zero4 = jnp.zeros((n_padded, 4), dtype=jnp.int32)
-    zcap = jnp.zeros((n_padded, 2), dtype=jnp.float32)
-    zvec = jnp.zeros((n_padded,), dtype=jnp.int32)
-    elig = jnp.zeros((n_padded,), dtype=bool)
-    args = (zero4, zcap, zero4, zvec, zvec, zvec, zvec, elig,
-            jnp.zeros((4,), dtype=jnp.int32), jnp.int32(0),
-            0, 0.0, False, False)
+    attribution ring). Runs through _launch_rows — the SAME launch real
+    dispatches use — so warm shapes can't drift. Returns the number of
+    dispatches issued."""
+    args = _noop_row(n_padded)
     done = 0
     with device_activity():
         for k in sorted({bucket(c) for c in counts}):
             for b in buckets:
                 if stop is not None and stop():
                     return done
-                idxs_dev, _oks = _stack_and_solve_exact(
-                    [args] * b, k, False, False
-                )
+                idxs_dev, _oks, _path, _single = _launch_rows(
+                    [args] * b, "exact", k, False, False)
                 jax.block_until_ready(idxs_dev)
                 done += 1
     return done

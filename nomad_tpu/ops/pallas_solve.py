@@ -17,9 +17,11 @@ VMEM-resident program per eval:
   SMEM operands indexed by ``pl.program_id(0)``: a (1, ·) SMEM block over
   the eval axis does not lower for B > 1.
 
-The batched variant grids over the eval axis — each program solves one
-eval of the coalesced batch (ops/coalesce.py), so K in-flight evals still
-cost one dispatch.
+The kernel grids over the eval axis — each grid step solves one eval of
+the coalesced batch, so K in-flight evals still cost one dispatch. There
+is no single-eval wrapper: the coalescer's jitted entry
+(ops/coalesce.py solve_waterfill_rows) stacks the riders' rows and traces
+this kernel inside the same program, a lone eval being B = 1.
 
 Semantics are those of solve_waterfill (differential-tested in interpret
 mode in tests/test_pallas_solve.py, lowered for TPU without a device in
@@ -225,9 +227,10 @@ def solve_waterfill_pallas_batched(
     tg_distinct: bool,
     interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Batched water-fill, one grid step per eval. Same contract as
-    coalesce.solve_waterfill_batched: returns (counts [B, N], remaining
-    [B])."""
+    """Batched water-fill, one grid step per eval: returns (counts
+    [B, N], remaining [B]). Traced inside coalesce.solve_waterfill_rows,
+    which stacks the riders' rows (a lone eval is B = 1) in the same
+    program; nothing calls it eagerly on the dispatch path."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -289,23 +292,6 @@ def solve_waterfill_pallas_batched(
     )
     counts = counts.reshape(b, n_pad)[:, :n]
     return counts, count.astype(jnp.int32) - counts.sum(axis=-1)
-
-
-def solve_waterfill_pallas(
-    total, sched_cap, used0, job_count0, tg_count0, bw_avail, bw_used0,
-    eligible, ask, bw_ask, count, penalty,
-    job_distinct: bool, tg_distinct: bool, interpret: bool = False,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Single-eval wrapper: same contract as binpack.solve_waterfill."""
-    counts, remaining = solve_waterfill_pallas_batched(
-        total[None], sched_cap[None], used0[None], job_count0[None],
-        tg_count0[None], bw_avail[None], bw_used0[None], eligible[None],
-        jnp.asarray(ask)[None], jnp.asarray(bw_ask).reshape(1),
-        jnp.asarray(count, dtype=jnp.int32).reshape(1),
-        jnp.asarray(penalty, dtype=jnp.float32).reshape(1),
-        job_distinct, tg_distinct, interpret=interpret,
-    )
-    return counts[0], remaining[0]
 
 
 def selected(n_padded: int) -> bool:

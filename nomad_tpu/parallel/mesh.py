@@ -155,7 +155,7 @@ def put_node_sharded(x, trailing_dims: int = 0):
 # Water-fill argument shardings, in solve_waterfill positional order:
 # total[N,4], sched_cap[N,2], used0[N,4], job_count0[N], tg_count0[N],
 # bw_avail[N], bw_used0[N], eligible[N], ask[D], bw_ask[].
-_WF_SPECS = (
+WF_SPECS = (
     P(NODE_AXIS, None), P(NODE_AXIS, None), P(NODE_AXIS, None),
     P(NODE_AXIS), P(NODE_AXIS), P(NODE_AXIS), P(NODE_AXIS), P(NODE_AXIS),
     P(), P(),
@@ -184,7 +184,7 @@ def shard_waterfill_args(mesh: Mesh, args10) -> tuple:
     the wrong sharding is counted in STATS["node_reshards"] — the guardrail
     tests hold that at zero on the warm path."""
     out = []
-    for x, spec in zip(args10, _WF_SPECS):
+    for x, spec in zip(args10, WF_SPECS):
         target = NamedSharding(mesh, spec)
         if isinstance(x, jax.Array) and x.sharding == target:
             out.append(x)
@@ -197,51 +197,19 @@ def shard_waterfill_args(mesh: Mesh, args10) -> tuple:
     return tuple(out)
 
 
-def shard_waterfill_batch_args(mesh: Mesh, stacked10, counts, penalties):
-    """Batched (eval-stacked) variant: [B, ...] tensors, node axis sharded,
-    eval axis over EVAL_AXIS when the mesh has one."""
-    b = stacked10[0].shape[0]
+def constrain_eval_stack(mesh: Mesh, stacked, specs) -> tuple:
+    """Inside a jitted batched solve (ops/coalesce.py solve_*_rows): pin
+    the eval-stacked [B, ...] tensors to their rows' shardings (``specs``,
+    of WF_SPECS) with the eval axis over EVAL_AXIS when the mesh has one
+    that divides B. The rows arrive node-sharded (shard_waterfill_args);
+    this keeps the stack from gathering them."""
+    b = stacked[0].shape[0]
     eval_axis = EVAL_AXIS if b % mesh.shape[EVAL_AXIS] == 0 else None
-    specs = tuple(
-        P(eval_axis, *spec) for spec in (
-            (NODE_AXIS, None), (NODE_AXIS, None), (NODE_AXIS, None),
-            (NODE_AXIS,), (NODE_AXIS,), (NODE_AXIS,), (NODE_AXIS,),
-            (NODE_AXIS,), (None,), (),
-        )
+    return tuple(
+        jax.lax.with_sharding_constraint(
+            x, NamedSharding(mesh, P(eval_axis, *spec)))
+        for x, spec in zip(stacked, specs)
     )
-    placed = tuple(
-        jax.device_put(x, NamedSharding(mesh, spec))
-        for x, spec in zip(stacked10, specs)
-    )
-    vec = NamedSharding(mesh, P(eval_axis))
-    return placed, jax.device_put(counts, vec), jax.device_put(penalties, vec)
-
-
-def shard_greedy_batch_args(mesh: Mesh, stacked10, active, penalties):
-    """Batched EXACT-scan variant (solve_greedy_batched): the same
-    [B, ...] node-axis shardings as the water-fill stack, plus the
-    [B, k] active masks (replicated over the node axis) and the [B]
-    penalties."""
-    b = stacked10[0].shape[0]
-    eval_axis = EVAL_AXIS if b % mesh.shape[EVAL_AXIS] == 0 else None
-    specs = tuple(
-        P(eval_axis, *spec) for spec in (
-            (NODE_AXIS, None), (NODE_AXIS, None), (NODE_AXIS, None),
-            (NODE_AXIS,), (NODE_AXIS,), (NODE_AXIS,), (NODE_AXIS,),
-            (NODE_AXIS,), (None,), (),
-        )
-    )
-    placed = tuple(
-        jax.device_put(x, NamedSharding(mesh, spec))
-        for x, spec in zip(stacked10, specs)
-    )
-    active = jax.device_put(
-        active, NamedSharding(mesh, P(eval_axis, None))
-    )
-    penalties = jax.device_put(
-        penalties, NamedSharding(mesh, P(eval_axis))
-    )
-    return placed, active, penalties
 
 
 # Per-mesh jit cache for node-sharded helper programs (the mirror's

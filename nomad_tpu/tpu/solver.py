@@ -186,6 +186,10 @@ class SolverPanel:
         # shared dispatch wall divided by N is the per-eval cost the
         # batching win shows up in.
         self._batch_widths: Dict[int, List[float]] = {}
+        # Coalescer dispatches that were ONE call into the device runtime
+        # (ops/coalesce.py _launch_rows): beside the coalescer's own
+        # ``dispatches`` it says how often the one-program launch engages.
+        self.single_program_dispatches = 0
         # Equivalence classes (Borg §'equivalence class'): identical
         # task groups of one job collapsed to one solve row with a
         # multiplicity count. rows_saved = solves that never dispatched.
@@ -282,6 +286,12 @@ class SolverPanel:
             row[1] += width
             row[2] += wall_ms
 
+    def record_single_program_dispatch(self) -> None:
+        """One coalescer dispatch that went out as exactly one call into
+        the device runtime (not a per-entry retry, not the mesh branch)."""
+        with self._lock:
+            self.single_program_dispatches += 1
+
     def record_staging(self, wall_ms: float, cpu_ms: float) -> None:
         with self._lock:
             self.staging_wall_ms += wall_ms
@@ -371,6 +381,7 @@ class SolverPanel:
                     d for d, _e, _m in self._batch_widths.values()),
                 "batch_evals": sum(
                     e for _d, e, _m in self._batch_widths.values()),
+                "single_program_dispatches": self.single_program_dispatches,
                 "staging_wall_ms": round(self.staging_wall_ms, 3),
                 "staging_cpu_ms": round(self.staging_cpu_ms, 3),
                 "staging_blocked_ms": round(max(

@@ -37,10 +37,12 @@ running and waiting cannot be told apart otherwise:
                                 first fetcher's device wait (ops/coalesce)
 - ``solver.execute.hold``       the rider starts to wait -> its batch taken
                                 off the pending list (ops/coalesce)
-- ``solver.execute.launch``     taken -> the jit call returned: stacking,
-                                launch, any compile; ``width``, ``kind``,
-                                ``path``, ``cpu_ms`` of the dispatcher thread
-                                (ops/coalesce)
+- ``solver.execute.launch``     taken -> the dispatch's ONE jit call returned
+                                (grouping, the two host arrays of counts and
+                                penalties, the call, any compile; stacking
+                                and masks run inside the program); ``width``,
+                                ``kind``, ``path``, ``cpu_ms`` of the
+                                dispatcher thread (ops/coalesce)
 - ``solver.execute.wake``       event set -> the rider runs again (ops/coalesce)
 - ``solver.execute.device_wait``  block_until_ready of the first fetcher
                                 (ops/coalesce)

@@ -15,8 +15,8 @@ from nomad_tpu.ops.coalesce import CoalescingSolver
 N = 64
 
 
-def _inputs(ask_cpu, count):
-    total = np.zeros((N, 4), dtype=np.int32)
+def _inputs(ask_cpu, count, n=N):
+    total = np.zeros((n, 4), dtype=np.int32)
     total[:, 0] = 4000
     total[:, 1] = 8192
     total[:, 2] = 100 * 1024
@@ -24,12 +24,12 @@ def _inputs(ask_cpu, count):
     return dict(
         total=jnp.asarray(total),
         sched_cap=jnp.asarray(total[:, :2].astype(np.float32)),
-        used0=jnp.zeros((N, 4), dtype=jnp.int32),
-        job_count0=jnp.zeros((N,), dtype=jnp.int32),
-        tg_count0=jnp.zeros((N,), dtype=jnp.int32),
-        bw_avail=jnp.full((N,), 1000, dtype=jnp.int32),
-        bw_used0=jnp.zeros((N,), dtype=jnp.int32),
-        eligible=jnp.ones((N,), dtype=bool),
+        used0=jnp.zeros((n, 4), dtype=jnp.int32),
+        job_count0=jnp.zeros((n,), dtype=jnp.int32),
+        tg_count0=jnp.zeros((n,), dtype=jnp.int32),
+        bw_avail=jnp.full((n,), 1000, dtype=jnp.int32),
+        bw_used0=jnp.zeros((n,), dtype=jnp.int32),
+        eligible=jnp.ones((n,), dtype=bool),
         ask=jnp.array([ask_cpu, 128, 0, 0], dtype=jnp.int32),
         bw_ask=jnp.int32(0),
         count=count,
@@ -131,7 +131,8 @@ def test_mixed_shapes_group_separately():
     assert int(cb.sum()) + ub == 50
 
 
-def _entries(inputs):
+def _entries(inputs, kind="wf"):
+    from nomad_tpu.ops.binpack import bucket
     from nomad_tpu.ops.coalesce import _Entry
 
     return [
@@ -140,26 +141,42 @@ def _entries(inputs):
             inp["tg_count0"], inp["bw_avail"], inp["bw_used0"],
             inp["eligible"], inp["ask"], inp["bw_ask"], inp["count"],
             inp["penalty"], False, False,
-        ))
+        ), kind=kind, k=bucket(inp["count"]) if kind == "exact" else 0)
         for inp in inputs
     ]
 
 
+def _panel_single():
+    from nomad_tpu.tpu.solver import SOLVER_PANEL
+
+    return SOLVER_PANEL.snapshot()["single_program_dispatches"]
+
+
 def test_batch_failure_falls_open_to_individual_solves(monkeypatch):
-    """A batch-level dispatch error retries each entry individually; the
-    fallback results carry the leading batch axis so fetch() returns the
-    full [N] counts vector, matching the direct solve."""
+    """A batch-level dispatch error retries each entry individually — the
+    same entry at B = 1 — and fetch() returns the full [N] counts vector,
+    matching the direct solve. The failed chunk is a dispatch that did
+    not go out as one call: the single-program counter falls behind."""
     from nomad_tpu.ops import coalesce
 
     engine = CoalescingSolver()
     inputs = [_inputs(100, 100), _inputs(120, 200)]
     entries = _entries(inputs)
+    real = coalesce.solve_waterfill_rows
+    widths = []
 
-    def boom(*args, **kwargs):
-        raise RuntimeError("batched program failed")
+    def stacked_fails(rows, *args, **kwargs):
+        widths.append(len(rows))
+        if len(rows) > 1:
+            raise RuntimeError("batched program failed")
+        return real(rows, *args, **kwargs)
 
-    monkeypatch.setattr(coalesce, "solve_waterfill_batched", boom)
+    monkeypatch.setattr(coalesce, "solve_waterfill_rows", stacked_fails)
+    single0 = _panel_single()
     engine._dispatch(entries)
+    assert widths == [2, 1, 1]
+    assert (engine.dispatches, engine.batch_retries) == (1, 1)
+    assert _panel_single() == single0
     for entry, inp in zip(entries, inputs):
         counts, unplaced = entry.result()
         d_counts, d_unplaced = _direct(inp)
@@ -179,8 +196,7 @@ def test_total_failure_raises_instead_of_hanging(monkeypatch):
     def boom(*args, **kwargs):
         raise ValueError("device is gone")
 
-    monkeypatch.setattr(coalesce, "solve_waterfill_batched", boom)
-    monkeypatch.setattr(coalesce, "solve_waterfill", boom)
+    monkeypatch.setattr(coalesce, "solve_waterfill_rows", boom)
     fetches = [
         _submit(engine, _inputs(100, 100)), _submit(engine, _inputs(120, 200))
     ]
@@ -355,7 +371,7 @@ def _direct_exact(inp):
 
 def test_exact_submissions_stack_into_one_dispatch():
     """Announced-burst exact solves of one (node, count-bucket) shape
-    stack into ONE solve_greedy_batched dispatch, each row bit-equal to
+    stack into ONE solve_greedy_rows dispatch, each row bit-equal to
     its lone dispatch; the solver panel's batch-width axis records the
     stacked width."""
     from nomad_tpu.tpu.solver import SOLVER_PANEL
@@ -401,21 +417,10 @@ def test_exact_submissions_stack_into_one_dispatch():
 def test_exact_and_waterfill_entries_never_share_a_dispatch():
     """Mixed-kind pending entries group by program family: a wf entry
     and an exact entry in one drain dispatch separately, both correct."""
-    from nomad_tpu.ops.coalesce import _Entry
-
     engine = CoalescingSolver()
     wf_inp = _inputs(100, 300)
     ex_inp = _inputs(80, 50)
-    entries = _entries([wf_inp])
-    from nomad_tpu.ops.binpack import bucket
-
-    entries.append(_Entry((
-        ex_inp["total"], ex_inp["sched_cap"], ex_inp["used0"],
-        ex_inp["job_count0"], ex_inp["tg_count0"], ex_inp["bw_avail"],
-        ex_inp["bw_used0"], ex_inp["eligible"], ex_inp["ask"],
-        ex_inp["bw_ask"], ex_inp["count"], ex_inp["penalty"],
-        False, False,
-    ), kind="exact", k=bucket(ex_inp["count"])))
+    entries = _entries([wf_inp]) + _entries([ex_inp], "exact")
     d0 = engine.dispatches
     engine._dispatch(entries)
     assert engine.dispatches == d0 + 2
@@ -462,3 +467,122 @@ def test_burst_generation_scopes_accounting():
         engine.burst_done()
     with engine._lock:
         assert engine._burst_outstanding == 0
+
+
+# -- one program a dispatch (ISSUE 34) ----------------------------------------
+
+
+def _family_entries(family, n, width, salt):
+    """``width`` entries of one dispatch group on an n-row bucket: asks,
+    counts and penalties differ by entry and by ``salt``; the node
+    tensors are ONE set (exact entries stack by mirror identity)."""
+    base = _inputs(0, 0, n)
+    base["eligible"] = jnp.asarray(np.arange(n) % 7 != 3)
+    inputs = []
+    for i in range(width):
+        inp = dict(base)
+        inp["ask"] = jnp.array([60 + 10 * i + salt, 128, 0, 0],
+                               dtype=jnp.int32)
+        # exact: 33..64 share the 64 bucket; wf: any count.
+        inp["count"] = (33 + 3 * i + salt if family == "exact"
+                        else 300 + 41 * i + 7 * salt)
+        inp["penalty"] = 10.0 if (i + salt) % 2 else 5.0
+        inputs.append(inp)
+    return inputs, _entries(inputs, family)
+
+
+def _assert_bit_equal(family, inputs, entries):
+    for inp, e in zip(inputs, entries):
+        if family == "exact":
+            idxs, oks = e.result()
+            d_idxs, d_oks = _direct_exact(inp)
+            np.testing.assert_array_equal(idxs[: inp["count"]], d_idxs)
+            np.testing.assert_array_equal(oks[: inp["count"]], d_oks)
+            assert not oks[inp["count"]:].any()
+        else:
+            counts, unplaced = e.result()
+            d_counts, d_unplaced = _direct(inp)
+            np.testing.assert_array_equal(counts, d_counts)
+            assert unplaced == d_unplaced
+
+
+@pytest.mark.parametrize("width", [1, 2, 3, 8])
+@pytest.mark.parametrize("family", ["wf", "exact"])
+def test_rows_entry_is_bit_equal_to_lone_solves(family, width):
+    """Every width of both families through the one launch: each rider's
+    counts / remaining (indices / oks) are those of its lone
+    solve_waterfill (solve_greedy), padding rows included."""
+    engine = CoalescingSolver()
+    inputs, entries = _family_entries(family, N, width, salt=0)
+    engine._dispatch(entries)
+    assert engine.dispatches == 1
+    assert entries[0].group.width == width
+    assert engine.paths == {"exact" if family == "exact" else "jnp": 1}
+    _assert_bit_equal(family, inputs, entries)
+
+
+# One node bucket a width that no other test solves on: a program first
+# met here compiles here.
+_FRESH_BUCKETS = {1: 2048, 3: 4096, 8: 8192}
+
+
+@pytest.mark.parametrize("width", [1, 3, 8])
+@pytest.mark.parametrize("family", ["wf", "exact"])
+def test_dispatch_is_one_program(family, width):
+    """The first dispatch of a shape compiles exactly ONE program (an
+    eager op beside the solve, on a new shape, would compile too), the
+    second none, and another count and penalty on the same shapes none
+    (they ride as typed host arrays, not as constants); every such
+    dispatch counts as a single-program dispatch."""
+    from nomad_tpu.scheduler import acquire_device
+    from nomad_tpu.tpu.solver import SOLVER_PANEL
+
+    acquire_device()   # registers the listener for XLA's compile events
+    n = _FRESH_BUCKETS[width]
+    engine = CoalescingSolver()
+    batches = [_family_entries(family, n, width, salt)
+               for salt in (0, 0, 5)]
+    single0 = _panel_single()
+    compiles = [SOLVER_PANEL.snapshot()["xla_compiles"]]
+    for _inp, entries in batches:
+        engine._dispatch(entries)
+        for e in entries:
+            e.result()
+        compiles.append(SOLVER_PANEL.snapshot()["xla_compiles"])
+    assert [b - a for a, b in zip(compiles, compiles[1:])] == [1, 0, 0]
+    assert engine.dispatches == 3
+    assert _panel_single() - single0 == 3
+    assert engine.batch_retries == 0
+    for inputs, entries in batches:
+        _assert_bit_equal(family, inputs, entries)
+
+
+@pytest.mark.parametrize("family", ["wf", "exact"])
+def test_warmed_set_is_the_dispatched_set(family):
+    """The warm calls and the dispatcher share one launch: after a
+    bucket is warmed, no real dispatch of any width compiles."""
+    from nomad_tpu.ops.coalesce import (
+        warm_batch_shapes,
+        warm_exact_batch_shapes,
+    )
+    from nomad_tpu.scheduler import acquire_device
+    from nomad_tpu.tpu.solver import SOLVER_PANEL
+
+    acquire_device()
+    n = 512 if family == "wf" else 1024   # fresh buckets again
+    if family == "wf":
+        assert warm_batch_shapes(n) == 4
+    else:   # counts 33..64 -> the 64 bucket, at every width
+        assert warm_exact_batch_shapes(
+            n, counts=(64,), buckets=(1, 2, 4, 8)) == 4
+    engine = CoalescingSolver()
+    batches = [_family_entries(family, n, width, salt=width)
+               for width in (1, 2, 3, 5, 8)]
+    compiled0 = SOLVER_PANEL.snapshot()["xla_compiles"]
+    for _inp, entries in batches:
+        engine._dispatch(entries)
+        for e in entries:
+            e.result()
+    assert SOLVER_PANEL.snapshot()["xla_compiles"] == compiled0
+    for inputs, entries in batches:
+        _assert_bit_equal(family, inputs, entries)

@@ -96,6 +96,35 @@ def test_coalesced_batch_dispatch_on_mesh(node_mesh):
         assert unplaced == d_unplaced
 
 
+@pytest.mark.parametrize("family", ["wf", "exact"])
+def test_stacked_dispatch_on_an_eval_by_node_mesh(family):
+    """A (2 evals x 4 nodes) mesh: the rows are placed node-sharded in
+    front of the same jitted entries, the stack inside the program rides
+    the eval axis (mesh.constrain_eval_stack), and every rider's result
+    is its lone solve's. The mesh branch is not a single-program
+    dispatch: the counter stays where it was."""
+    from nomad_tpu.ops.coalesce import CoalescingSolver
+    from test_coalesce import (
+        _assert_bit_equal,
+        _family_entries,
+        _panel_single,
+    )
+
+    mesh_lib.configure_node_sharding(8, eval_parallel=2)
+    try:
+        engine = CoalescingSolver()
+        inputs, entries = _family_entries(family, 64, 4, salt=1)
+        single0 = _panel_single()
+        engine._dispatch(entries)
+        assert (engine.dispatches, engine.batch_retries) == (1, 0)
+        assert _panel_single() == single0
+        a_dev = entries[0].group.counts_dev
+        assert len(a_dev.sharding.device_set) == 8
+        _assert_bit_equal(family, inputs, entries)
+    finally:
+        mesh_lib.clear_node_sharding()
+
+
 def _run_big_service_eval(factory):
     """A 32-node cluster and a count=300 service job: count > the exact
     threshold, so the TPU path runs the water-fill production kernel."""

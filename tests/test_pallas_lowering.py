@@ -1,4 +1,4 @@
-"""Deviceless TPU lowering of the batched Pallas water-fill.
+"""Deviceless TPU lowering of the water-fill dispatch on the Pallas kernel.
 
 ``jax.export`` with ``platforms=["tpu"]`` runs the Pallas TPU lowering
 rules — BlockSpec checks, memory spaces, every primitive's Mosaic rule —
@@ -6,30 +6,32 @@ without a device, so a kernel the installed JAX refuses is caught here on
 the CPU-pinned suite. Interpret mode skips all of that: it accepted the
 per-eval ``(1, ·)`` SMEM blocks that do not lower for B > 1. Whether the
 Mosaic COMPILER then accepts the module only a chip can say: the
-benchmark's drain cells run the kernel there (``benchmark/run.py``;
-``jit_solve_waterfill_pallas_batched`` in the ledger's ``device_ops``),
-with ``correct`` decided against ``benchmark/reference.py``.
+benchmark's water-fill cells run the kernel there (``benchmark/run.py``;
+``jit_solve_waterfill_rows`` in the ledger's ``device_ops``), with
+``correct`` decided against ``benchmark/reference.py``. What is lowered
+is the program the coalescer dispatches (``solve_waterfill_rows``: the
+riders' rows as they are, stacked inside it), not the kernel alone.
 """
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from nomad_tpu.ops.coalesce import MAX_BATCH_BUCKET
-from nomad_tpu.ops.pallas_solve import solve_waterfill_pallas_batched
+from nomad_tpu.ops.coalesce import MAX_BATCH_BUCKET, solve_waterfill_rows
 
 NODE_BUCKET = 16384  # cell-10k: bucket(10_000)
 
 
 def _arg_shapes(b, n, d=4):
+    """(rows, counts, penalties) as a dispatch of width b hands them in."""
     S = jax.ShapeDtypeStruct
     i32, f32 = jnp.int32, jnp.float32
-    return (
-        S((b, n, d), i32), S((b, n, 2), f32), S((b, n, d), i32),
-        S((b, n), i32), S((b, n), i32), S((b, n), i32), S((b, n), i32),
-        S((b, n), jnp.bool_), S((b, d), i32), S((b,), i32), S((b,), i32),
-        S((b,), f32),
+    row = (
+        S((n, d), i32), S((n, 2), f32), S((n, d), i32),
+        S((n,), i32), S((n,), i32), S((n,), i32), S((n,), i32),
+        S((n,), jnp.bool_), S((d,), i32), S((), i32),
     )
+    return (row,) * b, S((b,), i32), S((b,), f32)
 
 
 @pytest.mark.parametrize("flags", [(False, False), (True, False),
@@ -40,8 +42,9 @@ def test_kernel_lowers_for_tpu_at_every_coalesced_width(width, flags):
     assert width <= MAX_BATCH_BUCKET
     jd, td = flags
 
-    def solve(*args):
-        return solve_waterfill_pallas_batched(*args, jd, td)
+    def solve(rows, counts, penalties):
+        return solve_waterfill_rows(rows, counts, penalties, jd, td,
+                                    kernel="pallas")
 
     exported = jax.export.export(jax.jit(solve), platforms=["tpu"])(
         *_arg_shapes(width, NODE_BUCKET))

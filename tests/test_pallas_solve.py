@@ -11,13 +11,37 @@ import pytest
 
 import jax.numpy as jnp
 
+from functools import partial
+
 from nomad_tpu.ops import pallas_solve
 from nomad_tpu.ops.binpack import solve_waterfill
-from nomad_tpu.ops.coalesce import solve_waterfill_batched
-from nomad_tpu.ops.pallas_solve import (
-    solve_waterfill_pallas,
-    solve_waterfill_pallas_batched,
-)
+from nomad_tpu.ops.coalesce import solve_waterfill_rows
+from nomad_tpu.ops.pallas_solve import solve_waterfill_pallas_batched
+
+
+@pytest.fixture(autouse=True)
+def _interpreted_kernel(monkeypatch):
+    """The kernel as the coalescer's entry traces it, run by the
+    interpreter on this CPU-pinned suite. Every trace of
+    ``kernel="pallas"`` in this process is made under it: nothing else
+    selects the kernel on the CPU backend."""
+    monkeypatch.setattr(
+        pallas_solve, "solve_waterfill_pallas_batched",
+        partial(solve_waterfill_pallas_batched, interpret=True))
+
+
+def _scalars(rows):
+    return (np.asarray([int(r[10]) for r in rows], dtype=np.int32),
+            np.asarray([float(r[11]) for r in rows], dtype=np.float32))
+
+
+def solve_waterfill_pallas(*args):
+    """One eval through the coalescer's entry at B = 1 on the kernel:
+    same contract as binpack.solve_waterfill."""
+    *row, jd, td = args
+    counts, remaining = solve_waterfill_rows(
+        (tuple(row[:10]),), *_scalars([row]), jd, td, kernel="pallas")
+    return counts[0], remaining[0]
 
 
 def random_instance(rng, n, d=4):
@@ -43,7 +67,7 @@ def random_instance(rng, n, d=4):
 
 def assert_match(args, jd, td):
     c0, r0 = solve_waterfill(*args, jd, td)
-    c1, r1 = solve_waterfill_pallas(*args, jd, td, interpret=True)
+    c1, r1 = solve_waterfill_pallas(*args, jd, td)
     np.testing.assert_array_equal(np.asarray(c0), np.asarray(c1))
     assert int(r0) == int(r1)
 
@@ -89,41 +113,36 @@ def test_tie_break_matches_stable_argsort():
         jnp.int32(0), jnp.int32(7), jnp.float32(0.0),
     )
     c0, r0 = solve_waterfill(*args, False, False)
-    c1, r1 = solve_waterfill_pallas(*args, False, False, interpret=True)
+    c1, r1 = solve_waterfill_pallas(*args, False, False)
     np.testing.assert_array_equal(np.asarray(c0), np.asarray(c1))
     assert int(np.asarray(c1).sum()) == 7
     assert np.asarray(c1)[:7].sum() == 7  # lowest indices won the tie
 
 
-def test_batched_matches_vmapped():
+@pytest.mark.parametrize("width", [2, 3, 8])
+def test_batched_matches_vmapped(width):
+    """The two water-fills behind the one entry agree row for row at
+    every stacked width (width 1 is every test above)."""
     rng = np.random.default_rng(11)
-    rows = [random_instance(rng, 64) for _ in range(3)]
-    # Pad to a uniform batch the way the coalescer stacks entries.
-    cols = list(zip(*(r[:10] for r in rows)))
-    stacked = [jnp.stack(c) for c in cols]
-    counts = jnp.asarray([int(r[10]) for r in rows], dtype=jnp.int32)
-    pens = jnp.asarray([float(r[11]) for r in rows], dtype=jnp.float32)
-    c0, r0 = solve_waterfill_batched(*stacked, counts, pens, False, False)
-    c1, r1 = solve_waterfill_pallas_batched(
-        *stacked, counts, pens, False, False, interpret=True
-    )
+    rows = [random_instance(rng, 64) for _ in range(width)]
+    rows10 = tuple(r[:10] for r in rows)
+    counts, pens = _scalars(rows)
+    c0, r0 = solve_waterfill_rows(rows10, counts, pens, False, False)
+    c1, r1 = solve_waterfill_rows(
+        rows10, counts, pens, False, False, kernel="pallas")
     np.testing.assert_array_equal(np.asarray(c0), np.asarray(c1))
     np.testing.assert_array_equal(np.asarray(r0), np.asarray(r1))
+    for i, r in enumerate(rows):
+        c, rem = solve_waterfill(*r, False, False)
+        np.testing.assert_array_equal(np.asarray(c), np.asarray(c1[i]))
+        assert int(rem) == int(r1[i])
 
 
 def _select_interpreted_kernel(monkeypatch):
     """Make the coalescer's selection pick the kernel on this CPU-pinned
-    suite, run by the interpreter: the selection itself reads only the
-    backend and the node bucket (pallas_solve.selected)."""
-    from functools import partial
-
+    suite: the selection itself reads only the backend and the node
+    bucket (pallas_solve.selected)."""
     monkeypatch.setattr(pallas_solve, "selected", lambda n: True)
-    monkeypatch.setattr(
-        pallas_solve, "solve_waterfill_pallas",
-        partial(solve_waterfill_pallas, interpret=True))
-    monkeypatch.setattr(
-        pallas_solve, "solve_waterfill_pallas_batched",
-        partial(solve_waterfill_pallas_batched, interpret=True))
 
 
 def test_coalescer_dispatches_selected_kernel(monkeypatch):
@@ -145,8 +164,9 @@ def test_coalescer_dispatches_selected_kernel(monkeypatch):
 
 def test_coalescer_stacks_selected_kernel(monkeypatch):
     """Width > 1 rides the batched kernel (the dispatch the (1, .) SMEM
-    blocks could not lower for)."""
-    from nomad_tpu.ops.coalesce import _stack_and_solve
+    blocks could not lower for), through the launch the dispatcher and
+    the warm calls share."""
+    from nomad_tpu.ops.coalesce import _launch_rows
 
     _select_interpreted_kernel(monkeypatch)
     rng = np.random.default_rng(13)
@@ -154,9 +174,11 @@ def test_coalescer_stacks_selected_kernel(monkeypatch):
     entries = [
         (*r[:10], int(r[10]), float(r[11]), False, False) for r in rows
     ]
-    counts, remaining, path = _stack_and_solve(entries, False, False)
-    assert path == "pallas"
+    counts, remaining, path, single = _launch_rows(
+        entries, "wf", 0, False, False)
+    assert path == "pallas" and single
     assert counts.shape == (4, 64)  # padded to the width bucket
+    assert int(counts[3].sum()) == 0  # the padding row: count 0
     for i, r in enumerate(rows):
         c0, r0 = solve_waterfill(*r, False, False)
         np.testing.assert_array_equal(np.asarray(c0), np.asarray(counts[i]))
@@ -180,10 +202,12 @@ def test_kernel_failure_propagates_and_flips_no_latch(monkeypatch):
     def never(*a, **k):
         raise AssertionError("a kernel failure selected the jnp path")
 
-    monkeypatch.setattr(pallas_solve, "solve_waterfill_pallas", boom)
+    monkeypatch.setattr(pallas_solve, "solve_waterfill_pallas_batched", boom)
     monkeypatch.setattr(coalesce, "solve_waterfill", never)
     rng = np.random.default_rng(14)
-    args = random_instance(rng, 64)
+    # A node count no other test traces the kernel at: the entry meets
+    # the failing kernel while tracing, as a compile failure would be met.
+    args = random_instance(rng, 96)
     solver = CoalescingSolver()
     for attempt in (1, 2):
         fetch = solver.submit(*args[:10], int(args[10]), float(args[11]))
@@ -193,6 +217,7 @@ def test_kernel_failure_propagates_and_flips_no_latch(monkeypatch):
         # dispatch + its one-at-a-time retry, both through the kernel
         assert calls["kernel"] == 2 * attempt
     assert solver.batch_retries == 2
+    assert solver.paths == {}
 
 
 def test_selection_reads_backend_and_bucket(monkeypatch):
@@ -228,7 +253,7 @@ def test_fuzz_pallas_vs_waterfill(seed):
         jnp.int32(s["count"]), jnp.float32(s["penalty"]),
     )
     c0, r0 = solve_waterfill(*args, s["jd"], s["td"])
-    c1, r1 = solve_waterfill_pallas(*args, s["jd"], s["td"], interpret=True)
+    c1, r1 = solve_waterfill_pallas(*args, s["jd"], s["td"])
     np.testing.assert_array_equal(
         np.asarray(c0), np.asarray(c1),
         err_msg=f"pallas != waterfill (seed {seed})",
