@@ -3,7 +3,8 @@
 The benchmark's own copy of what it needs from nomad_tpu/simcluster/
 simnode.py (PERF.md lists the original for a later PR to delete): a
 fingerprint-shaped registration in batched tranches, heap-paced TTL
-heartbeat renewals, and seeded re-registrations. Only the server's
+heartbeat renewals (after one renewal of the whole fleet in set-up:
+``Fleet.renew_all``), and seeded re-registrations. Only the server's
 client-facing surface is used: ``Node.BatchRegister`` and
 ``Node.BatchHeartbeat`` over the RPC tier.
 """
@@ -109,6 +110,7 @@ class Fleet:
         self._lock = threading.Lock()
         self.granted: Dict[str, float] = {}
         self._due: List[tuple] = []
+        self._beating = threading.Lock()  # held over a pass of renewals
         self._stop = threading.Event()
         self._beater = None
         self.beat_errors = 0
@@ -143,41 +145,72 @@ class Fleet:
                 target=self._beat_loop, daemon=True, name="bench-beats")
             self._beater.start()
 
+    def renew_all(self) -> None:
+        """One renewal of every node now, in set-up, and the beat
+        schedule armed anew from what it grants. The server scales a TTL
+        by the timers it holds when it arms one, so the first nodes of a
+        fleet are granted its shortest (10-20 s where the 10,000th gets
+        200-400 s), and renewed at ``beat_fraction`` of that they have 2
+        s in hand: a host that stands still that long in a run's first
+        seconds lets them lapse, the server marks them down and places
+        their tasks again, and the run reads more placements than were
+        asked. Renewed once with the whole fleet armed, every node holds
+        the TTL of a fleet in steady state, and none falls due again
+        inside a run."""
+        with self._beating:   # no tranche is in flight
+            with self._lock:
+                ids = sorted(self.granted)
+                self._due = []
+            self._beat(ids)
+
+    def ttl_range(self) -> Tuple[float, float]:
+        """The shortest and the longest TTL now granted."""
+        with self._lock:
+            ttls = list(self.granted.values()) or [0.0]
+        return min(ttls), max(ttls)
+
     def _beat_loop(self) -> None:
         while not self._stop.wait(self.tick):
-            now = time.monotonic()
-            due: List[str] = []
-            with self._lock:
-                while self._due and self._due[0][0] <= now:
-                    due.append(heapq.heappop(self._due)[1])
-            for lo in range(0, len(due), BATCH):
-                tranche = due[lo:lo + BATCH]
-                try:
-                    out = self.call("Node.BatchHeartbeat",
-                                    {"node_ids": tranche})
-                except RPCError:
-                    # A real client keeps beating at its stale cadence
-                    # through transient failures.
-                    self.beat_errors += 1
-                    with self._lock:
-                        for nid in tranche:
-                            heapq.heappush(
-                                self._due, (now + self.tick * 2, nid))
-                    continue
-                ttls = out.get("heartbeat_ttls", {})
+            with self._beating:
+                now = time.monotonic()
+                due: List[str] = []
+                with self._lock:
+                    while self._due and self._due[0][0] <= now:
+                        due.append(heapq.heappop(self._due)[1])
+                self._beat(due)
+
+    def _beat(self, due: List[str]) -> None:
+        """Renew these nodes in tranches, and arm each one's next beat
+        at ``beat_fraction`` of the TTL it was granted."""
+        for lo in range(0, len(due), BATCH):
+            tranche = due[lo:lo + BATCH]
+            try:
+                out = self.call("Node.BatchHeartbeat",
+                                {"node_ids": tranche})
+            except RPCError:
+                # A real client keeps beating at its stale cadence
+                # through transient failures.
+                self.beat_errors += 1
                 with self._lock:
                     for nid in tranche:
-                        ttl = float(ttls.get(nid, 0.0) or 0.0)
-                        if ttl > 0:
-                            self.granted[nid] = ttl
-                        else:
-                            ttl = self.granted.get(nid, 0.0)
-                            if ttl <= 0:
-                                continue
                         heapq.heappush(
                             self._due,
-                            (time.monotonic() + self.beat_fraction * ttl,
-                             nid))
+                            (time.monotonic() + self.tick * 2, nid))
+                continue
+            ttls = out.get("heartbeat_ttls", {})
+            with self._lock:
+                for nid in tranche:
+                    ttl = float(ttls.get(nid, 0.0) or 0.0)
+                    if ttl > 0:
+                        self.granted[nid] = ttl
+                    else:
+                        ttl = self.granted.get(nid, 0.0)
+                        if ttl <= 0:
+                            continue
+                    heapq.heappush(
+                        self._due,
+                        (time.monotonic() + self.beat_fraction * ttl,
+                         nid))
 
     def stop(self) -> None:
         self._stop.set()

@@ -14,13 +14,25 @@ A mix says how jobs arrive (``arrivals``), what they are (``sizes`` and
 - ``{"process": "at_once", "jobs": n}``: n jobs offered as fast as the
   front door takes them. With ``"repeat": "when_placed"`` the next round
   is offered the moment the previous one is fully placed (a closed
-  loop) until ``--seconds`` have passed or the cell cannot hold the
-  next round whole, and the window closes with the last commit of the
-  last round offered (a plan of such a job commits thousands of
-  placements at once, so a window cut at a fixed instant would count in
-  steps of whole plans). With ``"never"`` there is one round, and the
-  window closes with its last commit where all of it is placed before
-  ``--seconds`` have passed, and after ``--seconds`` otherwise.
+  loop) until ``--seconds`` have passed, the cell cannot hold the next
+  round whole, or a round is left short, and the window closes with the
+  last commit of the last round offered (a plan of such a job commits
+  thousands of placements at once, so a window cut at a fixed instant
+  would count in steps of whole plans). With ``"never"`` there is one
+  round, and the window closes with its last commit where all of it is
+  placed, or every evaluation of it has ended, before ``--seconds`` have
+  passed, and after ``--seconds`` otherwise.
+
+The generator waits for a job only while that job can still be placed.
+A job is over when its ``Job.Register`` was refused, or when the
+evaluation the RPC answered with shows ``complete`` or ``failed`` in
+the event stream (``eval_done``). A round ends when its placements are
+committed or every one of its jobs is over; a job whose evaluation
+never shows is waited for until the grace (``ROUND_GRACE_S`` past
+``--seconds``) runs out. A closed loop whose round ends short before
+``--seconds`` have passed ends there (``round_short``): a program that
+has just left jobs unplaced is offered no further round, and every
+task asked and not placed counts as failed.
 
 What the jobs are. ``sizes`` and ``weights``: jobs of one task group of
 the configuration's ``task`` (its cpu and memory), of these sizes in the
@@ -38,10 +50,11 @@ configuration's ``task``: the same code builds both.
 
 A drain window so ends when its work ends or when ``--seconds`` have
 passed, whichever is first (``end`` in what ``play`` returns:
-``cell_full``, ``drained``, ``deadline``; ``rounds`` where a warm-up's
-``rounds`` key ends it), and the rate is what was committed in it over
-its length. What the cell can hold is reckoned from the configuration
-and the mix alone (``reference.rounds_that_fit`` over ``rounds_of``:
+``cell_full``, ``drained``, ``deadline``, ``round_short``; ``rounds``
+where a warm-up's ``rounds`` key ends it), and the rate is what was
+committed in it over its length. What the cell can hold is reckoned
+from the configuration and the mix alone
+(``reference.rounds_that_fit`` over ``rounds_of``:
 the mix's rounds replayed by first fit on the empty cell until the
 first task is left out; for tasks of one shape that is the cell's
 slots), times the mix's ``fill_limit`` (1 where it gives none), less
@@ -79,7 +92,9 @@ first (``deal``). Such a round ends when its placements are committed
 and every one of its deregistrations' evaluations (the ``eval_id`` the
 RPC answers with) shows ``complete`` in the event stream
 (``eval_done``); an evaluation that shows ``failed``, or none by the
-grace, leaves its job's tasks failed. A committed stop gives its job's
+grace, leaves its job's tasks failed, for a stop as for a
+registration, and the loop ends there (``round_short``, or
+``deadline`` where the grace ran past it). A committed stop gives its job's
 size back to ``slots_left``, so such a loop never ends ``cell_full``,
 and its window closes when its last round ends: with the later of the
 last commit and the last stop's ``complete``. The stops of a round are a
@@ -104,7 +119,15 @@ import math
 import random
 import threading
 import time
-from typing import Callable, Dict, Iterator, List, Optional, Tuple
+from typing import (
+    Callable,
+    Dict,
+    Iterator,
+    List,
+    Optional,
+    Sequence,
+    Tuple,
+)
 
 from nomad_tpu.api.codec import to_dict
 
@@ -299,11 +322,13 @@ class Player:
 
     def play(self, seconds: float, tag: str, overrides: Optional[Dict] = None,
              target_base: int = 0,
-             on_open: Callable[[], None] = lambda: None) -> Dict:
+             on_open: Callable[[], None] = lambda: None,
+             limit: float = math.inf) -> Dict:
         """Play the mix for at most ``seconds``. Returns {"opened",
         "closed", "rounds", "asked", "end"}; per-job records accumulate
         in ``self.jobs``. ``target_base`` is the watcher's placed total
-        before this play; ``on_open`` is called as the window opens."""
+        before this play; ``on_open`` is called as the window opens; no
+        round is waited for past ``limit`` (the warm-up's one clock)."""
         mix = dict(self.mix)
         mix.update(overrides or {})
         seconds = float(mix.get("seconds", seconds))
@@ -371,6 +396,7 @@ class Player:
                 self.round_log.append({"offered": time.time()})
             senders = ([] if preloaded and rounds == 1
                        else send(opened, stops))
+            sent = [rec for _i, rec, _p in ready]
             offered = _sizes(ready)
             asked += offered
             self.slots_left -= offered
@@ -387,13 +413,19 @@ class Player:
             # The round in flight when the time is up is played out: the
             # window of a closed loop ends on a round's last commit, so
             # that the rate is not cut to whole plans of 12,500.
-            self._placed_by(asked, deadline + ROUND_GRACE_S)
+            give_up = min(deadline + ROUND_GRACE_S, limit)
+            whole = self._placed_by(asked, give_up, sent)
             if stop_after is not None:
                 self.round_log[-1]["placed"] = time.time()
-                self._stopped_by([rec for _i, rec, _p in stops],
-                                 deadline + ROUND_GRACE_S)
+                whole = self._stopped_by([rec for _i, rec, _p in stops],
+                                         give_up) and whole
                 self.round_log[-1]["stopped"] = time.time()
             if time.time() >= deadline:
+                break
+            if not whole:
+                # A program that has just left jobs unplaced, or a stop
+                # failed, is offered no further round.
+                end = "round_short"
                 break
             if max_rounds is not None and rounds >= int(max_rounds):
                 end = "rounds"
@@ -404,9 +436,13 @@ class Player:
         if not closed_loop and max_rounds is None:
             if mix["arrivals"]["process"] == "at_once":
                 # One round: placed whole before the time is up, it ends
-                # the window; the caller closes it at the last commit.
-                if self._placed_by(asked, deadline):
+                # the window, and so does one whose every evaluation has
+                # ended with jobs left short; the caller closes it at the
+                # last commit.
+                if self._placed_by(asked, min(deadline, limit), sent):
                     end = "drained"
+                elif time.time() < deadline:
+                    end = "round_short"
             else:
                 time.sleep(max(0.0, deadline - time.time()))
         closed = time.time()
@@ -415,27 +451,60 @@ class Player:
         return {"opened": opened, "closed": closed, "rounds": rounds,
                 "asked": asked - target_base, "end": end}
 
-    def play_alone(self, tag: str, target_base: int, timeout: float) -> int:
+    def play_alone(self, tag: str, target_base: int, limit: float) -> int:
         """Each size (or template) of the mix once, one job at a time,
         the next when the last is placed; the placements asked. An open
         loop's jobs meet in a coalesced solve or do not by chance, and a
         size the warm-up only solved beside another would compile its
-        lone program inside the window."""
+        lone program inside the window. The pass stops at the first size
+        left short, and at ``limit``."""
         asked = target_base
         for item in lone_plan(self.mix, tag):
-            self._send(0.0, self._build([item], self.mix))
+            ready = self._build([item], self.mix)
+            self._send(0.0, ready)
             asked += item["size"]
             self.slots_left -= item["size"]
-            if not self._placed_by(asked, time.time() + timeout):
+            if not self._placed_by(asked, limit, [ready[0][1]]):
                 break
         return asked - target_base
 
-    def _placed_by(self, asked: int, limit: float) -> bool:
-        """Wait until ``asked`` placements are committed or the clock
-        passes ``limit``; whether they are."""
+    def _over(self, rec: Dict) -> bool:
+        """Whether this job can no longer be placed: its ``Job.Register``
+        was refused, or the evaluation it was answered with shows
+        ``complete`` or ``failed`` in the event stream."""
+        if "eval_id" in rec:
+            return self.eval_done(rec["eval_id"]) is not None
+        return "error" in rec
+
+    def _placed_by(self, asked: int, limit: float,
+                   recs: Sequence[Dict] = ()) -> bool:
+        """Wait until ``asked`` placements are committed, or every job of
+        ``recs`` is over, or the clock passes ``limit``, whichever is
+        first; whether they are committed. The harness waits for a job
+        only while it can still be placed; one whose evaluation never
+        shows is waited for until ``limit``.
+
+        The total is read once more after the last job is seen over, and
+        that is enough: the tail takes the events in order and adds a
+        batch's placements to its total before it notes the evaluations
+        that ended in that batch (``watcher.EventTail._take``), and an
+        evaluation's plans commit before it ends, so the total then holds
+        all that those evaluations will ever commit."""
+        seen = 0    # recs[:seen] are over; all have to be, so in order
         while self.placed_total() < asked and time.time() < limit:
+            while seen < len(recs) and self._over(recs[seen]):
+                seen += 1
+            if recs and seen == len(recs):
+                break
             time.sleep(0.005)
         return self.placed_total() >= asked
+
+    def settle_placed(self, asked: int, limit: float) -> bool:
+        """After a play: wait until ``limit`` for the jobs it offered,
+        as a round waits for its own; whether ``asked`` placements are
+        committed."""
+        return self._placed_by(
+            asked, limit, [r for r in self.jobs.values() if "due" in r])
 
     def _build_stops(self, wave: List[Tuple[str, int]]) -> List:
         """The stops of one live wave as a round's actions, shaped as

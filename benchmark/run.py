@@ -18,8 +18,24 @@ window open.
 The last line of stdout is the result and nothing else; the fuller
 report is the line before it and a file under benchmark/out/. Without a
 TPU it prints no result and exits 2, unless the configuration's file
-says ``"rehearsal": true``. README.md says how to add a configuration,
-a mix, a metric or a reader as new files.
+says ``"rehearsal": true``.
+
+Exit 3, and no result, means that the warm-up was left short: the
+program did not place the cell's own traffic before the window opened,
+so there is nothing to time. The harness waits for a job only while it
+can still be placed (generators/traffic.py), so where the program ends
+the evaluations it cannot place (``failed``, or ``complete`` with tasks
+left out) or refuses the registrations, exit 3 comes as soon as the
+last of them has ended, seconds after the warm-up was offered, with one
+``short:`` line a job (placed / asked, how its evaluations ended). Where
+it places nothing and ends nothing, exit 3 comes ``WARMUP_TIMEOUT_S``
+after the warm-up's start: that one clock covers the warm-up's play,
+the wait after it and the lone sizes. After a warm-up that finished, a
+run can wait without progress for at most ``--seconds`` +
+``ROUND_GRACE_S`` + ``--drain-timeout`` + ``QUIET_S`` + ``TRACE_JOIN_S``.
+
+README.md says how to add a configuration, a mix, a metric or a reader
+as new files.
 """
 
 from __future__ import annotations
@@ -48,7 +64,10 @@ from benchmark import reference, work  # noqa: E402
 
 TRACE_AFTER_S = 2.0     # the trace starts this long after the window opens
 TRACE_SECONDS = 4.0     # and covers this much of it
-WARMUP_TIMEOUT_S = 900.0
+WARMUP_TIMEOUT_S = 900.0  # from the warm-up's start to the last of its waits
+DRAIN_TIMEOUT_S = 60.0    # --drain-timeout: after the window, for what is due
+QUIET_S = 10.0            # then for the broker and the plan queue to empty
+TRACE_JOIN_S = 120.0      # and for the tracer to have written its trace
 
 
 def log(msg: str) -> None:
@@ -298,7 +317,7 @@ def main(argv=None) -> int:
                     help="after the run, also judge the reference put in "
                          "the program's place with these guarantees broken "
                          "(comma list or 'all'); printed, never the result")
-    ap.add_argument("--drain-timeout", type=float, default=60.0)
+    ap.add_argument("--drain-timeout", type=float, default=DRAIN_TIMEOUT_S)
     ap.add_argument("--rate-per-s", type=float, default=0.0,
                     help="offer a Poisson mix at this rate in place of its "
                          "file's (for the sweep that finds the knee)")
@@ -376,7 +395,13 @@ def main(argv=None) -> int:
         nodes = [node_spec(shape, i) for i in range(node_count(shape))]
         fleet.start_heartbeats()
         fleet.register([build_node(shape, nd) for nd in nodes])
-        log(f"{len(nodes)} nodes registered")
+        first_ttls = fleet.ttl_range()
+        # The fleet's first nodes were granted TTLs of seconds; renewed
+        # once now, none falls due inside the run (Fleet.renew_all).
+        fleet.renew_all()
+        log(f"{len(nodes)} nodes registered; TTLs granted "
+            "{:.0f}-{:.0f} s, after one renewal {:.0f}-{:.0f} s".format(
+                *first_ttls, *fleet.ttl_range()))
 
         def hold(held: bool) -> None:
             for w in srv.workers:
@@ -393,31 +418,41 @@ def main(argv=None) -> int:
         slots = int(fill_limit * tasks_fit + 1e-9)
         log(f"first fit places {rounds_fit} rounds whole, {tasks_fit} "
             f"tasks; fill limit {fill_limit}: {slots} slots")
+        # One clock for the whole warm-up: its play, the wait after it
+        # and the lone sizes.
+        warm_limit = time.time() + WARMUP_TIMEOUT_S
+
+        def warm_left(most: float) -> float:
+            return max(0.0, min(most, warm_limit - time.time()))
+
         warm = Player(fleet, mix, config, args.seed ^ 0x5EED5EED,
                       tail.placed_total, slots, hold,
                       eval_done=tail.eval_done)
-        played = warm.play(args.seconds, "warm", mix.get("warmup"))
-        if not wait_until(lambda: tail.placed_total() >= played["asked"],
-                          WARMUP_TIMEOUT_S):
-            log(f"warm-up placed {tail.placed_total()}/{played['asked']}")
+        played = warm.play(args.seconds, "warm", mix.get("warmup"),
+                           limit=warm_limit)
+        warmed = played["asked"]
+        whole = (warm.settle_placed(warmed, warm_limit)
+                 and played["end"] != "round_short")
+        if whole and mix["arrivals"]["process"] == "poisson":
+            wait_quiet(srv, warm_left(60.0))
+            warmed += warm.play_alone("lone", tail.placed_total(), warm_limit)
+            whole = tail.placed_total() >= warmed
+        if not whole:
+            log(f"warm-up placed {tail.placed_total()}/{warmed} "
+                f"({played['end']})")
             for jid, res in placements_by_job(tail.events, warm.jobs).items():
                 spec = warm.jobs[jid]["spec"]
                 if "due" in warm.jobs[jid] and res["placed"] < spec["count"]:
                     log(f"  short: {res['placed']}/{spec['count']} of {spec}: "
                         f"{eval_ends(tail.events, jid)}")
+            for jid, rec in warm.stops.items():
+                if rec.get("status") != "complete":
+                    log(f"  stop short: {jid}: {rec.get('status')} "
+                        f"{rec.get('error', '')}")
             return 3
-        wait_quiet(srv, 60.0)
-        warmed = played["asked"]
-        if mix["arrivals"]["process"] == "poisson":
-            alone = warm.play_alone("lone", tail.placed_total(),
-                                    WARMUP_TIMEOUT_S)
-            warmed += alone
-            if tail.placed_total() < warmed:
-                log(f"warm-up alone placed {tail.placed_total()}/{warmed}")
-                return 3
-            wait_quiet(srv, 60.0)
+        wait_quiet(srv, warm_left(60.0))
         warm_widths()
-        wait_quiet(srv, 60.0)
+        wait_quiet(srv, warm_left(60.0))
         log(f"warm: {len(warm.jobs)} jobs, {warmed} placements")
         gc.collect()
 
@@ -446,9 +481,11 @@ def main(argv=None) -> int:
                              target_base=base, on_open=on_open)
         mid.cancel()
         opened, closed = played["opened"], played["closed"]
-        if mix.get("repeat") == "when_placed" or played["end"] == "drained":
+        if (mix.get("repeat") == "when_placed"
+                or played["end"] in ("drained", "round_short")):
             # A closed loop's window ends with its last round's last commit,
-            # and so does a single round placed whole before the deadline.
+            # and so does a single round placed whole, or whose every
+            # evaluation has ended, before the deadline.
             # A round with stops ends with the later of its last commit
             # and its last stop's evaluation.
             commits = [e.time for e in tail.events[n_warm_events:]
@@ -471,15 +508,14 @@ def main(argv=None) -> int:
             hold(True)
             drained = wait_quiet(srv, args.drain_timeout, ready_too=False)
         else:
-            drained = wait_until(
-                lambda: tail.placed_total() >= base + played["asked"],
-                args.drain_timeout)
+            drained = player.settle_placed(
+                base + played["asked"], time.time() + args.drain_timeout)
             drained = player.settle_stops(
                 closed + args.drain_timeout) and drained
-            wait_quiet(srv, 10.0)
+            wait_quiet(srv, QUIET_S)
         drain_s = time.time() - closed
         if tracer_thread is not None:
-            tracer_thread.join(timeout=120.0)
+            tracer_thread.join(timeout=TRACE_JOIN_S)
         tail.stop()
         peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                    for d in jax.devices())
@@ -666,6 +702,13 @@ def main(argv=None) -> int:
                         for e in events if event_placed(e)][:2000],
             "trace_error": trace_state["error"] if trace_state else None,
             "heartbeat_errors": fleet.beat_errors,
+            # A node the server marked down had its TTL lapse: the fleet
+            # renews every one, so the harness's process was held up; the
+            # server then places that node's tasks again, and jobs_short
+            # and store_mismatch count the jobs that had tasks on it.
+            "nodes_down": sum(
+                1 for e in tail.events
+                if e.topic == "Node" and e.type == "NodeHeartbeatExpired"),
         }
         result = {"correct": correct, "attempted": attempted,
                   "failed": failed, "metrics": metrics, "device": device}
@@ -687,6 +730,8 @@ def main(argv=None) -> int:
     except OSError:
         pass
     print(json.dumps(report, default=str), flush=True)
+    print(f"fleet: {report['nodes_down']} nodes marked down in the run, "
+          f"{fleet.beat_errors} heartbeat errors", file=sys.stderr)
     for k, v in compared.items():
         print(f"compared {k}: {v['value']} (limit {v['limit']})",
               file=sys.stderr)

@@ -24,6 +24,7 @@ import json
 import os
 import sys
 import threading
+import time
 
 import pytest
 
@@ -202,12 +203,17 @@ class FakeFleet:
     """Takes registrations and stops over the two calls the player makes.
     A job counts as placed at once where ``places(job id)`` says so, and
     a stop's evaluation ends at once as ``stops(job id)`` says
-    (``complete``, ``failed``, or None for never)."""
+    (``complete``, ``failed``, or None for never). A registration's
+    evaluation ends at once as ``evals(job id)`` says (the same three, or
+    ``refused``: the call raises and answers with no evaluation); where
+    no rule is given, ``complete`` for a job placed and never for one
+    that is not."""
 
     def __init__(self, places=lambda job_id: True,
-                 stops=lambda job_id: "complete"):
+                 stops=lambda job_id: "complete", evals=None):
         self.places = places
         self.stops = stops
+        self.evals = evals
         self.placed = 0
         self.lock = threading.Lock()
         self.registered = []
@@ -227,10 +233,17 @@ class FakeFleet:
         assert method == "Job.Register"
         job = args["job"]
         self.registered.append(job["id"])
-        if self.places(job["id"]):
+        placed = self.places(job["id"])
+        how = (self.evals(job["id"]) if self.evals
+               else "complete" if placed else None)
+        if how == "refused":
+            raise RuntimeError("refused: " + job["id"])
+        if placed:
             count = sum(g["count"] for g in job["task_groups"])
             with self.lock:       # the senders are threads
                 self.placed += count
+        if how:                   # after its placements, as the tail notes it
+            self.ended["eval-" + job["id"]] = (how, 1.0)
         return {"eval_id": "eval-" + job["id"]}
 
 
@@ -252,11 +265,13 @@ def sixteen_nodes():
 
 def warmed(fleet, config, mix, slots):
     """The window's player, after a warm-up round as run.py plays it."""
-    warm = traffic.Player(fleet, mix, config, 11, lambda: fleet.placed, slots)
+    warm = traffic.Player(fleet, mix, config, 11, lambda: fleet.placed, slots,
+                          eval_done=fleet.eval_done)
     played = warm.play(30.0, "warm", mix["warmup"])
     assert played["rounds"] == 1 and played["end"] == "rounds"
     return traffic.Player(fleet, mix, config, 2_500_000_321,
-                          lambda: fleet.placed, warm.slots_left)
+                          lambda: fleet.placed, warm.slots_left,
+                          eval_done=fleet.eval_done)
 
 
 def test_closed_loop_offers_no_round_the_cell_cannot_hold():
@@ -315,7 +330,7 @@ def test_each_size_is_played_once_alone():
 
     fleet = FakeFleet(places)
     player = traffic.Player(fleet, mix, config, 7, lambda: fleet.placed, slots)
-    asked = player.play_alone("lone", 0, 5.0)
+    asked = player.play_alone("lone", 0, time.time() + 5.0)
     sizes = sorted(set(mix["sizes"]))
     assert asked == sum(sizes) == fleet.placed
     assert [player.jobs[j]["spec"]["count"] for j in fleet.registered] == sizes
@@ -323,6 +338,156 @@ def test_each_size_is_played_once_alone():
     assert placed_before == [sum(sizes[:k]) for k in range(len(sizes))]
     assert all("due" in rec for rec in player.jobs.values())
     assert player.slots_left == slots - asked
+
+
+# -- the harness waits for a job only while it can still be placed -------------
+#
+# ROUND_GRACE_S stays at 120 in all of these: what ends the wait is the
+# job's end, not a shorter grace.
+
+
+def ending(job_part, how):
+    """A fleet that places every job but those whose id holds
+    ``job_part``; their evaluation ends as ``how`` says."""
+    return FakeFleet(
+        places=lambda jid: job_part not in jid,
+        evals=lambda jid: how if job_part in jid else "complete")
+
+
+@pytest.mark.parametrize("how", ["failed", "complete", "refused"])
+def test_round_with_a_job_over_and_short_ends_the_loop_at_once(how):
+    # The second round's third job is over with nothing placed: its
+    # evaluation failed, or completed with its tasks left out, or its
+    # registration was refused. The round ends on the next poll and the
+    # loop offers no third, with 1,200 slots and 29 s to go.
+    assert traffic.ROUND_GRACE_S == 120.0
+    config, mix, slots = sixteen_nodes()
+    fleet = ending("-r001-00002", how)
+    player = warmed(fleet, config, mix, slots)
+    base = fleet.placed
+    played = player.play(30.0, "s", target_base=base)
+    assert played["end"] == "round_short" and played["rounds"] == 2
+    assert played["closed"] - played["opened"] < 1.0
+    assert played["asked"] == 2_400 and player.slots_left == 1_200
+    assert played["asked"] - (fleet.placed - base) == 300     # run.py's failed
+    assert len(fleet.registered) == 4 + 8
+    assert not any("-r002-" in jid for jid in fleet.registered)
+    rec = player.jobs["s-r001-00002"]
+    assert ("error" in rec and "eval_id" not in rec) == (how == "refused")
+
+
+def test_evaluation_that_never_shows_is_waited_for_until_the_grace(monkeypatch):
+    # Placed by nobody and ended by nobody (a server that hangs): the
+    # round waits --seconds and the grace out, as before.
+    monkeypatch.setattr(traffic, "ROUND_GRACE_S", 0.3)
+    config, mix, slots = sixteen_nodes()
+    fleet = FakeFleet(places=lambda jid: "s-r000-00001" not in jid)
+    player = warmed(fleet, config, mix, slots)
+    played = player.play(0.2, "s", target_base=fleet.placed)
+    assert played["end"] == "deadline" and played["rounds"] == 1
+    assert 0.5 <= played["closed"] - played["opened"] < 2.0
+    # One job's end does not end the round while another can be placed.
+    fleet = FakeFleet(
+        places=lambda jid: "s-r000-0000" not in jid,
+        evals=lambda jid: "failed" if "s-r000-00000" in jid else None)
+    player = warmed(fleet, config, mix, slots)
+    played = player.play(0.2, "s", target_base=fleet.placed)
+    assert played["end"] == "deadline"
+    assert played["closed"] - played["opened"] >= 0.5
+
+
+def test_round_placed_whole_goes_on_without_waiting_for_its_evaluations():
+    # Every job is placed 30 ms after it is registered and no evaluation
+    # ever shows: the next round goes out on the poll that sees the
+    # placements, as it did before the generator asked how a job ended.
+    config, mix, slots = sixteen_nodes()
+    placed_at = []
+
+    def places(job_id):
+        def commit():
+            with fleet.lock:
+                fleet.placed += 300
+            placed_at.append(time.time())
+        threading.Timer(0.03, commit).start()
+        return False
+
+    fleet = FakeFleet(places, evals=lambda jid: None)
+    player = traffic.Player(fleet, mix, config, 7, lambda: fleet.placed,
+                            slots, eval_done=fleet.eval_done)
+    played = player.play(30.0, "s", {"rounds": 3})
+    assert played["end"] == "rounds" and fleet.placed == 3_600
+    for rnd in (1, 2):
+        whole = max(placed_at[4 * (rnd - 1):4 * rnd])
+        sent = min(player.jobs[f"s-r{rnd:03d}-{k:05d}"]["sent"]
+                   for k in range(4))
+        assert 0.0 <= sent - whole < 0.05
+
+
+def test_lone_pass_stops_at_the_first_size_left_short():
+    config, _mix, slots = sixteen_nodes()
+    mix = run.load_json("traffic", "rehearsal-steady.json")
+    fleet = ending("lone-00002", "failed")        # the third size: 5
+    player = traffic.Player(fleet, mix, config, 7, lambda: fleet.placed,
+                            slots, eval_done=fleet.eval_done)
+    t0 = time.time()
+    asked = player.play_alone("lone", 0, t0 + 600.0)
+    assert time.time() - t0 < 1.0
+    assert asked == 1 + 2 + 5 and fleet.placed == 3
+    assert len(fleet.registered) == 3
+
+
+def test_single_round_whose_evaluations_have_ended_ends_the_window():
+    config, _mix, slots = sixteen_nodes()
+    mix = dict(run.load_json("traffic", "rehearsal-backlog.json"),
+               arrivals={"process": "at_once", "jobs": 6})
+    fleet = ending("s-r000-00004", "failed")
+    player = traffic.Player(fleet, mix, config, 7, lambda: fleet.placed,
+                            slots, eval_done=fleet.eval_done)
+    played = player.play(30.0, "s")
+    assert played["end"] == "round_short" and played["rounds"] == 1
+    assert played["closed"] - played["opened"] < 1.0 + traffic.HOLD_SETTLE_S
+    assert played["asked"] - fleet.placed == mix["sizes"][0]
+    # Placed whole it ends drained, as before.
+    fleet = FakeFleet()
+    player = traffic.Player(fleet, mix, config, 7, lambda: fleet.placed,
+                            slots, eval_done=fleet.eval_done)
+    assert player.play(30.0, "s")["end"] == "drained"
+
+
+def test_one_clock_bounds_every_wait_of_a_play():
+    # ``limit`` is the warm-up's one clock: neither the mix's seconds nor
+    # the grace carry a round past it, and settle_placed ends with it.
+    assert traffic.ROUND_GRACE_S == 120.0
+    config, mix, slots = sixteen_nodes()
+    fleet = FakeFleet(places=lambda jid: False)
+    player = traffic.Player(fleet, mix, config, 7, lambda: fleet.placed,
+                            slots, eval_done=fleet.eval_done)
+    t0 = time.time()
+    played = player.play(30.0, "warm", mix["warmup"], limit=t0 + 0.3)
+    assert not player.settle_placed(played["asked"], t0 + 0.3)
+    assert 0.3 <= time.time() - t0 < 1.0
+    assert played["rounds"] == 1 and played["asked"] == 1_200
+
+
+def test_a_run_that_makes_no_progress_ends_inside_the_drivers_limit():
+    # The driver stops a run after 1,200 s (the first of a cell, which
+    # compiles) and a set-up takes some 60 s before the warm-up starts.
+    # A warm-up that does not finish exits 3 inside WARMUP_TIMEOUT_S of
+    # its start; after one that finished, the waits that can pass with
+    # no progress are the window, the grace, the drain, the quiet and the
+    # tracer's join.
+    driver, setup = 1200.0, 60.0
+    assert run.WARMUP_TIMEOUT_S == 900.0
+    assert traffic.ROUND_GRACE_S == 120.0 and traffic.HOLD_SETTLE_S == 1.0
+    assert setup + run.WARMUP_TIMEOUT_S < driver
+    after = (BENCH["run_seconds"] + traffic.ROUND_GRACE_S
+             + run.DRAIN_TIMEOUT_S + run.QUIET_S + run.TRACE_JOIN_S)
+    assert after == 45 + 120 + 60 + 10 + 120
+    assert setup + after < driver
+    # No mix asks its warm-up to last longer than the one clock lets it.
+    for name in os.listdir(os.path.join(HERE, "traffic")):
+        warmup = run.load_json("traffic", name).get("warmup", {})
+        assert warmup.get("seconds", 0) <= run.WARMUP_TIMEOUT_S
 
 
 # -- jobs that end ----------------------------------------------------------------
@@ -379,6 +544,19 @@ def test_stop_that_never_ends_fails_its_tasks_and_frees_nothing(monkeypatch):
     assert len(player.stops) == 4 and len(done) == 2
     assert player.slots_left == 2_400 - 1_200 + 2 * 300
     assert not player.settle_stops(0.0)
+
+
+def test_stop_whose_evaluation_failed_ends_the_loop_at_once():
+    assert traffic.ROUND_GRACE_S == 120.0
+    fleet = FakeFleet(stops=lambda jid: "failed"
+                      if jid.startswith("warm-r001-00001") else "complete")
+    _warm, player = churn_players(fleet, 4_800)
+    played = player.play(30.0, "s", target_base=fleet.placed)
+    assert played["end"] == "round_short" and played["rounds"] == 1
+    assert played["closed"] - played["opened"] < 1.0
+    assert sorted(r["status"] for r in player.stops.values()) == [
+        "complete"] * 3 + ["failed"]
+    assert player.slots_left == 2_400 - 1_200 + 3 * 300
 
 
 def test_first_round_that_does_not_fit_beside_the_live_waves_is_refused():
@@ -640,11 +818,11 @@ def test_borg_12k_is_the_published_machine_table():
 
 
 def test_the_mixed_wave_is_the_issues_table():
-    # ISSUE 31's wave, kept as data for the cell the program cannot run
-    # yet (PERF.md section 7): 64 jobs, 6,940 tasks.
+    # ISSUE 31's wave, the data of the cell borg-12k.mixed-shapes, which
+    # comes with the program's repair (PERF.md section 7): 64 jobs,
+    # 6,940 tasks.
     config, _ = borg()
     mix = run.load_json("traffic", "mixed-shapes.json")
-    assert "borg-12k.mixed-shapes" not in {w["name"] for w in BENCH["workloads"]}
     plans = [traffic.round_plan(mix, seed, 45.0, rnd, "s")
              for seed, rnd in ((1, 0), (1, 1), (4_000_000_007, 0))]
     # Every seed and every round the same 64 templates in another order.
@@ -1139,6 +1317,202 @@ def test_broken_timed_path_comes_out_not_correct(
     result, _ = drive(capsys, workload)
     assert result["correct"] is False
     assert result["compared"][number]["value"] > 0
+
+
+# -- a program that cannot place the cell's traffic: exit 3 in seconds --------
+
+
+def _evaluations_fail(monkeypatch):
+    """The server's scheduler gives every attempt up: each evaluation
+    ends ``failed`` with nothing placed (what the program does to the jobs
+    it loses a race for, here without leaning on that fault)."""
+    from nomad_tpu.scheduler.generic import GenericScheduler
+
+    monkeypatch.setattr(GenericScheduler, "_process", lambda self: False)
+
+
+def _registrations_refused(monkeypatch):
+    """The front door refuses every ``Job.Register``."""
+    from benchmark.generators.fleet import Fleet
+
+    real = Fleet.call
+
+    def call(self, method, args):
+        if method == "Job.Register":
+            raise RuntimeError("refused")
+        return real(self, method, args)
+
+    monkeypatch.setattr(Fleet, "call", call)
+
+
+def _nothing_ends(monkeypatch):
+    """Worse than failing: the workers take no evaluation, so nothing is
+    placed and nothing ends. The one clock, cut to a second, ends it."""
+    from benchmark.generators.traffic import Player
+
+    real_play = Player.play
+
+    def play(self, *args, **kwargs):
+        self.hold(True)
+        # As for a preloaded round: a worker already waiting in the
+        # broker's dequeue would still take what comes next.
+        time.sleep(traffic.HOLD_SETTLE_S)
+        return real_play(self, *args, **kwargs)
+
+    monkeypatch.setattr(Player, "play", play)
+    monkeypatch.setattr(run, "WARMUP_TIMEOUT_S", 1.0)
+
+
+BURST, BACKLOG = ("rehearsal-256.rehearsal-burst",
+                  "rehearsal-256.rehearsal-backlog")
+
+
+@pytest.mark.parametrize("workload,fault,ends", [
+    (BURST, _evaluations_fail, "failed"),
+    (STEADY, _evaluations_fail, "failed"),
+    (BACKLOG, _evaluations_fail, "failed"),
+    (CHURN, _evaluations_fail, "failed"),
+    (BURST, _registrations_refused, "[]"),
+    (BURST, _nothing_ends, "[]"),
+    (STEADY, _nothing_ends, "[]"),
+], ids=["burst-failed", "steady-failed", "backlog-failed", "churn-failed",
+        "burst-refused", "burst-nothing-ends", "steady-nothing-ends"])
+def test_warm_up_left_short_exits_3_at_once(
+        capsys, monkeypatch, workload, fault, ends):
+    # ROUND_GRACE_S is 120, the mixes' warm-ups ask for up to 900 s and
+    # WARMUP_TIMEOUT_S is 900 (1 under _nothing_ends): the exit comes with
+    # the last evaluation's end, not with any of them.
+    assert traffic.ROUND_GRACE_S == 120.0 and run.WARMUP_TIMEOUT_S == 900.0
+    fault(monkeypatch)
+    t0 = time.time()
+    rc = run.main(["--workload", workload, "--seed", "2500000321",
+                   "--seconds", "2", "--trace", "0"])
+    took = time.time() - t0
+    out = capsys.readouterr()
+    assert rc == 3 and took < 60.0, out.err[-2000:]
+    assert out.out.strip() == ""                       # no result
+    offered = float(out.err.split("first fit places")[0].rsplit(
+        "bench[", 1)[1].split("s]")[0])
+    gave_up = float(out.err.split("warm-up placed")[0].rsplit(
+        "bench[", 1)[1].split("s]")[0])
+    assert gave_up - offered < 15.0
+    short = [ln for ln in out.err.splitlines() if "  short: " in ln]
+    assert short and all(" short: 0/" in ln for ln in short)
+    assert all(ends in ln.rsplit(": ", 1)[1] for ln in short)
+
+
+def test_window_whose_round_is_left_short_closes_there(capsys, monkeypatch):
+    # The warm-up runs sound; from the window's first round on every
+    # evaluation fails. The run ends as any finished run, not correct.
+    from benchmark.generators.traffic import Player
+
+    real_play = Player.play
+
+    def play(self, seconds, tag, *args, **kwargs):
+        if tag != "warm":
+            _evaluations_fail(monkeypatch)
+        return real_play(self, seconds, tag, *args, **kwargs)
+
+    monkeypatch.setattr(Player, "play", play)
+    t0 = time.time()
+    rc = run.main(["--workload", BURST, "--seed", "2500000321",
+                   "--seconds", "600", "--trace", "0"])
+    out = capsys.readouterr()
+    assert rc == 0 and time.time() - t0 < 60.0, out.err[-2000:]
+    result, report = json.loads(out.out.strip().splitlines()[-1]), report_of(out)
+    assert report["window_end"] == "round_short" and report["rounds"] == 1
+    assert result["correct"] is False
+    assert result["attempted"] == 1_200 == result["failed"]
+    assert result["compared"]["jobs_short"]["value"] == 4
+    assert report["drain_s"] < 5.0 and not report["drained"]
+    assert [j["evals"][0][0] for j in report["jobs_not_whole"]] == ["failed"] * 4
+
+
+class _TtlServer:
+    """Grants a TTL as the program does: scaled by the timers armed when
+    it arms one, 50 renewals a second, never under 10 s."""
+
+    def __init__(self):
+        self.armed = set()
+        self.beats = []
+
+    def grant(self, ids):
+        out = {}
+        for nid in ids:
+            others = len(self.armed - {nid})
+            self.armed.add(nid)
+            out[nid] = max(others / 50.0, 10.0)
+        return out
+
+    def call(self, method, args):
+        if method == "Node.BatchRegister":
+            return {"heartbeat_ttls": self.grant(
+                n["id"] for n in args["nodes"])}
+        assert method == "Node.BatchHeartbeat"
+        self.beats.append(list(args["node_ids"]))
+        return {"heartbeat_ttls": self.grant(args["node_ids"])}
+
+
+def test_one_renewal_in_set_up_gives_every_node_the_whole_fleets_ttl(
+        monkeypatch):
+    # The fleet's first nodes are granted 10 s and would be renewed with
+    # 2 s in hand, in a run's first seconds; a host that stood still that
+    # long once read 52 jobs placed twice over (PERF.md, PR 36).
+    from benchmark.generators.fleet import Fleet, build_node
+
+    server = _TtlServer()
+    fleet = Fleet("nowhere")
+    monkeypatch.setattr(fleet, "call", server.call)
+    config = run.load_json("configs", "rehearsal-256.json")
+    config["nodes"]["count"] = 2_000
+    fleet.register([build_node(config["nodes"], node_spec(config["nodes"], i))
+                    for i in range(2_000)])
+    assert fleet.ttl_range() == (10.0, 1_999 / 50.0)
+    assert min(due for due, _nid in fleet._due) < time.monotonic() + 8.5
+    fleet.renew_all()
+    assert fleet.ttl_range() == (1_999 / 50.0, 1_999 / 50.0)
+    assert [len(b) for b in server.beats] == [500] * 4
+    # Each node once on the schedule, none due before 0.8 of that TTL.
+    assert sorted(nid for _due, nid in fleet._due) == sorted(fleet.granted)
+    assert (min(due for due, _nid in fleet._due)
+            > time.monotonic() + 0.8 * 1_999 / 50.0 - 1.0)
+    fleet.stop()
+
+
+def test_a_lapsed_ttl_reads_as_jobs_placed_twice_and_says_so(
+        capsys, monkeypatch):
+    # What a run reads where the harness's process is held up past a TTL:
+    # from the window's opening on no beat goes out, the rehearsal
+    # fleet's TTLs of 10-20 s lapse, and the server places the tasks of
+    # the nodes it marked down again. failed stays 0, since every job was
+    # placed whole; the jobs that had tasks on those nodes read more
+    # placements than were asked and than the store holds.
+    from benchmark.generators.fleet import Fleet
+    from benchmark.generators.traffic import Player
+
+    real_play, real_beat = Player.play, Fleet._beat
+    held = threading.Event()
+
+    def play(self, seconds, tag, *args, **kwargs):
+        if tag != "warm":
+            held.set()
+        return real_play(self, seconds, tag, *args, **kwargs)
+
+    monkeypatch.setattr(Player, "play", play)
+    monkeypatch.setattr(
+        Fleet, "_beat",
+        lambda self, due: None if held.is_set() else real_beat(self, due))
+    rc = run.main(["--workload", STEADY, "--seed", "2500000322",
+                   "--seconds", "9", "--trace", "0"])
+    out = capsys.readouterr()
+    assert rc == 0, out.err[-2000:]
+    result, report = json.loads(out.out.strip().splitlines()[-1]), report_of(out)
+    assert report["nodes_down"] > 0
+    assert f"fleet: {report['nodes_down']} nodes marked down" in out.err
+    assert result["correct"] is False and result["failed"] == 0
+    short = result["compared"]["jobs_short"]["value"]
+    assert short > 0 and result["compared"]["store_mismatch"]["value"] > 0
+    assert all(j["placed"] > j["count"] for j in report["jobs_not_whole"])
 
 
 def test_compare_counts_a_job_left_short():
