@@ -31,6 +31,7 @@ from jax import lax
 
 from nomad_tpu import trace
 from nomad_tpu.ops.fit import NEG_INF, score_fit
+from nomad_tpu.scheduler import candidates
 
 
 # Counts at or below this route through the exact greedy scan (padded to
@@ -82,6 +83,124 @@ def _monotone_u32(score: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(neg, ~bits, bits | jnp.uint32(0x80000000))
 
 
+def _floor_div(a, b):
+    """``a // b`` for int32 a >= 0 (a negative a gives 0) and b >= 1,
+    without the vector integer division: XLA's TPU backend takes 18 s to
+    compile one over a [8, 16384, 4] operand (compiled here for a v5e,
+    PR 37), and every program of the coalescer would pay it. Exact for
+    every quotient up to 2^22 (and for every a < 2^24): the float
+    quotient is then within two of the answer, and two rounds of integer
+    products settle it. A larger quotient comes out within 2^-22 of
+    itself, and its only reader clips it to a count far below."""
+    a = jnp.maximum(a, 0)
+    q = jnp.floor(a.astype(jnp.float32) / b.astype(jnp.float32)
+                  ).astype(jnp.int32)
+    for _ in range(2):
+        # (q + 1) * b and q * b stay inside int32 wherever q is exact
+        # to within two: both are within 3b of a <= 2^31 - 2^24.
+        q = q - (q * b > a).astype(jnp.int32)
+        q = q + ((q + 1) * b <= a).astype(jnp.int32)
+    return q
+
+
+def _copies_cap(total, used0, job_count0, tg_count0, bw_avail, bw_used0,
+                eligible, ask, bw_ask, most, job_distinct, tg_distinct):
+    """Per-node capacity for this ask, in copies, clipped to ``most``
+    (int32[N], 0 on a node that is ineligible or already overcommitted).
+    The ONE definition the water-fill and the candidate rule share."""
+    big = jnp.int32(2**30)
+    avail = total - used0
+    nonneg = jnp.all(avail >= 0, axis=-1) & (bw_used0 <= bw_avail)
+    safe_ask = jnp.maximum(ask, 1)[None, :]
+    dim_cap = jnp.where(ask[None, :] > 0, _floor_div(avail, safe_ask), big)
+    cap = jnp.min(dim_cap, axis=-1)
+    bw_cap = jnp.where(
+        bw_ask > 0,
+        _floor_div(bw_avail - bw_used0, jnp.maximum(bw_ask, 1)), big)
+    cap = jnp.minimum(cap, bw_cap)
+    if job_distinct:
+        cap = jnp.minimum(cap, jnp.where(job_count0 == 0, 1, 0))
+    if tg_distinct:
+        cap = jnp.minimum(cap, jnp.where(tg_count0 == 0, 1, 0))
+    return jnp.where(eligible & nonneg, jnp.clip(cap, 0, most), 0).astype(jnp.int32)
+
+
+def restrict_to_candidates(
+    total, used0, job_count0, tg_count0, bw_avail, bw_used0, eligible,
+    ask, bw_ask, count, cand_key, job_distinct, tg_distinct, spread,
+):
+    """The candidate rule on the device (scheduler/candidates.py is its
+    host side and says why): ``eligible`` narrowed to the nodes this
+    evaluation's solve may choose from, wherever those hold all ``count``
+    copies; where they do not, nothing is narrowed, so a group is solved
+    over every eligible node in this same program and is left short only
+    where the cell is. A node is ROOMY where it has room for ``HEADROOM``
+    x L copies, L = ceil(count / nodes) being its even share: another
+    evaluation's share still fits beside this one's. The two program
+    families (``spread``, static):
+
+    - the exact scan packs best fit, into the fullest node, where two
+      evaluations cannot both land: it keeps the evaluation's own class
+      of nodes, at the finest level that holds the group (level 0 is the
+      whole cell). The finest class is its own, to pack as it likes; of a
+      coarser one, which other evaluations' classes lie in, it keeps the
+      roomy nodes alone, and so does every level of an attempt after a
+      refused plan (``cand_key`` >= ``RETRY``: a drawn key, which any
+      evaluation in flight may share);
+    - the water-fill spreads, and meets other evaluations wherever it
+      goes: it keeps the roomy nodes of the whole cell, and of them the
+      ``count`` roomiest where there are more than copies: the far end
+      of the cell from the fullest node an exact scan is packing.
+
+    ``cand_key`` < 0 narrows nothing. Integer arithmetic throughout: the
+    host oracle reproduces the mask exactly."""
+    n = total.shape[0]
+    bits = candidates.class_bits(n)
+    if bits == 0 and not spread:
+        return eligible
+    room = candidates.HEADROOM
+    cap = _copies_cap(total, used0, job_count0, tg_count0, bw_avail,
+                      bw_used0, eligible, ask, bw_ask, room * count,
+                      job_distinct, tg_distinct)
+    if spread:
+        nodes = jnp.maximum((cap > 0).sum(dtype=jnp.int32), 1)
+        share = (count + nodes - 1) // nodes
+        roomy = cap >= room * jnp.maximum(share, 1)
+        holds = jnp.where(roomy, jnp.minimum(cap, count), 0).sum() >= count
+
+        # A group of fewer copies than there are roomy nodes goes one to a
+        # node: to the ``count`` roomiest, ties and all. The largest floor
+        # that still keeps ``count`` nodes, by bisection (cap <= room x
+        # count < 2^31).
+        def floor_body(_, lohi):
+            lo, hi = lohi
+            mid = lo + (hi - lo + 1) // 2
+            ok = (roomy & (cap >= mid)).sum(dtype=jnp.int32) >= count
+            return (jnp.where(ok, mid, lo), jnp.where(ok, hi, mid - 1))
+
+        floor, _ = lax.fori_loop(
+            0, 32, floor_body, (jnp.int32(0), room * count))
+        kept = roomy & (cap >= floor)
+        return eligible & (kept | ~holds | (cand_key < 0))
+    rows = lax.iota(jnp.uint32, n)
+    node_key = ((rows * jnp.uint32(candidates.ROW_HASH))
+                >> (32 - candidates.KEY_BITS)).astype(jnp.int32)
+    retry = cand_key >= candidates.RETRY
+    diff = node_key ^ (jnp.maximum(cand_key, 0) & (candidates.RETRY - 1))
+    # Level l keeps the nodes whose key shares its top l bits with the
+    # evaluation's: diff < 2^(KEY_BITS - l). One masked sum a level.
+    widths = jnp.asarray(candidates.level_widths(bits), dtype=jnp.int32)
+    inside = (diff[:, None] < widths[None, :]) & (cap[:, None] > 0)
+    nodes = jnp.maximum(inside.sum(axis=0, dtype=jnp.int32), 1)
+    share = jnp.maximum((count + nodes - 1) // nodes, 1)    # L, a level
+    shared = (jnp.arange(bits + 1) < bits) | retry
+    inside = inside & (~shared[None, :]
+                       | (cap[:, None] >= room * share[None, :]))
+    held = jnp.where(inside, jnp.minimum(cap, count)[:, None], 0
+                     ).sum(axis=0) >= count
+    level = jnp.max(jnp.where(held, jnp.arange(bits + 1), -1))
+    kept = jnp.take(inside, jnp.maximum(level, 0), axis=1)
+    return eligible & (kept | (level < 0) | (cand_key < 0))
 
 
 @partial(jax.jit, static_argnames=("job_distinct", "tg_distinct"))
@@ -246,21 +365,9 @@ def solve_waterfill(
     among those with cap > L. So: binary-search L, then one scored top-k —
     no sequential state updates at all. Returns (counts[N], unplaced).
     """
-    big = jnp.int32(2**30)
-
-    # Per-node capacity for this ask, in copies.
-    avail = total - used0
-    nonneg = jnp.all(avail >= 0, axis=-1) & (bw_used0 <= bw_avail)
-    safe_ask = jnp.maximum(ask, 1)[None, :]
-    dim_cap = jnp.where(ask[None, :] > 0, avail // safe_ask, big)
-    cap = jnp.min(dim_cap, axis=-1)
-    bw_cap = jnp.where(bw_ask > 0, (bw_avail - bw_used0) // jnp.maximum(bw_ask, 1), big)
-    cap = jnp.minimum(cap, bw_cap)
-    if job_distinct:
-        cap = jnp.minimum(cap, jnp.where(job_count0 == 0, 1, 0))
-    if tg_distinct:
-        cap = jnp.minimum(cap, jnp.where(tg_count0 == 0, 1, 0))
-    cap = jnp.where(eligible & nonneg, jnp.clip(cap, 0, count), 0).astype(jnp.int32)
+    cap = _copies_cap(total, used0, job_count0, tg_count0, bw_avail,
+                      bw_used0, eligible, ask, bw_ask, count,
+                      job_distinct, tg_distinct)
 
     # Largest L with sum(min(cap, L)) <= count.
     def placed_at(level):
@@ -332,6 +439,7 @@ def solve_many_async(
     eligible, ask, bw_ask, count: int, penalty: float,
     job_distinct: bool = False, tg_distinct: bool = False,
     exact_threshold: int = EXACT_THRESHOLD,
+    cand_key: int = candidates.NO_KEY, scan_steps: int = 0,
 ):
     """Dispatch the solve for ``count`` copies of one ask; return a fetch()
     closure that blocks on the device and yields (node_indices, ok).
@@ -344,6 +452,9 @@ def solve_many_async(
     the fused path reconstructs from per-node counts, so indices come
     grouped by node — copies of one ask are interchangeable, so callers
     must not rely on ordering. Unplaceable tail is idx -1 / ok False.
+    ``scan_steps`` asks the exact scan for the program of that many
+    steps at the least (the inactive ones place nothing): a remainder
+    then rides the program its whole group compiled.
     """
     if count <= exact_threshold:
         # The exact scan rides the coalescing engine like the water-fill:
@@ -358,6 +469,7 @@ def solve_many_async(
             total, sched_cap, used0, job_count0, tg_count0, bw_avail,
             bw_used0, eligible, ask, bw_ask, count, penalty,
             job_distinct=job_distinct, tg_distinct=tg_distinct,
+            cand_key=cand_key, scan_steps=scan_steps,
         )
 
     import numpy as np
@@ -369,6 +481,7 @@ def solve_many_async(
         total, sched_cap, used0, job_count0, tg_count0, bw_avail, bw_used0,
         eligible, ask, bw_ask, count, penalty,
         job_distinct=job_distinct, tg_distinct=tg_distinct,
+        cand_key=cand_key,
     )
 
     def fetch_fused():
@@ -393,6 +506,7 @@ def solve_counts_async(
     total, sched_cap, used0, job_count0, tg_count0, bw_avail, bw_used0,
     eligible, ask, bw_ask, count: int, penalty: float,
     job_distinct: bool = False, tg_distinct: bool = False,
+    cand_key: int = candidates.NO_KEY,
 ):
     """Water-fill dispatch returning per-node placement *counts* — the
     columnar form consumed by AllocBatch. One device round-trip; no
@@ -407,6 +521,7 @@ def solve_counts_async(
         total, sched_cap, used0, job_count0, tg_count0, bw_avail, bw_used0,
         eligible, ask, bw_ask, count, penalty,
         job_distinct=job_distinct, tg_distinct=tg_distinct,
+        cand_key=cand_key,
     )
 
 

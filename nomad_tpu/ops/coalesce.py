@@ -22,10 +22,11 @@ small dispatches instead of one stacked one.
 One program a dispatch: the launch (_launch_rows) is ONE call into the
 device runtime for either program family at any width. The riders' rows
 go into the jitted entry (solve_waterfill_rows / solve_greedy_rows) as
-the device arrays each entry already holds, the per-eval counts and
-penalties as two small typed host arrays; the stacking on the eval axis
-(a lone solve is B = 1), the exact scan's active masks and the choice of
-water-fill kernel all happen inside that program. Nothing eager runs on
+the device arrays each entry already holds, the per-eval counts,
+penalties and candidate keys as three small typed host arrays; the
+stacking on the eval axis (a lone solve is B = 1), each evaluation's
+candidates (scheduler/candidates.py), the exact scan's active masks and
+the choice of water-fill kernel all happen inside that program. Nothing eager runs on
 the dispatcher thread beside it: each eager op or transfer costs as much
 host time as the launch itself (0.7-0.8 ms on the benchmark's host, where
 a width-1 water-fill used to make 19 such calls around a 27 us kernel).
@@ -48,8 +49,14 @@ import numpy as np
 
 from nomad_tpu import telemetry, trace
 from nomad_tpu.ops import pallas_solve
-from nomad_tpu.ops.binpack import bucket, solve_greedy, solve_waterfill
+from nomad_tpu.ops.binpack import (
+    bucket,
+    restrict_to_candidates,
+    solve_greedy,
+    solve_waterfill,
+)
 from nomad_tpu.parallel import mesh as mesh_lib
+from nomad_tpu.scheduler.candidates import NO_KEY
 
 logger = logging.getLogger("nomad_tpu.coalesce")
 
@@ -83,16 +90,37 @@ def _stack_columns(rows):
     return tuple(jnp.stack(col) for col in zip(*rows))
 
 
+def _candidates_only(eligible, counts, keys, spread, total, used0,
+                     job_count0, tg_count0, bw_avail, bw_used0, ask, bw_ask,
+                     job_distinct, tg_distinct):
+    """``eligible`` [B, N] narrowed, row by row, to each evaluation's
+    candidates (binpack.restrict_to_candidates; ``spread`` names the
+    program family). ``total`` and ``bw_avail`` are the rows' own
+    ([B, N, .]) or, unstacked, the one mirror's every row shares. No
+    ``keys``: nothing is narrowed."""
+    if keys is None:
+        return eligible
+    shared = None if total.ndim == 2 else 0
+    return jax.vmap(
+        restrict_to_candidates,
+        in_axes=(shared, 0, 0, 0, shared) + (0,) * 6 + (None, None, None),
+    )(total, used0, job_count0, tg_count0, bw_avail, bw_used0, eligible,
+      ask, bw_ask, counts, keys, job_distinct, tg_distinct, spread)
+
+
 @partial(jax.jit,
          static_argnames=("job_distinct", "tg_distinct", "kernel", "mesh"))
 def solve_waterfill_rows(rows, counts, penalties, job_distinct, tg_distinct,
-                         kernel="jnp", mesh=None):
+                         kernel="jnp", mesh=None, keys=None):
     """ONE program a water-fill dispatch, whatever its width. ``rows`` is
     a tuple of B rows of the ten device arrays of solve_waterfill's
-    positional order, as each rider holds them; ``counts`` (int32[B]) and
-    ``penalties`` (float32[B]) ride as host arrays, typed, so that no
-    value retraces. The stacking on the eval axis (the ``[None]`` of a
-    lone solve is the case B = 1) happens in here, then the Pallas kernel
+    positional order, as each rider holds them; ``counts`` (int32[B]),
+    ``penalties`` (float32[B]) and the evaluations' candidate ``keys``
+    (int32[B], scheduler/candidates.py; None restricts nothing) ride as
+    host arrays, typed, so that no value retraces. The stacking on the eval axis (the ``[None]``
+    of a lone solve is the case B = 1) happens in here, then each row's
+    eligibility is narrowed to its evaluation's candidates, then the
+    Pallas kernel
     (``kernel="pallas"``, decided before the call by pallas_solve.selected)
     or the vmapped closed form. Every eval solves independently against
     its own optimistic view, like concurrent reference workers. Returns
@@ -101,6 +129,12 @@ def solve_waterfill_rows(rows, counts, penalties, job_distinct, tg_distinct,
     if mesh is not None:
         stacked = mesh_lib.constrain_eval_stack(
             mesh, stacked, mesh_lib.WF_SPECS)
+    (total, _sched_cap, used0, job_count0, tg_count0, bw_avail, bw_used0,
+     eligible, ask, bw_ask) = stacked
+    eligible = _candidates_only(
+        eligible, counts, keys, True, total, used0, job_count0, tg_count0,
+        bw_avail, bw_used0, ask, bw_ask, job_distinct, tg_distinct)
+    stacked = stacked[:7] + (eligible,) + stacked[8:]
     if kernel == "pallas":
         return pallas_solve.solve_waterfill_pallas_batched(
             *stacked, counts, penalties, job_distinct, tg_distinct)
@@ -117,7 +151,7 @@ _EVAL_COLS = (2, 3, 4, 6, 7, 8, 9)
 @partial(jax.jit,
          static_argnames=("k", "job_distinct", "tg_distinct", "mesh"))
 def solve_greedy_rows(shared, rows, counts, penalties, k, job_distinct,
-                      tg_distinct, mesh=None):
+                      tg_distinct, mesh=None, keys=None):
     """ONE program an exact-scan dispatch, whatever its width: the vmap of
     solve_greedy over the eval axis, each row the IDENTICAL sequential
     scan it would run alone (rows never read each other: bit-equal to B
@@ -126,14 +160,18 @@ def solve_greedy_rows(shared, rows, counts, penalties, k, job_distinct,
     exact entries by mirror identity; broadcasting beats materializing B
     copies of the [N, .] node data); ``rows`` is a tuple of B rows of the
     seven per-eval tensors (_EVAL_COLS). The active masks are built in
-    here from ``counts`` (int32[B], host). Returns (idxs[B, k],
-    oks[B, k])."""
+    here from ``counts`` (int32[B], host), and each row's eligibility is
+    narrowed to its evaluation's candidates (``keys``, int32[B], host;
+    None restricts nothing). Returns (idxs[B, k], oks[B, k])."""
     total, sched_cap, bw_avail = shared
     stacked = _stack_columns(rows)
     if mesh is not None:
         stacked = mesh_lib.constrain_eval_stack(
             mesh, stacked, [mesh_lib.WF_SPECS[i] for i in _EVAL_COLS])
     used0, job_count0, tg_count0, bw_used0, eligible, ask, bw_ask = stacked
+    eligible = _candidates_only(
+        eligible, counts, keys, False, total, used0, job_count0, tg_count0,
+        bw_avail, bw_used0, ask, bw_ask, job_distinct, tg_distinct)
     active = jnp.arange(k, dtype=jnp.int32)[None, :] < counts[:, None]
     idxs, oks, _scores = jax.vmap(
         solve_greedy,
@@ -161,10 +199,15 @@ def _panel():
 
 class _Entry:
     __slots__ = ("args", "event", "group", "index", "error", "kind", "k",
-                 "traced", "t_taken", "t_launched", "launch_cpu_s")
+                 "cand_key", "traced", "t_taken", "t_launched",
+                 "launch_cpu_s")
 
-    def __init__(self, args, kind: str = "wf", k: int = 0):
+    def __init__(self, args, kind: str = "wf", k: int = 0,
+                 cand_key: int = NO_KEY):
         self.args = args
+        # The evaluation's candidate key (scheduler/candidates.py): a
+        # per-eval scalar of the dispatch like count and penalty.
+        self.cand_key = cand_key
         self.event = threading.Event()
         self.group: Optional["_Group"] = None
         self.index = 0
@@ -414,12 +457,13 @@ class CoalescingSolver:
         self, total, sched_cap, used0, job_count0, tg_count0, bw_avail,
         bw_used0, eligible, ask, bw_ask, count: int, penalty: float,
         job_distinct: bool = False, tg_distinct: bool = False,
+        cand_key: int = NO_KEY,
     ):
         entry = _Entry((
             total, sched_cap, used0, job_count0, tg_count0, bw_avail,
             bw_used0, eligible, ask, bw_ask, count, penalty,
             bool(job_distinct), bool(tg_distinct),
-        ))
+        ), cand_key=int(cand_key))
         self._enqueue(entry)
         return entry.result
 
@@ -427,18 +471,22 @@ class CoalescingSolver:
         self, total, sched_cap, used0, job_count0, tg_count0, bw_avail,
         bw_used0, eligible, ask, bw_ask, count: int, penalty: float,
         job_distinct: bool = False, tg_distinct: bool = False,
+        cand_key: int = NO_KEY, scan_steps: int = 0,
     ):
         """Queue one exact greedy scan (count <= EXACT_THRESHOLD).
         Concurrent exact solves of one (node bucket, count bucket,
         distinct flags) shape stack on the eval axis and dispatch as ONE
         solve_greedy_rows program — each stacked row runs the
         identical independent scan, so results are bit-equal to a lone
-        dispatch. Returns fetch() -> (node_indices[count], ok[count])."""
+        dispatch. ``scan_steps`` picks the count bucket where it is the
+        larger (the program of the whole group, for a remainder of it).
+        Returns fetch() -> (node_indices[count], ok[count])."""
         entry = _Entry((
             total, sched_cap, used0, job_count0, tg_count0, bw_avail,
             bw_used0, eligible, ask, bw_ask, count, penalty,
             bool(job_distinct), bool(tg_distinct),
-        ), kind="exact", k=bucket(count))
+        ), kind="exact", k=bucket(max(count, scan_steps)),
+            cand_key=int(cand_key))
 
         self._enqueue(entry)
 
@@ -611,7 +659,8 @@ class CoalescingSolver:
         the group and woken. Returns whether it went out as one call."""
         head = entries[0]
         a_dev, b_dev, path, single = _launch_rows(
-            [e.args for e in entries], head.kind, head.k, jd, td)
+            [e.args for e in entries], head.kind, head.k, jd, td,
+            keys=[e.cand_key for e in entries])
         self._count_path(path)
         cls = _ExactGroup if head.kind == "exact" else _Group
         group = cls(a_dev, b_dev, width=len(entries), t0=t0, path=path)
@@ -622,13 +671,14 @@ class CoalescingSolver:
         return single
 
 
-def _launch_rows(rows, kind: str, k: int, jd: bool, td: bool):
+def _launch_rows(rows, kind: str, k: int, jd: bool, td: bool, keys=None):
     """THE launch of a dispatch of either family at any width, shared by
     the dispatcher, its per-entry retry (B = 1) and the warm calls, so
     the warmed set is provably the dispatched set. Off the mesh it is ONE
     call into the device runtime: the riders' rows go in as they are, the
-    per-eval scalars as two small typed host arrays, and everything else
-    (stacking, active masks, the kernel) happens inside the jitted entry.
+    per-eval scalars (count, penalty, candidate key) as three small typed
+    host arrays, and everything else (stacking, candidates, active masks,
+    the kernel) happens inside the jitted entry.
     The eval axis pads to its power-of-two bucket; padding rows repeat
     row 0's arrays with count 0 (a no-op solve). On a configured mesh
     (parallel/mesh.py) the rows' placement calls run first, in front of
@@ -639,6 +689,8 @@ def _launch_rows(rows, kind: str, k: int, jd: bool, td: bool):
     counts = np.array([r[10] for r in rows] + [0] * pad, dtype=np.int32)
     penalties = np.array([r[11] for r in rows] + [0.0] * pad,
                          dtype=np.float32)
+    keys = np.array(list(keys or [NO_KEY] * len(rows)) + [NO_KEY] * pad,
+                    dtype=np.int32)
     rows10 = [r[:10] for r in rows]
     n_padded = rows10[0][0].shape[0]
     mesh = mesh_lib.mesh_for_nodes(n_padded)
@@ -650,12 +702,12 @@ def _launch_rows(rows, kind: str, k: int, jd: bool, td: bool):
         out = solve_greedy_rows(
             tuple(rows10[0][i] for i in _SHARED_COLS),
             tuple(tuple(r[i] for i in _EVAL_COLS) for r in rows10),
-            counts, penalties, k, jd, td, mesh)
+            counts, penalties, k, jd, td, mesh, keys)
     else:
         path = ("pallas" if mesh is None and pallas_solve.selected(n_padded)
                 else "jnp")
         out = solve_waterfill_rows(
-            rows10, counts, penalties, jd, td, path, mesh)
+            rows10, counts, penalties, jd, td, path, mesh, keys)
     return (*out, path, mesh is None)
 
 

@@ -26,9 +26,17 @@ from nomad_tpu.structs import (
 class EvalContext:
     """Context for one evaluation (reference: context.go:59-126)."""
 
-    def __init__(self, state, plan: Plan, logger: Optional[logging.Logger] = None):
+    def __init__(self, state, plan: Plan, logger: Optional[logging.Logger] = None,
+                 attempt: int = 0, eval_index: int = 0):
         self._state = state
         self._plan = plan
+        # Which scheduling attempt of the evaluation this is (0 = the
+        # first; one more after every plan the pipeline refused), and the
+        # raft index that orders the evaluation among its neighbours (its
+        # job's modify index; 0 = none known). The dense stack draws its
+        # candidates from them (scheduler/candidates.py).
+        self.attempt = attempt
+        self.eval_index = eval_index
         self._logger = logger or logging.getLogger("nomad_tpu.sched")
         self._metrics = AllocMetric()
         self.regexp_cache: Dict[str, Pattern] = {}

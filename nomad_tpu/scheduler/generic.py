@@ -70,6 +70,8 @@ class GenericScheduler:
         self.stack: Optional[GenericStack] = None
         self.limit_reached = False
         self.next_eval: Optional[Evaluation] = None
+        # Scheduling attempts of this evaluation so far (retry_max's count)
+        self.attempts = 0
 
     # -- stack construction (overridden by the TPU scheduler) -------------
 
@@ -79,6 +81,7 @@ class GenericScheduler:
     def process(self, ev: Evaluation) -> None:
         """Handle a single evaluation (generic_sched.go:85-114)."""
         self.eval = ev
+        self.attempts = 0
         if ev.triggered_by not in (
             EVAL_TRIGGER_JOB_REGISTER,
             EVAL_TRIGGER_NODE_UPDATE,
@@ -113,7 +116,15 @@ class GenericScheduler:
         (generic_sched.go:116-184)."""
         self.job = self.state.job_by_id(self.eval.job_id)
         self.plan = self.eval.make_plan(self.job)
-        self.ctx = EvalContext(self.state, self.plan, self.logger)
+        # An attempt after a refused plan says so to the stack: the dense
+        # stack then draws fresh candidates for the remainder instead of
+        # meeting the argmax that was refused (scheduler/candidates.py).
+        self.ctx = EvalContext(
+            self.state, self.plan, self.logger, attempt=self.attempts,
+            eval_index=self.eval.job_modify_index
+            or (self.job.modify_index if self.job is not None else 0),
+        )
+        self.attempts += 1
         self.stack = self.make_stack(self.ctx)
         if self.job is not None:
             self.stack.set_job(self.job)

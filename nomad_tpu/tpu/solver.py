@@ -8,8 +8,12 @@ argmax bin-pack on device (nomad_tpu.ops.binpack).
 
 Differences from the host oracle, by design:
 - The host GenericStack ranks only a random ~log2(n) subset of feasible
-  nodes (power-of-two-choices, stack.go:94-121); the dense solve scores
-  every node at no extra cost, so placement quality is >= host.
+  nodes (power-of-two-choices, stack.go:94-121), which is also what keeps
+  its concurrent evaluations apart; the dense solve scores every node of
+  the evaluation's candidate class (scheduler/candidates.py: classes of
+  nodes that do not overlap, the finest one that holds the group, every
+  eligible node where none does), so placement quality is >= host and
+  evaluations in flight still choose different machines.
 - Network *port* assignment stays a host post-pass on the selected node
   (sparse + sequential, network.go:136-194); only dense bandwidth
   feasibility rides the device solve.
@@ -41,7 +45,7 @@ from nomad_tpu.ops.binpack import (
     solve_counts_async,
     solve_many_async,
 )
-from nomad_tpu.scheduler import DEVICE_BREAKER
+from nomad_tpu.scheduler import DEVICE_BREAKER, candidates
 from nomad_tpu.scheduler.context import EvalContext
 from nomad_tpu.scheduler.feasible import _has_distinct_hosts
 from nomad_tpu.scheduler.generic import ALLOC_NOT_NEEDED, GenericScheduler
@@ -190,6 +194,14 @@ class SolverPanel:
         # (ops/coalesce.py _launch_rows): beside the coalescer's own
         # ``dispatches`` it says how often the one-program launch engages.
         self.single_program_dispatches = 0
+        # The candidate rule (scheduler/candidates.py): exact scans that
+        # ran on a class of their evaluation's key, those that left the
+        # key's finest class because it could not hold the group, and the
+        # scheduling attempts (one a plan submitted or found empty) of
+        # the evaluations the dense schedulers processed.
+        self.sampled_solves = 0
+        self.widened_solves = 0
+        self.schedule_attempts = 0
         # Equivalence classes (Borg §'equivalence class'): identical
         # task groups of one job collapsed to one solve row with a
         # multiplicity count. rows_saved = solves that never dispatched.
@@ -292,6 +304,18 @@ class SolverPanel:
         with self._lock:
             self.single_program_dispatches += 1
 
+    def record_candidates(self, sampled: bool, widened: bool) -> None:
+        """One exact scan under a candidate key: ``sampled`` where it ran
+        on a class of nodes and not the whole cell, ``widened`` where it
+        placed outside the key's finest class."""
+        with self._lock:
+            self.sampled_solves += bool(sampled)
+            self.widened_solves += bool(widened)
+
+    def record_attempt(self) -> None:
+        with self._lock:
+            self.schedule_attempts += 1
+
     def record_staging(self, wall_ms: float, cpu_ms: float) -> None:
         with self._lock:
             self.staging_wall_ms += wall_ms
@@ -382,6 +406,9 @@ class SolverPanel:
                 "batch_evals": sum(
                     e for _d, e, _m in self._batch_widths.values()),
                 "single_program_dispatches": self.single_program_dispatches,
+                "sampled_solves": self.sampled_solves,
+                "widened_solves": self.widened_solves,
+                "schedule_attempts": self.schedule_attempts,
                 "staging_wall_ms": round(self.staging_wall_ms, 3),
                 "staging_cpu_ms": round(self.staging_cpu_ms, 3),
                 "staging_blocked_ms": round(max(
@@ -479,10 +506,12 @@ class _SolveInputs:
     __slots__ = (
         "mask", "used", "job_count", "tg_count", "bw_used",
         "ask", "ask_np", "bw_ask", "bw_ask_val", "job_distinct", "tg_distinct",
+        "cand_key",
     )
 
     def __init__(self, mask, used, job_count, tg_count, bw_used, ask, ask_np,
-                 bw_ask, bw_ask_val, job_distinct, tg_distinct):
+                 bw_ask, bw_ask_val, job_distinct, tg_distinct,
+                 cand_key=candidates.NO_KEY):
         self.mask = mask
         self.used = used
         self.job_count = job_count
@@ -494,6 +523,7 @@ class _SolveInputs:
         self.bw_ask_val = bw_ask_val
         self.job_distinct = job_distinct
         self.tg_distinct = tg_distinct
+        self.cand_key = cand_key
 
 
 class TPUStack:
@@ -515,7 +545,10 @@ class TPUStack:
         self.mirror: Optional[NodeMirror] = None
 
     def set_nodes(self, nodes: List[Node]) -> None:
-        # No shuffle needed: the solve is a global argmax, not a sampled scan.
+        # No shuffle: what keeps evaluations in flight from one argmax is
+        # the candidate key drawn in prepare() (scheduler/candidates.py):
+        # each solves over its own class of the mirror's rows, the finest
+        # that holds the group, and over every eligible node where none does.
         self.mirror = NodeMirror(nodes)
 
     def set_mirror(self, mirror: NodeMirror) -> None:
@@ -529,11 +562,18 @@ class TPUStack:
 
     # -- core batched solve ------------------------------------------------
 
-    def solve_group(self, tg: TaskGroup, count: int, overlap=None):
+    def solve_group(self, tg: TaskGroup, count: int, overlap=None,
+                    group_count: int = 0):
         """One batched device solve for ``count`` copies of a task group:
         eligibility masks + usage tensorization + dispatch + readback. This
         is the reformulated Stack.Select loop (stack.go:131-159) and the
         north-star timed phase.
+
+        ``group_count`` is the group's whole size where ``count`` is what
+        is left of it to place (a remainder after a refused plan, a
+        scale-up): the program is then the one the whole group solves
+        with, the exact scan of its count bucket or the water-fill, so
+        that a remainder never brings a program of its own to compile.
 
         Returns (idxs, oks, size): numpy node indices / ok flags per copy
         (idxs is None when the node set is empty). ``overlap``, if given, is
@@ -557,6 +597,8 @@ class TPUStack:
                 return None, None, tg_constr.size
 
             _check_device_fault(tg.name)
+            steps = max(count, group_count)
+            exact = steps <= EXACT_THRESHOLD
             t_dispatch = time.perf_counter()
             with _device_dispatch():
                 with st.stage("transfer"):
@@ -565,21 +607,24 @@ class TPUStack:
                         prep.job_count, prep.tg_count, self.mirror.bw_avail,
                         prep.bw_used, prep.mask, prep.ask, prep.bw_ask, count,
                         self.penalty, job_distinct=prep.job_distinct,
-                        tg_distinct=prep.tg_distinct,
+                        tg_distinct=prep.tg_distinct, cand_key=prep.cand_key,
+                        exact_threshold=EXACT_THRESHOLD if exact else -1,
+                        scan_steps=steps if exact else 0,
                     )
                 if overlap is not None:
                     overlap()
                 idxs, oks = fetch()
         self.ctx.metrics().allocation_time = time.perf_counter() - start
         _emit_solver_trace(st, start, count)
-        exact = count <= EXACT_THRESHOLD
+        if exact:
+            self._record_candidates(prep.cand_key, idxs[oks])
         # Panel wall = the dispatch→readback window only: staging
         # (constraint masks, mirror usage build) is HOST work and must
         # not inflate the device-time books.
         SOLVER_PANEL.record_solve(
             "exact" if exact else "waterfill",
             self.mirror.n, self.mirror.padded,
-            count, bucket(count) if exact else 0,
+            count, bucket(steps) if exact else 0,
             int(np.count_nonzero(oks)),
             (time.perf_counter() - t_dispatch) * 1000.0,
         )
@@ -614,7 +659,7 @@ class TPUStack:
                         prep.job_count, prep.tg_count, self.mirror.bw_avail,
                         prep.bw_used, prep.mask, prep.ask, prep.bw_ask, count,
                         self.penalty, job_distinct=prep.job_distinct,
-                        tg_distinct=prep.tg_distinct,
+                        tg_distinct=prep.tg_distinct, cand_key=prep.cand_key,
                     )
                 if overlap is not None:
                     overlap()
@@ -627,6 +672,20 @@ class TPUStack:
             (time.perf_counter() - t_dispatch) * 1000.0,
         )
         return counts, unplaced, tg_constr.size
+
+    def _record_candidates(self, cand_key: int, placed_rows) -> None:
+        """Book one exact scan under the candidate rule, from where its
+        placements lie: on a class of its key (sampled) or over the whole
+        cell, and outside the key's finest class (widened: that class
+        could not hold the group; so is a scan that placed nothing, which
+        tried every level)."""
+        bits = candidates.class_bits(self.mirror.padded)
+        if cand_key < 0 or bits == 0:
+            return
+        level = (candidates.level_of(self.mirror.padded, cand_key,
+                                     placed_rows)
+                 if len(placed_rows) else 0)
+        SOLVER_PANEL.record_candidates(level > 0, level < bits)
 
     def select_many(self, tg: TaskGroup, count: int) -> Tuple[List[Optional[_Placement]], Resources]:
         """Place ``count`` copies of a task group in one batched device solve.
@@ -681,12 +740,16 @@ class TPUStack:
         with trace.stage("staging.upload"):
             ask_dev = device_const("ask", ask_vec)
             bw_ask_dev = device_const("i32", bw_ask_val)
+        # The system stack pins every placement to its node: no candidates.
+        cand_key = (candidates.NO_KEY if self.system
+                    else candidates.draw_key(self.ctx))
         return _SolveInputs(
             mask=mask_dev, used=used, job_count=job_count,
             tg_count=tg_count, bw_used=bw_used,
             ask=ask_dev, ask_np=ask_np, bw_ask=bw_ask_dev,
             bw_ask_val=bw_ask_val,
             job_distinct=job_distinct, tg_distinct=tg_distinct,
+            cand_key=cand_key,
         )
 
     def _offer_networks(
@@ -779,6 +842,10 @@ class TPUGenericScheduler(GenericScheduler):
 
     def make_stack(self, ctx: EvalContext) -> TPUStack:
         return TPUStack(ctx, batch=self.batch)
+
+    def _process(self) -> bool:
+        SOLVER_PANEL.record_attempt()
+        return super()._process()
 
     def compute_job_allocs(self) -> None:
         """Columnar reconcile fast path, skipping name materialization and
@@ -1703,7 +1770,8 @@ class TPUGenericScheduler(GenericScheduler):
             # blocks (GIL released) in the device readback inside solve_group.
             uuid_future = _uuid_pool().submit(generate_uuids, count)
 
-            idxs, oks, size = self.stack.solve_group(tg, count)
+            idxs, oks, size = self.stack.solve_group(
+                tg, count, group_count=tg.count)
             uuids = uuid_future.result()
 
             has_networks = any(
