@@ -95,20 +95,15 @@ class _NodeTable:
     writes, while usage is re-read from the snapshot every call."""
 
     __slots__ = ("rows", "totals", "reserved", "dead", "scalar_only", "n",
-                 "block_rows_cache", "_mirror_maps", "block_usage_cache")
+                 "_mirror_maps", "block_usage_cache")
 
     def __init__(self, snap):
         import numpy as np
 
         nodes = snap.nodes()
         self.n = len(nodes)
-        # id(block) -> (block, rows, counts): per-block node-run row
-        # resolution, valid for this table's lifetime (blocks are COW).
-        self.block_rows_cache = {}
-        # (id-set, block refs, usage[N,4], net_rows) of the last
-        # _existing_block_usage_rows accumulation — extended
-        # incrementally while the block set only grows (the applier's
-        # monotonic verify sequence), recomputed on any removal.
+        # _BlockUsage of the last _existing_block_usage_rows call: rolled
+        # by the blocks that came and went since, per block.
         self.block_usage_cache = None
         # id(mirror id array) -> (array, table rows aligned with it):
         # one string resolve per (table, mirror) pair; every plan built
@@ -194,8 +189,7 @@ class _NodeTable:
             new.rows = rows
             # Row numbering of existing nodes didn't move, but cached
             # resolutions may hold -1 for the appended ids and the usage
-            # accumulator is row-aligned: rebuild those lazily.
-            new.block_rows_cache = {}
+            # accumulator is row-aligned: rebuild it lazily.
             import collections
             new._mirror_maps = collections.OrderedDict()
             new.block_usage_cache = None
@@ -204,7 +198,6 @@ class _NodeTable:
             # Pure row patches leave row numbering AND block usage
             # (a function of blocks, not node fields) intact: share the
             # warm caches with the ancestor.
-            new.block_rows_cache = self.block_rows_cache
             new._mirror_maps = self._mirror_maps
             new.block_usage_cache = self.block_usage_cache
         new.totals = totals
@@ -506,22 +499,28 @@ def _existing_block_usage(snap):
     return usage, net_nodes, blocks
 
 
-def _block_rows_cached(table, blk):
-    """(rows int64[k], counts int64[k]) for a block's live node runs,
-    resolved against ``table`` once per (table, block) pair. Blocks are
-    copy-on-write (any exclusion/update commits a NEW object,
-    state/blocks.py), so the identity key can never serve stale runs;
-    holding the block in the cache entry pins its id. Without this, every
-    plan verify re-resolved every existing block's ~10k node ids through
-    the row dict — the dominant cost of the coalesced pipeline's later
-    verifies."""
+# Lifetime totals of the verifier's block-usage accumulator, surfaced
+# through PlanPipeline.stats(): accumulations from nothing, and stored
+# blocks the roll subtracted.
+_BLOCK_USAGE_LOCK = threading.Lock()
+_BLOCK_USAGE_TOTALS = {"block_usage_rebuilds": 0, "block_usage_removals": 0}
+
+
+def block_usage_stats() -> dict:
+    with _BLOCK_USAGE_LOCK:
+        return dict(_BLOCK_USAGE_TOTALS)
+
+
+def _block_contrib(table, blk):
+    """What one stored block adds to the verifier's usage: (block, rows,
+    counts, vec) — its live node runs resolved to ``table`` rows (unknown
+    ids dropped) and its resource vector, or vec None for a block with
+    network asks, which marks its rows instead of adding to them. Resolved
+    once per (table, block): blocks are copy-on-write (any exclusion or
+    update commits a NEW object, state/blocks.py), so a block's runs never
+    change under its identity."""
     import numpy as np
 
-    cache = table.block_rows_cache
-    entry = cache.get(id(blk))
-    if entry is not None and entry[0] is blk:
-        return entry[1], entry[2]
-    get = table.rows.get
     if blk.excluded:
         pairs = list(blk.live_node_counts())
         nids = [p[0] for p in pairs]
@@ -529,69 +528,106 @@ def _block_rows_cached(table, blk):
     else:
         nids = blk.node_ids
         counts = np.asarray(blk.node_counts, dtype=np.int64)
+    get = table.rows.get
     rows = np.fromiter(
         (get(nid, -1) for nid in nids), dtype=np.int64, count=len(nids)
     )
-    cache[id(blk)] = (blk, rows, counts)
-    if len(cache) > 256:
-        cache.clear()
-    return rows, counts
+    valid = rows >= 0
+    if not valid.all():
+        rows, counts = rows[valid], counts[valid]
+    vec = (None if _block_has_net(blk)
+           else np.asarray(blk.resource_vector(), dtype=np.int64))
+    return blk, rows, counts, vec
 
 
-def _accumulate_block_usage(table, blocks, usage, net_rows):
-    """Fold ``blocks`` into (usage[N,4], net_rows) — one np.add.at per
-    block, per-block row resolution cached on the table. Mutates and
-    returns the passed arrays (callers own them)."""
-    import numpy as np
+class _BlockUsage:
+    """Stored blocks' usage over one node table's rows: ``usage``
+    int64[N,4] (None until a block without network asks arrives),
+    ``net_rows`` bool[N] (None until a block with them arrives) from a
+    per-row count of such blocks, and ``contrib`` {id(block): what
+    _block_contrib resolved}, whose refs pin the ids. Immutable once
+    published on the table — ``rolled`` returns a new one over copied
+    arrays, so results handed to concurrent readers never change."""
 
-    for blk in blocks:
-        rows, counts = _block_rows_cached(table, blk)
-        valid = rows >= 0
-        if _block_has_net(blk):
-            if net_rows is None:
-                net_rows = np.zeros(table.n, dtype=bool)
-            net_rows[rows[valid]] = True
-            continue
-        vec = np.asarray(blk.resource_vector(), dtype=np.int64)
-        if usage is None:
-            usage = np.zeros((table.n, 4), dtype=np.int64)
-        np.add.at(usage, rows[valid], vec[None, :] * counts[valid, None])
-    return usage, net_rows
+    __slots__ = ("contrib", "usage", "net_count", "net_rows")
+
+    def __init__(self):
+        self.contrib = {}
+        self.usage = None
+        self.net_count = None
+        self.net_rows = None
+
+    def rolled(self, table, gone, new) -> "_BlockUsage":
+        """This usage less the contributions keyed in ``gone``, plus the
+        blocks in ``new``: O(runs of the changed blocks)."""
+        import numpy as np
+
+        out = _BlockUsage()
+        contrib = out.contrib = dict(self.contrib)
+        subs = [contrib.pop(k) for k in gone]
+        adds = [_block_contrib(table, blk) for blk in new]
+        for c in adds:
+            contrib[id(c[0])] = c
+        usage, net_count = self.usage, self.net_count
+        if any(c[3] is not None for c in subs + adds):
+            usage = (np.zeros((table.n, 4), dtype=np.int64) if usage is None
+                     else usage.copy())
+        if any(c[3] is None for c in subs + adds):
+            net_count = (np.zeros(table.n, dtype=np.int32)
+                         if net_count is None else net_count.copy())
+        for sign, group in ((-1, subs), (1, adds)):
+            for _blk, rows, counts, vec in group:
+                if vec is None:
+                    np.add.at(net_count, rows, sign)
+                else:
+                    np.add.at(usage, rows,
+                              (sign * vec)[None, :] * counts[:, None])
+        out.usage = usage
+        out.net_count = net_count
+        out.net_rows = (self.net_rows if net_count is self.net_count
+                        else net_count > 0)
+        return out
+
+
+def _accumulate_block_usage(table, blocks) -> _BlockUsage:
+    """The usage of ``blocks`` from nothing: a table's first call, and the
+    reference every roll equals."""
+    return _BlockUsage().rolled(table, (), blocks)
 
 
 def _existing_block_usage_rows(snap, table):
     """Vectorized block usage over node-table rows: (usage[N,4] int64 or
     None, net_rows bool[N] or None, blocks).
 
-    Incremental across the applier's verify sequence: blocks are COW
-    (any exclusion/update/removal commits NEW objects), so while the
-    snapshot's block identity-set only GROWS relative to the cached
-    accumulation, only the new blocks fold in — a burst of K commits
-    costs O(total runs) across its K verifies instead of O(K x total).
-    Any removal (shrunk or replaced block) recomputes from scratch. The
-    cache holds the block refs, pinning their ids against reuse; arrays
-    are copied before extension so results already handed to concurrent
-    readers never mutate underneath them."""
+    Rolls across the verify sequence in both directions: blocks are COW
+    (any exclusion/update commits NEW objects, a whole-block stop takes
+    one out), so the snapshot's blocks are diffed by identity against the
+    table's accumulation — each block gone is subtracted, each new one
+    added, a replaced one (an exclusion, or the store's twin of an
+    optimistic copy) is one of each. O(blocks) for the diff plus the runs
+    of the changed blocks; from nothing only on a table's first call (a
+    table re-numbered by appended nodes starts without one). Concurrent
+    callers (the committer, the scheduler's headroom base) publish by one
+    assignment."""
     blocks = snap.alloc_blocks()
-    cache = table.block_usage_cache
-    cur_ids = {id(b) for b in blocks}
-    if cache is not None:
-        cached_ids, _cached_refs, usage, net_rows = cache
-        if cached_ids <= cur_ids:
-            new = [b for b in blocks if id(b) not in cached_ids]
-            if not new:
-                return usage, net_rows, blocks
-            usage = None if usage is None else usage.copy()
-            net_rows = None if net_rows is None else net_rows.copy()
-            usage, net_rows = _accumulate_block_usage(
-                table, new, usage, net_rows
-            )
-            table.block_usage_cache = (cur_ids, list(blocks), usage,
-                                       net_rows)
-            return usage, net_rows, blocks
-    usage, net_rows = _accumulate_block_usage(table, blocks, None, None)
-    table.block_usage_cache = (cur_ids, list(blocks), usage, net_rows)
-    return usage, net_rows, blocks
+    acc = table.block_usage_cache
+    if acc is None:
+        acc = _accumulate_block_usage(table, blocks)
+        with _BLOCK_USAGE_LOCK:
+            _BLOCK_USAGE_TOTALS["block_usage_rebuilds"] += 1
+    else:
+        cur = {id(b): b for b in blocks}
+        contrib = acc.contrib
+        gone = [k for k in contrib if k not in cur]
+        new = [b for k, b in cur.items() if k not in contrib]
+        if not gone and not new:
+            return acc.usage, acc.net_rows, blocks
+        acc = acc.rolled(table, gone, new)
+        if gone:
+            with _BLOCK_USAGE_LOCK:
+                _BLOCK_USAGE_TOTALS["block_usage_removals"] += len(gone)
+    table.block_usage_cache = acc
+    return acc.usage, acc.net_rows, blocks
 
 
 def _prevaluate_nodes_bulk(snap, plan: Plan, ask: _AskAccum = None,

@@ -44,6 +44,7 @@ from nomad_tpu.server.plan_apply import (
     _existing_block_usage_rows,
     _node_table,
     _object_allocs,
+    block_usage_stats,
     evaluate_plan,
     stops_only,
 )
@@ -398,11 +399,13 @@ class PlanPipeline(threading.Thread):
         self._stop.set()
 
     def stats(self) -> Dict[str, int]:
-        """The process-wide totals, and what this pipeline's own FSM made
-        of the stop batches it was sent (only the FSM knows whether a
-        block still stood as the plan saw it)."""
+        """The process-wide totals, the verifier's block-usage rebuilds
+        and removals, and what this pipeline's own FSM made of the stop
+        batches it was sent (only the FSM knows whether a block still
+        stood as the plan saw it)."""
         return {
             **self.totals.stats(),
+            **block_usage_stats(),
             "stop_batch_members": self.fsm.stop_batch_members,
             "stop_batch_fallback_members":
                 self.fsm.stop_batch_fallback_members,

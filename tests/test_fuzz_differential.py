@@ -1951,3 +1951,146 @@ def test_batched_plan_verify_fused_engagement_parity(seed):
     assert totals.fused_plans > 0, (
         f"seed {seed}: fused pass never engaged on its home distribution"
     )
+
+
+@pytest.mark.parametrize("seed", range(0, N_BATCH_VERIFY_SEEDS, 2))
+def test_batched_plan_verify_with_stops_matches_sequential(seed):
+    """Same parity contract with whole-block stops inside the batch:
+    stop-only plans interleaved with fused columnar plans and scalar ones
+    (network asks, object rows, asks that overflow) over a cell that the
+    stored blocks nearly fill, so what a stop frees decides what later
+    plans commit. The node table's block usage is rolled by both
+    snapshots in turn (the sequential one and the batched one share the
+    table), and each snapshot's usage must still equal a from-nothing
+    accumulation at the end."""
+    import copy as _copy
+    import itertools
+
+    from nomad_tpu.server import plan_apply
+    from nomad_tpu.server.plan_apply import (
+        _accumulate_block_usage,
+        _existing_block_usage_rows,
+        evaluate_plan,
+    )
+    from nomad_tpu.server.plan_pipeline import (
+        _PipelineTotals,
+        apply_result_to_snapshot,
+        evaluate_plans,
+    )
+    from nomad_tpu.state import StateStore
+    from nomad_tpu.structs import AllocBatch, AllocStopBatch, Plan
+
+    rng = np.random.default_rng(90_000 + seed)
+    with plan_apply._NODE_TABLE_LOCK:
+        plan_apply._NODE_TABLE_CACHE = None
+    store = StateStore()
+    n_nodes = int(rng.integers(4, 16))
+    for i in range(n_nodes):
+        store.upsert_node(i + 1, Node(
+            id=f"sp-{i:03d}", datacenter="dc1", name=f"sp{i}",
+            status="ready",
+            resources=Resources(cpu=4000, memory_mb=8192,
+                                disk_mb=100_000, iops=10_000),
+        ))
+    idx = n_nodes
+    ids = [n.id for n in store.nodes()]
+
+    def _mk_batch(cpu, net=False):
+        picks = [str(rng.choice(ids))
+                 for _ in range(int(rng.integers(1, 5)))]
+        counts = [int(rng.integers(4, 30)) for _ in picks]
+        res = Resources(cpu=cpu, memory_mb=int(rng.integers(8, 32)))
+        if net:
+            res.networks = [NetworkResource(device="eth0", mbits=1)]
+        return AllocBatch(
+            eval_id=generate_uuid(), job=None, tg_name="web",
+            resources=res, task_resources={"t": res}, metrics=None,
+            node_ids=picks, node_counts=counts,
+            name_idx=np.arange(sum(counts)),
+            ids_seed=int(rng.integers(1, 2**63)),
+        )
+
+    # Stored blocks that leave little room: a placement after a stop
+    # fits where it would not have before it.
+    for _ in range(int(rng.integers(4, 10))):
+        idx += 1
+        store.upsert_alloc_blocks(
+            idx, [_mk_batch(int(rng.integers(20, 60)))])
+    live = list(store.snapshot().alloc_blocks())
+    rng.shuffle(live)
+
+    k = int(rng.integers(4, 10))
+    plans = []
+    for p in range(k):
+        plan = Plan(eval_id=f"sp-{seed}-{p}", priority=50)
+        shape = rng.random()
+        if (shape < 0.3 or p == 0) and live:
+            blks = [live.pop() for _ in range(min(len(live),
+                                                  int(rng.integers(1, 3))))]
+            plan.stop_batches = [AllocStopBatch(
+                eval_id=plan.eval_id, job_id=b.job_id, block_id=b.block_id,
+                n_live=b.n_live, n_total=b.n, ids_seed=b.ids_seed,
+                desired_status=structs.ALLOC_DESIRED_STATUS_STOP,
+                desired_description="gone", node_ids=b.node_ids)
+                for b in blks]
+        elif shape < 0.75:
+            for _ in range(int(rng.integers(1, 3))):
+                plan.append_batch(_mk_batch(int(rng.integers(10, 60))))
+        elif shape < 0.9:
+            plan.append_batch(_mk_batch(int(rng.integers(10, 40)),
+                                        net=True))
+        else:
+            for s in range(int(rng.integers(1, 4))):
+                nid = str(rng.choice(ids))
+                plan.node_allocation.setdefault(nid, []).append(
+                    _pv_alloc(rng, nid, s, cpu=int(rng.integers(50, 900))))
+        plans.append(plan)
+
+    plans_seq = _copy.deepcopy(plans)
+    plans_fused = _copy.deepcopy(plans)
+    snap_seq = store.snapshot()
+    snap_fused = store.snapshot()
+    stamp_seq = itertools.count(100_000)
+    stamp_fused = itertools.count(100_000)
+
+    want = []
+    for plan in plans_seq:
+        res = evaluate_plan(snap_seq, plan)
+        if not res.is_noop():
+            apply_result_to_snapshot(snap_seq, res, next(stamp_seq))
+        want.append((_pv_decisions(res),
+                     [b.block_id for b in res.stop_batches]))
+
+    totals = _PipelineTotals()
+    got_results = evaluate_plans(
+        snap_fused, plans_fused,
+        stamp_index=lambda: next(stamp_fused), totals=totals)
+    got = [(_pv_decisions(r), [b.block_id for b in r.stop_batches])
+           for r in got_results]
+
+    assert got == want, f"seed {seed}: verify with stops diverged"
+    assert totals.stats()["stop_plans"] == sum(
+        1 for p in plans if p.stop_batches)
+
+    def _blocks(snap):
+        return sorted(
+            (tuple(b.node_ids), tuple(int(c) for c in b.node_counts),
+             tuple(sorted(b.excluded)))
+            for b in snap.alloc_blocks())
+    assert _blocks(snap_fused) == _blocks(snap_seq)
+    assert sorted(b.block_id for b in snap_fused.stopped_alloc_blocks()) \
+        == sorted(b.block_id for b in snap_seq.stopped_alloc_blocks())
+    table = plan_apply._node_table(snap_fused)
+    for snap in (snap_seq, snap_fused, store.snapshot()):
+        usage, net_rows, _ = _existing_block_usage_rows(snap, table)
+        want = _accumulate_block_usage(table, snap.alloc_blocks())
+        want_u, want_n = want.usage, want.net_rows
+        np.testing.assert_array_equal(
+            np.zeros((table.n, 4), dtype=np.int64) if usage is None
+            else usage,
+            np.zeros((table.n, 4), dtype=np.int64) if want_u is None
+            else want_u)
+        assert (net_rows is None or not net_rows.any()) == (
+            want_n is None or not want_n.any())
+        if want_n is not None and net_rows is not None:
+            np.testing.assert_array_equal(net_rows, want_n)
