@@ -1,7 +1,16 @@
 """Job shapes: a mock.Job()-shaped job built from a configuration's
 ``task`` group and, where a mix gives ``templates``, its ``task_shapes``.
 The benchmark's own copy of ``build_job`` from
-nomad_tpu/simcluster/workload.py, with the shapes read from data."""
+nomad_tpu/simcluster/workload.py, with the shapes read from data.
+
+A spec of a mix that updates its jobs carries two keys more: ``update``
+(``{stagger_s, max_parallel}``, the job's rolling update strategy) and
+``version`` (0 when first registered, one more with each update). The
+version is stamped into every task's ``env`` under ``VERSION_ENV``: a
+change of ``env`` is a destructive update to the server, which evicts
+and places the job's tasks anew, and the stamp is how a row read back
+from the state store tells its version (``version_of``). A spec without
+the keys builds the job it built before they existed, byte for byte."""
 
 from __future__ import annotations
 
@@ -14,9 +23,11 @@ from nomad_tpu.structs import (
     RestartPolicy,
     Task,
     TaskGroup,
+    UpdateStrategy,
 )
 
 PRIORITY = 50
+VERSION_ENV = "JOB_VERSION"
 
 
 def job_spec(task: Dict, job_id: str, jtype: str, count: int = 0,
@@ -60,8 +71,11 @@ def template_spec(config: Dict, template: Dict, job_id: str,
 
 
 def build_job(spec: Dict) -> Job:
-    """One ``TaskGroup`` of one ``Task`` for each group, in order."""
-    return Job(
+    """One ``TaskGroup`` of one ``Task`` for each group, in order; the
+    update strategy and the version's stamp where the spec has them."""
+    env = ({VERSION_ENV: str(int(spec["version"]))} if "version" in spec
+           else {})
+    job = Job(
         region="global", id=spec["id"], name=spec["id"], type=spec["type"],
         priority=spec["priority"], datacenters=list(spec["datacenters"]),
         constraints=[Constraint(l_target=lt, operand=op, r_target=rt)
@@ -72,8 +86,22 @@ def build_job(spec: Dict) -> Job:
                 attempts=1, interval=600.0, delay=5.0),
             tasks=[Task(
                 name=g["name"], driver=spec["driver"],
+                env=dict(env),
                 resources=Resources(cpu=g["cpu"],
                                     memory_mb=g["memory_mb"]),
             )],
         ) for g in spec["groups"]],
     )
+    if "update" in spec:
+        job.update = UpdateStrategy(
+            stagger=float(spec["update"]["stagger_s"]),
+            max_parallel=int(spec["update"]["max_parallel"]))
+    return job
+
+
+def version_of(job: Optional[Job], group: str) -> Optional[int]:
+    """The version stamped into ``job``'s group ``group`` (the job an
+    allocation embeds), or None where it carries no stamp."""
+    tg = job.lookup_task_group(group) if job is not None else None
+    stamp = tg.tasks[0].env.get(VERSION_ENV) if tg and tg.tasks else None
+    return int(stamp) if stamp is not None else None
