@@ -2,8 +2,11 @@
 
     JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q
 
-They hold: every cell of BENCHMARK.json resolves to its files by name;
-the result line has exactly the contract's keys; work.py's byte counts
+They hold: every cell of BENCHMARK.json resolves to its files by name,
+and a metric's cells are listed there alone; the result line has exactly
+the contract's keys, and a rehearsal reports what the cells of its mix
+report, as BENCHMARK.json lists them (no test pins today's list of
+cells, which later entries extend); work.py's byte counts
 against hand-worked shapes; the xplane reduction on a small recorded
 trace; the traffic plan is reproducible from the seed and offers every
 seed the same work; a drain window ends when its work ends (no round
@@ -78,6 +81,32 @@ def test_every_metric_file_is_listed_and_every_listed_metric_has_a_file():
     files = {f[:-len(".json")] for f in os.listdir(
         os.path.join(HERE, "metrics"))}
     assert listed == files
+
+
+# The eight metrics that borg-12k.mixed-shapes brought keep a copy of
+# their list in the file while tests/test_benchmark_mixed_shapes.py reads
+# it there; nothing in the harness reads it.
+FILE_LIST_READ_BY_A_TEST = {
+    "schedule_attempts_per_eval.mixed", "solves_per_eval.mixed",
+    "sampled_solve_share.mixed", "widened_per_sampled_solve.mixed",
+    "staging_mask_mean_ms.mixed", "staging_usage_job_mean_ms.mixed",
+    "greedy_kernel_us.mixed", "broker_wait_mean_ms.mixed",
+}
+
+
+def test_no_metric_file_carries_its_own_list_of_cells():
+    # BENCHMARK.json's per_layer entry is a metric's one list of cells:
+    # run.py reads a metric file's ``source`` alone, and a second list in
+    # the file goes stale with every cell that an entry adds.
+    listed = {m["name"]: m for m in BENCH["per_layer"]}
+    for f in sorted(os.listdir(os.path.join(HERE, "metrics"))):
+        spec, name = run.load_json("metrics", f), f[:-len(".json")]
+        assert set(spec) - {"workloads"} == {
+            "name", "layer", "unit", "better", "moves", "source"}, f
+        if name in FILE_LIST_READ_BY_A_TEST:
+            assert set(spec["workloads"]) <= set(listed[name]["workloads"])
+        else:
+            assert "workloads" not in spec, f
 
 
 def test_unlisted_cell_needs_a_rehearsal_configuration():
@@ -1419,14 +1448,29 @@ def report_of(out):
     return json.loads(out.out.strip().splitlines()[-2])
 
 
+def end_to_end_of(rehearsal):
+    """The end-to-end metrics a ``--trace 0`` run of a rehearsal reports:
+    ``run.Cell``'s list, held equal to the metrics of BENCHMARK.json that
+    name no cell or one of the cells the rehearsal's mix stands for."""
+    cell = run.Cell(rehearsal)
+    cells = {w["name"] for w in BENCH["workloads"]
+             if w["traffic"] == cell.mix["metrics_of"]}
+    want = {m["name"] for m in cell.end_to_end()}
+    assert "setup_s" in want and want == {
+        m["name"] for m in BENCH["end_to_end"]
+        if "workloads" not in m or cells & set(m["workloads"])}
+    return want
+
+
 def test_result_line_has_the_contracts_keys(capsys):
     result, out = drive(capsys)
     assert set(result) == RESULT_KEYS | {"compared"}
     assert list(result)[-1] == "compared"
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] > 0
-    assert set(result["metrics"]) == {"placed_p50_ms",
-                                      "setup_s"}
+    want = end_to_end_of("rehearsal-256.rehearsal-steady")
+    assert {"placed_p50_ms", "setup_s"} <= want
+    assert set(result["metrics"]) == want
     for m in result["metrics"].values():
         assert set(m) == {"value", "unit"} and m["value"] > 0
     assert set(result["device"]) == {"platform", "kind", "count",
@@ -1494,7 +1538,9 @@ def test_churn_places_and_stops_a_wave_each_round(capsys):
     assert report["placed_in_window"] == placed == report["asked"]
     assert report["stops"] == report["stops_asked"] == placed
     assert result["attempted"] == 2 * placed
-    assert set(result["metrics"]) == {"placements_per_s", "setup_s"}
+    want = end_to_end_of("rehearsal-256.rehearsal-churn")
+    assert {"placements_per_s", "setup_s"} <= want
+    assert set(result["metrics"]) == want
     assert result["compared"]["stopped_running"] == {"value": 0, "limit": 0}
     # The window closes with its last round's end: the rate is the
     # placements over a length that holds every round's stops too.
@@ -1535,7 +1581,7 @@ def test_rehearsal_update_rolls_every_job_and_its_controls_fail(capsys):
         "rounds"] == result["attempted"] == report["placed_in_window"]
     assert report["chain_evals"]["min"] >= 4          # ceil(40 / 10)
     assert report["slots_left"] == 240 * 320 - 360
-    assert set(result["metrics"]) == {"setup_s"}
+    assert set(result["metrics"]) == end_to_end_of(UPDATE)
     assert set(result["compared"]) == set(reference.LIMITS)
     assert all(v["value"] == 0 for v in result["compared"].values())
     assert all(len(r) == 3 for r in report["round_log"])
@@ -1565,7 +1611,9 @@ def test_mixed_shapes_fill_the_cell_to_its_limit_and_end_there(capsys):
         "jobs_not_whole"]
     assert result["attempted"] == 4 * 401 == report["placed_in_window"]
     assert report["jobs_due"] == 4 * 3 and report["jobs_not_whole"] == []
-    assert set(result["metrics"]) == {"placements_per_s", "setup_s"}
+    want = end_to_end_of(MIXED)
+    assert {"placements_per_s", "setup_s"} <= want
+    assert set(result["metrics"]) == want
     assert all(v["value"] == 0 for v in result["compared"].values())
     # One plan an evaluation, and none refused: no two jobs share a node.
     assert report["plans_per_eval"] == {"1": 12}
@@ -1961,6 +2009,11 @@ def test_benchmark_json_meets_the_contracts_limits():
     assert b["paths"] == ["benchmark"] and 1 <= b["run_seconds"] <= 51
     assert all(_line(w) for w in b["command"]) and len(b["command"]) <= 32
     cells = {w["name"] for w in b["workloads"]}
+    assert 1 <= len(b["configs"]) <= 24 and 1 <= len(b["workloads"]) <= 24
+    assert 1 <= len(b["end_to_end"]) <= 16 and 1 <= len(b["per_layer"]) <= 128
+    # Of the cells at most half, rounded down, ask for four chips; one may.
+    four = sum(w["chips"] == 4 for w in b["workloads"])
+    assert four <= max(1, len(b["workloads"]) // 2)
     for c in b["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert NAME.match(c["name"]) and _line(c["source"]) and _line(c["why"])
