@@ -21,7 +21,7 @@ from collections import deque
 from concurrent.futures import Future
 from typing import Any, Callable, Dict, Optional, TYPE_CHECKING
 
-from nomad_tpu import faults, telemetry, trace
+from nomad_tpu import cpu_observe, faults, telemetry, trace
 
 if TYPE_CHECKING:  # injected collaborator; import would be circular
     from nomad_tpu.server.eval_broker import EvalBroker
@@ -79,6 +79,7 @@ class FSM:
             "alloc_client_update": self._apply_alloc_client_update,
         }
 
+    @cpu_observe.BOOK.apply.charge()
     def apply(self, index: int, msg_type: str, payload: dict) -> Any:
         handler = self._handlers.get(msg_type)
         if handler is None:

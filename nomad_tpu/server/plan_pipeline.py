@@ -36,7 +36,7 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
-from nomad_tpu import telemetry, trace
+from nomad_tpu import cpu_observe, telemetry, trace
 from nomad_tpu.server.eval_broker import BrokerError, EvalBroker
 from nomad_tpu.server.plan_apply import (
     _AskAccum,
@@ -398,14 +398,18 @@ class PlanPipeline(threading.Thread):
     def stop(self) -> None:
         self._stop.set()
 
-    def stats(self) -> Dict[str, int]:
+    def stats(self) -> Dict[str, float]:
         """The process-wide totals, the verifier's block-usage rebuilds
-        and removals, and what this pipeline's own FSM made of the stop
-        batches it was sent (only the FSM knows whether a block still
-        stood as the plan saw it)."""
+        and removals, the committer's and the FSM apply's CPU (process
+        totals, ms; the apply's own, not the committer's, where a
+        one-member raft applies on the committer's thread), and what this
+        pipeline's own FSM made of the stop batches it was sent (only the
+        FSM knows whether a block still stood as the plan saw it)."""
         return {
             **self.totals.stats(),
             **block_usage_stats(),
+            "cpu_ms": cpu_observe.BOOK.committer.ms(),
+            "apply_cpu_ms": cpu_observe.BOOK.apply.ms(),
             "stop_batch_members": self.fsm.stop_batch_members,
             "stop_batch_fallback_members":
                 self.fsm.stop_batch_fallback_members,
@@ -485,6 +489,7 @@ class PlanPipeline(threading.Thread):
                                 ("plan", "pipeline", "plan_done_error")
                             )
 
+    @cpu_observe.BOOK.committer.charge()
     def _process_batch(self, batch: List[PendingPlan]) -> None:
         tracer = trace.get_tracer()
 

@@ -17,7 +17,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
-from nomad_tpu import structs, trace
+from nomad_tpu import cpu_observe, structs, trace
 from nomad_tpu.events import EventBroker
 from nomad_tpu.server.core_sched import CoreScheduler
 from nomad_tpu.server.eval_broker import EvalBroker
@@ -261,6 +261,9 @@ class Server:
                  logger: Optional[logging.Logger] = None):
         self.config = config or ServerConfig()
         self.logger = logger or logging.getLogger("nomad_tpu.server")
+        # The collector's pauses are read from the interpreter book
+        # (/v1/agent/solver); its hook is one per process.
+        cpu_observe.BOOK.collector.install()
 
         self.eval_broker = EvalBroker(
             self.config.eval_nack_timeout, self.config.eval_delivery_limit,

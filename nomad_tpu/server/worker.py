@@ -14,7 +14,7 @@ import threading
 import time
 from typing import Optional, Tuple
 
-from nomad_tpu import telemetry, trace
+from nomad_tpu import cpu_observe, telemetry, trace
 from nomad_tpu.backoff import Backoff
 from nomad_tpu.scheduler import new_scheduler
 from nomad_tpu.server.eval_broker import BrokerError
@@ -144,6 +144,8 @@ class Worker(threading.Thread):
                     continue
                 self._process(*dequeued)
 
+    # The evaluation's CPU, on a batch thread as on the lone path.
+    @cpu_observe.BOOK.eval.charge()
     def _process(self, ev: Evaluation, token: str,
                  wait_index: int = 0) -> None:
         # Wait for the local FSM to reach both the eval's modify index and

@@ -36,7 +36,7 @@ from typing import Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from nomad_tpu import faults, telemetry, trace
+from nomad_tpu import cpu_observe, faults, telemetry, trace
 from nomad_tpu.network import NetworkIndex
 from nomad_tpu.ops.binpack import (
     EXACT_THRESHOLD,
@@ -344,7 +344,13 @@ class SolverPanel:
     # -- exposition ----------------------------------------------------------
 
     def snapshot(self) -> Dict:
-        """The panel's section of the /v1/agent/solver body."""
+        """The panel's section of the /v1/agent/solver body, with the
+        interpreter book's running totals and the coalescer's dispatcher
+        CPU (``interp_*``)."""
+        from nomad_tpu.ops.coalesce import GLOBAL_SOLVER
+
+        interpreter = cpu_observe.BOOK.snapshot()
+        interpreter["interp_dispatch_cpu_ms"] = GLOBAL_SOLVER.cpu.ms()
         with self._lock:
             node_buckets = [
                 {
@@ -417,6 +423,7 @@ class SolverPanel:
                 "xla_compile_ms": round(self.xla_compile_ms, 3),
                 "xla_cache_loads": self.xla_cache_loads,
                 "xla_cache_load_ms": round(self.xla_cache_load_ms, 3),
+                **interpreter,
                 "equiv": {
                     "classes": self.equiv_classes,
                     "members": self.equiv_members,
