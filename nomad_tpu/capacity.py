@@ -60,7 +60,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from nomad_tpu import telemetry
+from nomad_tpu import cpu_observe, telemetry
 from nomad_tpu.structs import NODE_STATUS_READY, RESOURCE_DIMS
 
 # Lane classification: the admission front door's batch/service distinction
@@ -277,6 +277,7 @@ class CapacityAccountant:
         logs, or rebuild when the delta cannot be expressed (store
         replaced, log horizon passed). Safe to call from tests without
         the thread."""
+        cpu_observe.BOOK.accountant_polls.add()
         store = self._store()
         if store is None:
             return

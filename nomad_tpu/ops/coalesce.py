@@ -47,7 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from nomad_tpu import cpu_observe, telemetry, trace
+from nomad_tpu import telemetry, trace
 from nomad_tpu.ops import pallas_solve
 from nomad_tpu.ops.binpack import (
     bucket,
@@ -349,9 +349,6 @@ class CoalescingSolver:
         self._cond = threading.Condition(self._lock)
         self._pending: List[_Entry] = []
         self._thread: Optional[threading.Thread] = None
-        # The dispatcher thread's CPU (and its predecessors'), read by its
-        # clock when the solver panel is read.
-        self.cpu = cpu_observe.ThreadRole()
         # Count of in-flight dispatches (the daemon thread's current batch
         # plus any inline fast-path dispatches).
         self._active = 0
@@ -455,7 +452,6 @@ class CoalescingSolver:
                 target=self._run, daemon=True, name="solve-coalescer"
             )
             self._thread.start()
-            self.cpu.watch(self._thread)
 
     def submit(
         self, total, sched_cap, used0, job_count0, tg_count0, bw_avail,

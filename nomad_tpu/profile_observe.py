@@ -68,43 +68,20 @@ import threading
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-from nomad_tpu import prng, telemetry
+from nomad_tpu import cpu_observe, prng, telemetry
 
-# Thread-role classification: every thread in the process maps to exactly one
-# role by FIRST-MATCH prefix rule (order matters: "raft-observatory"
-# must classify observer, not raft). Pinned by the golden-format tests —
-# extending the classification is an artifact-schema change.
+# Thread-role classification: every thread in the process maps to exactly
+# one role, by the interpreter book's table of thread names
+# (``cpu_observe.THREAD_TABLE``, its third column). Pinned by the
+# golden-format tests — extending the classification is an
+# artifact-schema change.
 ROLES = ("worker", "pipeline-committer", "raft", "heartbeat-wheel",
          "express-committer", "observer", "http", "main", "other")
 
-_ROLE_PREFIXES: Tuple[Tuple[str, str], ...] = (
-    ("worker-", "worker"),
-    ("plan-pipeline", "pipeline-committer"),
-    ("raft-observatory", "observer"),
-    ("read-observatory", "observer"),
-    ("runtime-profiler", "observer"),
-    ("capacity-accountant", "observer"),
-    ("stats-emitter", "observer"),
-    ("slo-monitor", "observer"),
-    ("raft-", "raft"),
-    ("heartbeat-wheel", "heartbeat-wheel"),
-    ("express-commit", "express-committer"),
-    ("http-server", "http"),
-)
-
 
 def classify_thread(name: str) -> str:
-    """Thread name -> role, first matching prefix wins. HTTP request
-    handlers ride ThreadingHTTPServer's default naming
-    ("Thread-N (process_request_thread)")."""
-    for prefix, role in _ROLE_PREFIXES:
-        if name.startswith(prefix):
-            return role
-    if "process_request_thread" in name:
-        return "http"
-    if name == "MainThread":
-        return "main"
-    return "other"
+    """Thread name -> role."""
+    return cpu_observe.thread_role(name)[1]
 
 
 @dataclass
@@ -363,10 +340,12 @@ class RuntimeObservatory:
             n += 1
         with self._lock:
             self.samples += 1
+        cpu_observe.BOOK.profiler_passes.add()
         return n
 
     # -- byte-economy ledger --------------------------------------------------
 
+    @cpu_observe.BOOK.profiler_ledger.charge()
     def refresh(self) -> None:
         """One ledger poll: mirror buffers, bounded rings, state-store
         tables, observatory tables, RSS. Safe to call from tests

@@ -50,7 +50,7 @@ from concurrent.futures import Future
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-from nomad_tpu import faults, telemetry
+from nomad_tpu import cpu_observe, faults, telemetry
 from nomad_tpu.raft.log_codec import decode_payload, encode_payload
 from nomad_tpu.rpc import ConnPool, RPCError, RPCServer, RemoteError
 
@@ -896,8 +896,9 @@ class RaftNode:
         if leader_id is not None:
             self.leader_id = leader_id
         if was_leader and self.on_leadership_change:
-            threading.Thread(
-                target=self.on_leadership_change, args=(False,), daemon=True
+            cpu_observe.Thread(
+                target=self.on_leadership_change, args=(False,), daemon=True,
+                name=f"raft-leadership-{self.config.node_id}",
             ).start()
         # Fail outstanding leader futures
         for future in self._apply_futures.values():
@@ -973,7 +974,8 @@ class RaftNode:
                         done.set()
 
         threads = [
-            threading.Thread(target=request, args=(pid, addr), daemon=True)
+            cpu_observe.Thread(target=request, args=(pid, addr), daemon=True,
+                               name=f"raft-vote-{pid}")
             for pid, addr in self._other_peers().items()
         ]
         for t in threads:
@@ -1009,8 +1011,9 @@ class RaftNode:
         # prior-term tail — including a freshly replayed log.
         self.apply("_noop", {})
         if self.on_leadership_change:
-            threading.Thread(
-                target=self.on_leadership_change, args=(True,), daemon=True
+            cpu_observe.Thread(
+                target=self.on_leadership_change, args=(True,), daemon=True,
+                name=f"raft-leadership-{self.config.node_id}",
             ).start()
         self._replicate_now.set()
 
@@ -1060,8 +1063,9 @@ class RaftNode:
                 self._advance_commit_locked()
             return
         threads = [
-            threading.Thread(
-                target=self._replicate_to, args=(pid, addr), daemon=True
+            cpu_observe.Thread(
+                target=self._replicate_to, args=(pid, addr), daemon=True,
+                name=f"raft-replicate-{pid}",
             )
             for pid, addr in peers.items()
         ]
@@ -1360,7 +1364,7 @@ class RaftNode:
         if (self.last_applied - self.snapshot_index
                 >= self.config.snapshot_threshold and not self._compacting):
             self._compacting = True
-            threading.Thread(
+            cpu_observe.Thread(
                 target=self._compact_async, daemon=True,
                 name=f"raft-compact-{self.config.node_id}",
             ).start()

@@ -32,7 +32,7 @@ import threading
 import time
 from typing import Any, Callable, Dict, Optional
 
-from nomad_tpu import faults, telemetry
+from nomad_tpu import cpu_observe, faults, telemetry
 
 _LEN = struct.Struct(">I")
 
@@ -173,7 +173,7 @@ def serve_frames(
         except BaseException:
             inflight.release()
             raise
-        threading.Thread(
+        cpu_observe.Thread(
             target=handle, args=(req,), daemon=True, name=thread_name,
         ).start()
 
@@ -233,8 +233,9 @@ class RPCServer:
                 conn, _ = self._listener.accept()
             except OSError:
                 return
-            t = threading.Thread(
-                target=self._serve_conn, args=(conn,), daemon=True
+            t = cpu_observe.Thread(
+                target=self._serve_conn, args=(conn,), daemon=True,
+                name=f"rpc-conn-{self.addr}",
             )
             t.start()
 

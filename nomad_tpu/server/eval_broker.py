@@ -99,6 +99,15 @@ class _PriorityQueue:
         return len(self._heap)
 
 
+def _timer(interval: float, function, args: tuple,
+           kind: str) -> threading.Timer:
+    """A daemon timer thread, not yet started, named for its kind."""
+    timer = threading.Timer(interval, function, args=args)
+    timer.daemon = True
+    timer.name = f"eval-broker-{kind}"
+    return timer
+
+
 class _UnackEval:
     __slots__ = ("eval", "token", "nack_timer")
 
@@ -276,8 +285,7 @@ class EvalBroker:
                     self._trace_root[ev.id] = root
 
         if ev.wait > 0:
-            timer = threading.Timer(ev.wait, self._enqueue_waiting, args=(ev,))
-            timer.daemon = True
+            timer = _timer(ev.wait, self._enqueue_waiting, (ev,), "wait")
             timer.start()
             self._time_wait[ev.id] = timer
             self.stats.total_waiting += 1
@@ -423,10 +431,8 @@ class EvalBroker:
         ev = self._ready[sched].pop()
         token = generate_uuid()
 
-        nack_timer = threading.Timer(
-            self.nack_timeout, self._nack_from_timer, args=(ev.id, token)
-        )
-        nack_timer.daemon = True
+        nack_timer = _timer(
+            self.nack_timeout, self._nack_from_timer, (ev.id, token), "nack")
         nack_timer.start()
 
         self._unack[ev.id] = _UnackEval(ev, token, nack_timer)
@@ -529,10 +535,8 @@ class EvalBroker:
         if unack.token != token:
             raise BrokerError(ERR_TOKEN_MISMATCH)
         unack.nack_timer.cancel()
-        new_timer = threading.Timer(
-            self.nack_timeout, self._nack_from_timer, args=(eval_id, token)
-        )
-        new_timer.daemon = True
+        new_timer = _timer(
+            self.nack_timeout, self._nack_from_timer, (eval_id, token), "nack")
         new_timer.start()
         unack.nack_timer = new_timer
 
@@ -600,11 +604,8 @@ class EvalBroker:
                 # Propagate the ORIGIN of this nack into the retry: a
                 # deferred worker nack must not count as a timeout when
                 # the retry lands.
-                retry = threading.Timer(
-                    0.25, self._nack_from_timer,
-                    args=(eval_id, token, _from_timer),
-                )
-                retry.daemon = True
+                retry = _timer(0.25, self._nack_from_timer,
+                               (eval_id, token, _from_timer), "nack")
                 unack.nack_timer = retry
                 retry.start()
                 self.logger.debug(

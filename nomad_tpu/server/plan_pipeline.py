@@ -669,7 +669,7 @@ class PlanPipeline(threading.Thread):
             # worker a waiter-thread spawn + context switch.
             self._resolve_batch(dispatched)
         else:
-            waiter = threading.Thread(
+            waiter = cpu_observe.Thread(
                 target=self._resolve_batch, args=(dispatched,), daemon=True,
                 name="plan-pipeline-wait",
             )

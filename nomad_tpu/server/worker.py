@@ -127,7 +127,7 @@ class Worker(threading.Thread):
                             engine.burst_done()
 
                 threads = [
-                    threading.Thread(
+                    cpu_observe.Thread(
                         target=member,
                         args=(ev, token, wait_index),
                         daemon=True, name=f"{self.name}-batch{i}",
@@ -199,7 +199,7 @@ class Worker(threading.Thread):
                         "eval touch failed for %s (retrying): %s", ev.id, e
                     )
 
-        toucher = threading.Thread(
+        toucher = cpu_observe.Thread(
             target=touch_loop, daemon=True, name=f"{self.name}-touch"
         )
         toucher.start()

@@ -345,12 +345,8 @@ class SolverPanel:
 
     def snapshot(self) -> Dict:
         """The panel's section of the /v1/agent/solver body, with the
-        interpreter book's running totals and the coalescer's dispatcher
-        CPU (``interp_*``)."""
-        from nomad_tpu.ops.coalesce import GLOBAL_SOLVER
-
+        interpreter book's running totals (``interp_*``)."""
         interpreter = cpu_observe.BOOK.snapshot()
-        interpreter["interp_dispatch_cpu_ms"] = GLOBAL_SOLVER.cpu.ms()
         with self._lock:
             node_buckets = [
                 {
